@@ -235,9 +235,9 @@ class ZooConfig:
     # supervisor next to --metrics-dir) is the fallback.
     flightrec_dir: Optional[str] = None
     # step profiler (orca/learn/estimator.py Estimator(profile=)): the
-    # per-device peak FLOP/s the train.mfu gauge divides by.  None falls
-    # back to a nominal per-platform constant — set this to your
-    # hardware's real peak for an honest MFU.
+    # per-device peak FLOP/s the train.mfu gauge divides by.  None uses
+    # the published peak of the device_kind (core/device.py); on a
+    # platform without one (CPU) the gauge then stays unset.
     device_peak_flops: Optional[float] = None
 
     # worker liveness (core/launcher.py gang supervision): a file this

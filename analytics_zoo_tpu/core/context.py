@@ -28,6 +28,32 @@ from .config import MeshConfig, ZooConfig
 
 logger = logging.getLogger("analytics_zoo_tpu")
 
+#: Where compiled programs persist when ``JAX_COMPILATION_CACHE_DIR`` does
+#: not say: one fixed directory beside the package (``<checkout>/.jax_cache``).
+#: Fixed on purpose — a cache whose directory moves between runs never hits.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def configure_compile_cache(path: Optional[str] = None) -> str:
+    """Place JAX's persistent compilation cache; returns the directory in
+    use.  ``JAX_COMPILATION_CACHE_DIR`` wins: JAX reads it itself, so when
+    it is set no directory is set in code and ``path`` is ignored.
+    Otherwise the cache lives at ``path``, by default
+    :data:`DEFAULT_COMPILE_CACHE_DIR`.  Called by ``init_orca_context`` (and
+    ``serving.enable_aot_cache``), so the trainer, the server and the CLIs
+    all compile into the same place."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    placed = jax.config.jax_compilation_cache_dir
+    if path is None and placed:
+        return placed  # an earlier call in this process chose it
+    path = path or DEFAULT_COMPILE_CACHE_DIR
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
 
 class _Heartbeat:
     """Progress-based worker liveness: ``beat()`` rewrites the heartbeat
@@ -228,6 +254,7 @@ def init_orca_context(cluster_mode: str = "local",
 
         logging.basicConfig(level=getattr(logging, cfg.log_level, logging.INFO))
         logger.setLevel(getattr(logging, cfg.log_level, logging.INFO))
+        configure_compile_cache()
 
         if cluster_mode == "multihost":
             # zoo-launch (core/launcher.py) passes the topology via env vars,

@@ -3,15 +3,18 @@
 Reference parity (SURVEY.md §2.10): the reference's host data plane was
 native (BlockManager/plasma/Redis/PMEM behind JNI).  Here the equivalent —
 the queueing/synchronization under data prefetch and serving batching — is
-C++ (zoo_native.cpp), compiled on first import with g++ and loaded via
-ctypes.  A pure-Python fallback (queue.Queue) keeps every feature working if
-no compiler is available; ``NativeQueue.is_native`` reports which is active.
+C++ (zoo_native.cpp), compiled on first use with g++ and loaded via
+ctypes.  No binary is kept in git: the library is built from the source
+beside this file and from nothing else.  A pure-Python fallback
+(queue.Queue) keeps every feature working on a machine without a
+compiler; ``NativeQueue.is_native`` reports which is active.
 """
 
 from __future__ import annotations
 
 import atexit
 import ctypes
+import hashlib
 import logging
 import os
 import queue as pyqueue
@@ -24,45 +27,34 @@ logger = logging.getLogger("analytics_zoo_tpu")
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "zoo_native.cpp")
-_SO = os.path.join(_HERE, "libzoonative.so")
 _lib = None
 _lib_lock = threading.Lock()
 
 
-def _build(force: bool = False) -> Optional[str]:
-    if (not force and os.path.exists(_SO)
-            and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)):
-        return _SO
+def _so_path() -> str:
+    """The library's path, named by the SOURCE's content hash: an edited
+    zoo_native.cpp gets a new name and so a rebuild, with no reliance on
+    file times (meaningless in a copied tree)."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    return os.path.join(_HERE, f"libzoonative-{digest}.so")
+
+
+def _build() -> Optional[str]:
+    so = _so_path()
+    if os.path.exists(so):
+        return so
+    tmp = f"{so}.{os.getpid()}.tmp"  # concurrent first uses don't collide
     cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-           _SRC, "-o", _SO + ".tmp"]
+           _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(_SO + ".tmp", _SO)
-        return _SO
+        os.replace(tmp, so)
+        return so
     except (OSError, subprocess.SubprocessError) as e:
         logger.warning("native build failed (%s); using Python fallback "
                        "queue", e)
         return None
-
-
-def _load(so: str) -> Optional[ctypes.CDLL]:
-    """dlopen, tolerating a STALE prebuilt .so (packaged artifact built
-    against a different glibc/toolchain): rebuild from source once and
-    retry; a second failure falls back to the Python queue instead of
-    crashing every import of the serving stack."""
-    try:
-        return ctypes.CDLL(so)
-    except OSError as e:
-        logger.warning("stale native library %s (%s); rebuilding", so, e)
-        so = _build(force=True)
-        if so is None:
-            return None
-        try:
-            return ctypes.CDLL(so)
-        except OSError as e2:
-            logger.warning("rebuilt native library failed to load (%s); "
-                           "using Python fallback queue", e2)
-            return None
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
@@ -76,8 +68,11 @@ def get_lib() -> Optional[ctypes.CDLL]:
         if so is None:
             _lib = False
             return None
-        lib = _load(so)
-        if lib is None:
+        try:
+            lib = ctypes.CDLL(so)
+        except OSError as e:
+            logger.warning("native library %s failed to load (%s); using "
+                           "Python fallback queue", so, e)
             _lib = False
             return None
         lib.zn_queue_create.restype = ctypes.c_void_p

@@ -12,8 +12,9 @@ gradients are computed by a blocked pure-JAX backward (rematerializes logits
 one k-block at a time under `lax.scan` — the standard flash-attention-2
 recomputation trade: extra FLOPs for O(T) memory).
 
-On non-TPU backends the kernel runs in Pallas interpret mode (tests) or falls
-back to the same blocked pure-JAX math.
+On platform ``tpu`` the forward is always the compiled kernel (or the
+compiler's error).  On other backends it is the same blocked pure-JAX math,
+or the kernel in Pallas interpret mode when a test sets ``INTERPRET``.
 """
 
 from __future__ import annotations
@@ -24,11 +25,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # the TPU dialect imports fine on CPU builds; guard just in case
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
@@ -255,10 +252,14 @@ INTERPRET = False  # tests set True to exercise the Pallas kernel on CPU
 
 def _flash_fwd_dispatch(q3, k3, v3, causal, block_q, block_k):
     scale = 1.0 / (q3.shape[-1] ** 0.5)
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu or INTERPRET:
+    if jax.default_backend() == "tpu":
+        # the compiled kernel or the compiler's error: no interpret mode,
+        # no pure-JAX stand-in on the device the kernel was written for
         return _padded_pallas(q3, k3, v3, scale, causal, block_q, block_k,
-                              interpret=not on_tpu)
+                              interpret=False)
+    if INTERPRET:
+        return _padded_pallas(q3, k3, v3, scale, causal, block_q, block_k,
+                              interpret=True)
     return _blocked_fwd_jax(q3, k3, v3, scale, causal,
                             min(block_k, k3.shape[1]))
 

@@ -56,22 +56,12 @@ logger = logging.getLogger("analytics_zoo_tpu")
 #: Valid values for ``ZooEstimator(nan_policy=...)``.
 NAN_POLICIES = ("warn", "skip_step", "rollback", "raise")
 
-#: Nominal per-device peak FLOP/s by jax platform, the ``train.mfu``
-#: denominator when ``ZooConfig.device_peak_flops`` is unset.  These are
-#: order-of-magnitude placeholders (MFU is a trend signal either way);
-#: set the config field to your hardware's real peak for honest numbers.
-NOMINAL_PEAK_FLOPS = {"cpu": 5e10, "gpu": 1e13, "tpu": 9e13}
 
-
-def _jit_cache_size(fn: Any) -> Optional[int]:
+def _jit_cache_size(fn: Any) -> int:
     """How many executables a jitted function has compiled so far —
     the per-step compile-event probe (``InferenceModel.compile_count``'s
-    pattern applied to the training step).  None when this jax version
-    doesn't expose the cache."""
-    try:
-        return int(fn._cache_size())
-    except Exception:  # noqa: BLE001 — private API, degrade silently
-        return None
+    pattern applied to the training step)."""
+    return int(fn._cache_size())
 
 
 class NonFiniteLossError(RuntimeError):
@@ -298,8 +288,9 @@ class ZooEstimator:
           training FLOPs — each epoch sets the ``train.mfu`` gauge to
           ``flops_per_sample × samples_per_sec / (peak × n_devices)``.
           ``peak`` comes from the dict's ``peak_flops``, then
-          ``ZooConfig.device_peak_flops``, then a nominal per-platform
-          constant (``NOMINAL_PEAK_FLOPS``);
+          ``ZooConfig.device_peak_flops``, then the published peak of
+          the ``device_kind`` (``core/device.py``); with none of the
+          three (the CPU backend) the gauge stays unset;
         - **device trace**: dict keys ``trace_dir`` + ``trace_steps``
           ``(k, k+n)`` capture a ``jax.profiler`` trace for steps
           [k, k+n) — the same machinery as the ``profile_dir`` /
@@ -498,10 +489,9 @@ class ZooEstimator:
             # parameter shapes match what the train step applies
             example_x = self.augment(example_x, None, training=False)
         # init under jit: ONE compiled program instead of hundreds of
-        # eager per-op dispatches.  Eager init was (a) the trigger surface
+        # eager per-op dispatches.  Eager init was the trigger surface
         # for an intermittent native abort in XLA:CPU under dispatch load
-        # (big-model init inside test_models), and (b) seconds-to-minutes
-        # of per-op round-trips on remote-device platforms.
+        # (big-model init inside test_models).
         variables = jax.jit(
             lambda r, x: self.model.init(r, x, training=True)
         )(rng, example_x)
@@ -1095,7 +1085,7 @@ class ZooEstimator:
                                 # empty, so the first step's compile IS
                                 # a counted event
                                 cache_prev = _jit_cache_size(
-                                    self._train_step) or 0
+                                    self._train_step)
                         # liveness beat for the zoo-launch gang
                         # supervisor (no-op unless a heartbeat file is
                         # configured); the payload makes the heartbeat
@@ -1126,8 +1116,7 @@ class ZooEstimator:
                             # grew during THIS step ⇒ it paid a retrace
                             # (new input shape/dtype) — name the step
                             cs = _jit_cache_size(self._train_step)
-                            if (cs is not None and cache_prev is not None
-                                    and cs > cache_prev):
+                            if cs > cache_prev:
                                 self.compile_count += cs - cache_prev
                                 m_compiles.inc(cs - cache_prev)
                                 trace_lib.record(
@@ -1135,8 +1124,7 @@ class ZooEstimator:
                                     {"step": self._py_step,
                                      "compiles": cs - cache_prev},
                                     parent=epoch_sid)
-                            if cs is not None:
-                                cache_prev = cs
+                            cache_prev = cs
                         step_ms_i = (time.monotonic() - t_fetch) * 1000.0
                         m_step.observe(step_ms_i)
                         if record_spans:
@@ -1340,9 +1328,10 @@ class ZooEstimator:
         None (gauge untouched) unless the profiler is on AND the model
         declares ``flops_per_sample`` (or the profile dict supplies it).
         The peak is ``profile['peak_flops']`` → ``ZooConfig.
-        device_peak_flops`` → a nominal per-platform constant — nominal
-        peaks make MFU a trend signal, not an absolute; configure the
-        real peak for honest numbers."""
+        device_peak_flops`` → the published peak of this ``device_kind``
+        (core/device.py; an unlisted TPU kind raises).  On a platform
+        with no published peak and none configured the gauge stays
+        unset."""
         if self._profile_cfg is None:
             return None
         fps = (self._profile_cfg.get("flops_per_sample")
@@ -1354,7 +1343,10 @@ class ZooEstimator:
             from analytics_zoo_tpu.core.context import config_default
             peak = config_default("device_peak_flops", None)
         if peak is None:
-            peak = NOMINAL_PEAK_FLOPS.get(jax.default_backend(), 1e12)
+            from analytics_zoo_tpu.core.device import peak_bf16_flops
+            peak = peak_bf16_flops()
+        if peak is None:
+            return None
         return float(fps) * samples_per_sec / (float(peak)
                                                * jax.device_count())
 

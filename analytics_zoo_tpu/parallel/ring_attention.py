@@ -20,10 +20,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map  # jax >= 0.4.35: top-level callable
-except ImportError:  # older jax: the experimental namespace
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 _NEG_INF = -1e30

@@ -29,15 +29,12 @@ def pvary_like(x, *refs):
     """Mark ``x`` as varying over every manual mesh axis any of ``refs`` is
     varying over.  Needed for lax.scan carries inside shard_map: a
     freshly-created zeros init is 'unvarying', but the scan body produces
-    'varying' values, and new JAX rejects the mismatch.  No-op outside
-    shard_map / on JAX versions without the vma type."""
+    'varying' values, and JAX rejects the mismatch.  No-op outside
+    shard_map."""
     vma = set()
     for r in refs:
         for leaf in jax.tree_util.tree_leaves(r):
-            try:
-                vma |= set(jax.typeof(leaf).vma)
-            except (AttributeError, TypeError):
-                pass
+            vma |= set(jax.typeof(leaf).vma)
     if not vma:
         return x
     return jax.tree_util.tree_map(

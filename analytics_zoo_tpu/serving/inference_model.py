@@ -96,15 +96,22 @@ def _dequantize_tree(variables: Any, compute_dtype: Any,
             for k, v in variables.items()}
 
 
-def enable_aot_cache(path: str) -> None:
-    """Point JAX's persistent compilation cache at ``path`` so serving
-    executables compile once per machine, not once per process — with
-    ``save_executables`` (skips tracing/lowering) this is the full
-    OpenVINO-IR analog: a restart reuses the compiled artifact.  Safe to
-    call more than once; applies process-wide."""
-    jax.config.update("jax_compilation_cache_dir", path)
+def enable_aot_cache(path: Optional[str] = None) -> str:
+    """Persist EVERY serving executable in JAX's compilation cache, however
+    small or quick to compile, so serving executables compile once per
+    machine, not once per process — with ``save_executables`` (skips
+    tracing/lowering) this is the full OpenVINO-IR analog: a restart
+    reuses the compiled artifact.  The directory is placed by
+    ``core.context.configure_compile_cache``: ``JAX_COMPILATION_CACHE_DIR``
+    when set (``path`` is then ignored), else ``path``, else
+    ``<checkout>/.jax_cache`` — keep it FIXED across restarts, a directory
+    that moves never hits.  Returns the directory in use.  Safe to call
+    more than once; applies process-wide."""
+    from analytics_zoo_tpu.core.context import configure_compile_cache
+    path = configure_compile_cache(path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return path
 
 
 class InferenceModel:

@@ -1477,6 +1477,18 @@ def main(argv: Optional[List[str]] = None) -> None:
                              "ZooConfig.controller_interval_s)")
     args = parser.parse_args(argv)
 
+    import jax
+    if args.autoscale and jax.default_backend() == "tpu":
+        # one process per chip: this process holds the chip(s) for its own
+        # replica, and a zoo-serving child that needs one fails or hangs
+        # at start-up.  Refuse now instead of at the first scale-up.
+        parser.error(
+            "--autoscale spawns zoo-serving child processes, and this "
+            "process holds the TPU; a chip belongs to one process.  Run "
+            "one zoo-serving per chip/host and put a ReplicaSet router in "
+            "front of them.")
+    from analytics_zoo_tpu.core.context import configure_compile_cache
+    configure_compile_cache()
     cfg = None
     if args.config is not None:
         from analytics_zoo_tpu.core.config import ZooConfig

@@ -37,7 +37,9 @@ def main() -> None:
 
     init_orca_context("local")
     try:
-        enable_aot_cache(tempfile.mkdtemp(prefix="zoo_aot_cache_"))
+        # JAX_COMPILATION_CACHE_DIR, else the fixed <checkout>/.jax_cache:
+        # a cache directory that moves between runs never hits
+        enable_aot_cache()
 
         # 1. train a small CNN (class signal: bright channel per class)
         rng = np.random.default_rng(0)

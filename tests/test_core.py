@@ -68,10 +68,7 @@ def test_psum_on_mesh():
     def f(v):
         return jax.lax.psum(v.sum(), "data")
 
-    try:
-        from jax import shard_map  # jax >= 0.4.35: top-level callable
-    except ImportError:  # older jax: the experimental namespace
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     out = jax.jit(
         shard_map(f, mesh=mesh, in_specs=P("data", None), out_specs=P())
     )(xs)
@@ -157,3 +154,40 @@ def test_summary_writer(tmp_path):
     scalars = SummaryWriter(str(tmp_path), "train").read_scalar("loss")
     assert [s for s, _ in scalars] == [0, 1, 2]
     assert scalars[0][1] == 1.0
+
+
+# -- compile-cache placement (core/context.configure_compile_cache) -----------
+
+_CACHE_PROBE = (
+    "import jax; "
+    "from analytics_zoo_tpu.core import init_orca_context; "
+    "from analytics_zoo_tpu.serving import enable_aot_cache; "
+    "init_orca_context('local'); print(jax.config.jax_compilation_cache_dir); "
+    "print(enable_aot_cache('x')); "
+    "print(jax.config.jax_compilation_cache_dir)")
+
+
+@pytest.mark.parametrize("env_dir", ["/some/dir", None])
+def test_compile_cache_placement(env_dir):
+    """JAX_COMPILATION_CACHE_DIR set: the directory is the environment's and
+    no call in the program moves it.  Unset: init_orca_context places the
+    cache at the fixed <checkout>/.jax_cache, derived from the package's
+    location — never a temporary directory, which would never hit."""
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    out = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                         cwd="/", capture_output=True, text=True,
+                         timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    at_init, returned, after = out.stdout.split()
+    if env_dir:
+        assert at_init == returned == after == env_dir
+    else:
+        assert at_init == os.path.join(repo, ".jax_cache")
+        assert returned == after == "x"  # a deployment placing its own

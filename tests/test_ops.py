@@ -4,13 +4,19 @@ Mirrors the reference's TFNet/TorchNet differential-test pattern (SURVEY.md
 §4.4): run both implementations on the same inputs, compare within tolerance.
 """
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import analytics_zoo_tpu.ops.flash_attention as fa_mod
 from analytics_zoo_tpu.ops import flash_attention, mha_reference
+
+# the MODULE: ``import analytics_zoo_tpu.ops.flash_attention as m`` binds the
+# function of the same name that ops/__init__ re-exports, and setting
+# INTERPRET on that would leave the kernel unexercised
+fa_mod = importlib.import_module("analytics_zoo_tpu.ops.flash_attention")
 
 
 def _qkv(rng, b=2, t=64, h=2, d=16):

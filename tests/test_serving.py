@@ -192,6 +192,20 @@ def test_native_queue_empty_payload():
     assert payload == b"" and tag == 7
 
 
+def test_native_queue_builds_from_source_on_first_use(tmp_path,
+                                                      monkeypatch):
+    """No binary is kept in git: with the library absent, first use
+    compiles zoo_native.cpp and the queue is the C++ one."""
+    from analytics_zoo_tpu import native
+    so = tmp_path / "libzoonative-test.so"
+    monkeypatch.setattr(native, "_so_path", lambda: str(so))
+    monkeypatch.setattr(native, "_lib", None)
+    q = native.NativeQueue(max_items=2)
+    assert so.exists()
+    assert q.is_native
+    assert q.push(b"abc", tag=1) and q.pop(timeout=1.0) == (b"abc", 1)
+
+
 # -- HTTP frontend ------------------------------------------------------------
 
 def test_http_frontend(inference_model):

@@ -222,9 +222,10 @@ def _traced_table_prims(sharded: bool):
 
 
 # equation outputs at table shape that do NOT materialize a new dense
-# array: pjit results are the returned updated tables, stop_gradient is
+# array: jit results (the primitive the installed JAX 0.9 calls ``jit``,
+# older ones ``pjit``) are the returned updated tables, stop_gradient is
 # an identity alias on the forward lookup
-_TABLE_ALIAS_PRIMS = {"pjit", "stop_gradient"}
+_TABLE_ALIAS_PRIMS = {"jit", "stop_gradient"}
 
 
 def test_backward_never_materializes_dense_table_grad():
