@@ -252,6 +252,19 @@ def span(name: str, trace_id: Optional[str] = None,
     return Span(name, trace_id=trace_id, parent=parent, stages=stages)
 
 
+def phase(name: str):
+    """A host span named ``zoo:<name>`` in the PROFILER's trace (not in the
+    span ring): ``with trace.phase("fit.data_wait"): ...`` puts what the
+    loop was doing on the clock of the device planes, so that a reduction
+    can lay device idle beside it.  The only place the ``zoo:`` prefix is
+    written.  With no profiler session running it costs the object and a
+    flag test.  The profiler records a span when it CLOSES, and only if it
+    also opened inside the session.  JAX is imported here, not at the top:
+    nothing else in this module needs it."""
+    import jax
+    return jax.profiler.TraceAnnotation("zoo:" + name)
+
+
 def find(trace_id: str) -> List[TraceRecord]:
     """Every recorded span of ``trace_id``, in arrival order."""
     with _lock:

@@ -26,6 +26,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from analytics_zoo_tpu.core import metrics as _metrics_lib
+from analytics_zoo_tpu.core import trace as _trace_lib
 from analytics_zoo_tpu.core.faults import get_registry as _fault_registry
 from .shards import XShards
 
@@ -328,7 +329,8 @@ class PrefetchIterator:
         the one-item lag is what guarantees a slot is never reused
         while its bytes are still in flight to the device."""
         t0 = time.monotonic()
-        placed = self._place(raw)
+        with _trace_lib.phase("feed.place"):
+            placed = self._place(raw)
         disp_ms = (time.monotonic() - t0) * 1000.0
         self._retire()
         self._staged = (placed, raw if hasattr(raw, "release") else None,

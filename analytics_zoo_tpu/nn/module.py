@@ -122,7 +122,11 @@ class Scope:
                 "names (weight sharing requires the same layer object)")
         sub._reuse = self._reuse or prev is module
         self._child_seen[name] = module
-        out = module.forward(sub, *args, **kwargs)
+        # the child's name on every op traced under it, so that a profile
+        # reads .../bert/layer_3/attention/... (backward too: JAX names the
+        # transposed ops after the forward's scope)
+        with jax.named_scope(name):
+            out = module.forward(sub, *args, **kwargs)
         if not self.init_mode and (sub.state or sub_state_in):
             self.state[name] = sub.state
         if self.taps is not None:

@@ -21,6 +21,7 @@ get_model``, with TensorBoard-style summaries and checkpoint triggers.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import math
 import os
@@ -683,9 +684,11 @@ class ZooEstimator:
                     gsum = jax.tree_util.tree_map(jnp.add, gsum, grads)
                     return (gsum, new_state, i + 1), loss
 
-                (gsum, new_state, _), losses = jax.lax.scan(
-                    body, (gzero, ts["state"], jnp.zeros((), jnp.int32)),
-                    micro)
+                with jax.named_scope("grad_accum"):
+                    (gsum, new_state, _), losses = jax.lax.scan(
+                        body,
+                        (gzero, ts["state"], jnp.zeros((), jnp.int32)),
+                        micro)
                 grads = jax.tree_util.tree_map(lambda g: g / accum, gsum)
                 loss_val = losses.mean()
             elif compress_wire:
@@ -763,16 +766,18 @@ class ZooEstimator:
             if sparse_paths:
                 # dense optimizer over dense params; sparse tables update
                 # below by scatter-add on the unique rows only
-                updates, opt_state = tx.update(grads, ts["opt_state"],
-                                               dense_p)
-                dense_new = optax.apply_updates(dense_p, updates)
+                with jax.named_scope("optimizer"):
+                    updates, opt_state = tx.update(grads, ts["opt_state"],
+                                                   dense_p)
+                    dense_new = optax.apply_updates(dense_p, updates)
                 new_tables = dict(tables)
                 if "touched" in ts:
                     new_touched = dict(ts["touched"])
                 for key, g in tap_grads.items():
                     tp = emb_lib.table_path_of(key)
-                    new_tables[tp] = new_tables[tp].at[uniqs[key]].add(
-                        (-embed_lr * g).astype(new_tables[tp].dtype))
+                    with jax.named_scope("optimizer"):
+                        new_tables[tp] = new_tables[tp].at[uniqs[key]].add(
+                            (-embed_lr * g).astype(new_tables[tp].dtype))
                     if new_touched is not None:
                         # delta checkpoints (ISSUE 15): mark the batch's
                         # unique rows dirty.  The dedup buffer pads with
@@ -785,9 +790,10 @@ class ZooEstimator:
                 params = emb_lib.merge_sparse(dense_new, new_tables)
                 grads_for_norm = (grads, tap_grads)
             else:
-                updates, opt_state = tx.update(grads, ts["opt_state"],
-                                               ts["params"])
-                params = optax.apply_updates(ts["params"], updates)
+                with jax.named_scope("optimizer"):
+                    updates, opt_state = tx.update(grads, ts["opt_state"],
+                                                   ts["params"])
+                    params = optax.apply_updates(ts["params"], updates)
                 grads_for_norm = grads
             bad_steps = ts["bad_steps"]
             if guard_skip:
@@ -987,6 +993,12 @@ class ZooEstimator:
         reg = telemetry.get_registry()
         m_step = reg.histogram("train.step_ms")
         m_wait = reg.histogram("train.data_wait_ms")
+        # the epoch boundary, once an epoch: the host time from a drained
+        # device to the epoch's first dispatch (epoch-end bookkeeping, the
+        # feed's new epoch and prefetcher, the first batch's wait), and
+        # that first wait alone
+        m_gap = reg.histogram("train.epoch_gap_ms")
+        m_first_wait = reg.histogram("train.first_batch_wait_ms")
         m_steps = reg.counter("train.steps")
         m_samples = reg.counter("train.samples")
         m_bad = reg.counter("train.bad_steps")
@@ -1014,6 +1026,13 @@ class ZooEstimator:
         fit_sid = trace_lib.new_span_id() if record_spans else None
         self.trace_id = fit_tid  # correlate this fit in the span ring
         fit_t0 = time.monotonic()
+        # profiler phases (trace_lib.phase): the leaf regions of this loop
+        # that can leave the device idle, as host spans on the profiler's
+        # clock.  zoo:fit.epoch_end stays open from the batch loop's exit
+        # (the prefetcher's shutdown inside it) to the top of the next
+        # epoch, across a ``continue`` and several exits, so it is held
+        # here and closed wherever the loop leaves it.
+        epoch_end = contextlib.ExitStack()
 
         if self._preempt is not None:
             self._preempt.active = True
@@ -1027,7 +1046,9 @@ class ZooEstimator:
                 cache_prev = _jit_cache_size(self._train_step)
             # while (not for): nan_policy="rollback" rewinds self._epoch to
             # the restored checkpoint's epoch and re-runs from there
+            t_drained = time.monotonic()  # nothing dispatched yet
             while self._epoch < target_epoch:
+                epoch_end.close()
                 epoch_sid = (trace_lib.new_span_id() if record_spans
                              else None)
                 # monotonic: a wall-clock step (NTP) mid-epoch must not
@@ -1062,7 +1083,8 @@ class ZooEstimator:
                 try:
                     while True:
                         t_fetch = time.monotonic()
-                        batch = next(batch_iter, None)
+                        with trace_lib.phase("fit.data_wait"):
+                            batch = next(batch_iter, None)
                         if batch is None:
                             break
                         wait = time.monotonic() - t_fetch
@@ -1104,8 +1126,14 @@ class ZooEstimator:
                         if faults.fire("step.nan"):
                             batch = _poison_batch(batch)
                         self._maybe_profile()
-                        self._ts, loss_val = self._train_step(self._ts,
-                                                              batch)
+                        if t_drained is not None:  # the epoch's first
+                            m_gap.observe(
+                                (time.monotonic() - t_drained) * 1000.0)
+                            m_first_wait.observe(wait * 1000.0)
+                            t_drained = None
+                        with trace_lib.phase("fit.dispatch"):
+                            self._ts, loss_val = self._train_step(
+                                self._ts, batch)
                         losses.append(loss_val)
                         # track the step in Python: reading
                         # self._ts["step"] would force a device sync on
@@ -1185,8 +1213,10 @@ class ZooEstimator:
                             raise Preempted(self._py_step, path)
                         if trigger and self.model_dir and trigger.fires(
                                 step=self._py_step, epoch_end=False):
-                            self._trigger_save()
+                            with trace_lib.phase("fit.save"):
+                                self._trigger_save()
                 finally:
+                    epoch_end.enter_context(trace_lib.phase("fit.epoch_end"))
                     # mid-epoch exits (rollback, preemption, raise) must
                     # not leak the prefetch producer thread
                     if isinstance(batch_iter, PrefetchIterator):
@@ -1199,6 +1229,7 @@ class ZooEstimator:
                     keep = max(0, self._epoch - start_epoch)
                     for v in history.values():
                         del v[keep:]
+                    t_drained = time.monotonic()  # the NaN check synced
                     continue
                 if not losses:
                     raise ValueError(
@@ -1220,6 +1251,7 @@ class ZooEstimator:
                         m_bad.inc(self.bad_steps - bad_before)
                 else:
                     epoch_loss = float(stacked.mean())
+                t_drained = time.monotonic()  # the read-back returned
                 history["loss"].append(epoch_loss)
                 if self.nan_policy is not None:
                     history.setdefault("bad_steps", []).append(
@@ -1290,6 +1322,7 @@ class ZooEstimator:
                 if trigger and self.model_dir and trigger.fires(
                         step=self._py_step, epoch_end=True):
                     self._trigger_save()
+            epoch_end.close()  # before the trace stops: it records on close
             self._stop_profile()  # short runs: close the trace at fit end
         except Exception as e:
             # flight recorder: an unhandled step exception (including a
@@ -1305,6 +1338,7 @@ class ZooEstimator:
                        "error": str(e)})
             raise
         finally:
+            epoch_end.close()  # an exception must not leave the phase open
             ZooEstimator._device_lock.release()
             if self._preempt is not None:
                 self._preempt.active = False
