@@ -236,6 +236,23 @@ class ZooEstimator:
         a BERT-base step — over ``grad_accum`` micro-batches, and keeps
         each micro-batch at its best-fusing size.
 
+        On a mesh with S > 1 batch shards (``data`` x ``fsdp``) micro-batch
+        ``i`` is the same rows ``i*B/grad_accum ...`` of the global batch
+        as on one device; only their placement follows the mesh: each
+        micro-batch's rows are sharded over the batch axes, so a chip
+        computes ``B/(grad_accum*S)`` rows of every micro-batch and none
+        twice (one all-to-all of the input batch a step puts them there).
+        Losses, per-micro-batch rng and BatchNormalization statistics (the
+        whole micro-batch's, reduced across the shards) are one device's
+        for the same seed.  The gradients meet in ONE all-reduce a step,
+        after the accumulation loop: the compiler moves the reduce of each
+        ``sum + g`` out of the scan (XLA's while-loop all-reduce code
+        motion, on TPU and GPU; an embedding table's scatter-add gradient
+        is the exception and is reduced once a micro-batch; ``fsdp``
+        reduce-scatters into its sharded sum once a micro-batch, as ZeRO
+        does).  Where ``B/grad_accum`` does not divide into S, GSPMD
+        places the split itself and may compute rows more than once.
+
         ``nan_policy``: training-loop self-healing for non-finite loss /
         gradients (None = unguarded, zero overhead):
 
@@ -637,10 +654,33 @@ class ZooEstimator:
         embed_lr = self._embed_lr()
         if sparse_paths:
             from analytics_zoo_tpu.parallel import embedding as emb_lib
+        from analytics_zoo_tpu.parallel.util import batch_shard_count
+        nshards = batch_shard_count(mesh)
         if compress_wire:
             from analytics_zoo_tpu.parallel.util import (
-                batch_shard_count, batch_shard_spec, compressed_allreduce)
-            nshards = batch_shard_count(mesh)
+                batch_shard_spec, compressed_allreduce)
+
+        def split_micro(l, is_feature):
+            """``[B, ...]`` -> ``[accum, B/accum, ...]``: micro-batch ``i``
+            is rows ``i*B/accum ...``, as on one device.  The batch arrives
+            sharded on dim 0; left alone, the reshape puts that sharding on
+            the accumulation axis, which the scan needs whole, and GSPMD
+            then replicates the micro-batch's rows (every chip computed 2x
+            its share at accum 2, the whole micro-batch at accum >= the
+            shard count).  So each micro-batch's ROWS are pinned to the
+            mesh's batch axes, as the feed would place a batch of that
+            size ('x' keeps its ``seq`` sharding): one all-to-all of the
+            input batch a step, and no row computed twice.  Where the rows
+            do not divide into the shards, GSPMD places them as before."""
+            l = l.reshape((accum, l.shape[0] // accum) + l.shape[1:])
+            if nshards > 1 and l.shape[1] % nshards == 0:
+                rows = batch_sharding(
+                    mesh, l.ndim - 1,
+                    seq_dim_size=(l.shape[2] if is_feature and l.ndim > 2
+                                  else None))
+                l = jax.lax.with_sharding_constraint(
+                    l, NamedSharding(mesh, P(None, *rows.spec)))
+            return l
 
         def train_step(ts, batch):
             step_rng = jax.random.fold_in(ts["rng"], ts["step"])
@@ -669,10 +709,15 @@ class ZooEstimator:
                 # micro-batch accumulation: scan fwd/bwd over accum equal
                 # slices, ONE optimizer update on the mean gradient —
                 # numerically the full-batch step, minus accum-1 optimizer
-                # sweeps
-                micro = jax.tree_util.tree_map(
-                    lambda l: l.reshape((accum, l.shape[0] // accum)
-                                        + l.shape[1:]), batch)
+                # sweeps.  On several batch shards each micro-batch's
+                # gradient is a sum over the shards; the replicated carry
+                # would have it reduced once a micro-batch, but XLA's
+                # while-loop code motion moves the all-reduce of a plain
+                # ``gsum + g`` out of the loop, to once a step (checked in
+                # tests/test_tpu_compile.py)
+                micro = {k: jax.tree_util.tree_map(
+                    lambda l, f=(k == "x"): split_micro(l, f), v)
+                    for k, v in batch.items()}
                 gzero = jax.tree_util.tree_map(jnp.zeros_like, ts["params"])
 
                 def body(carry, mb):

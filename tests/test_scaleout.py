@@ -410,3 +410,137 @@ def test_lars_momentum_accumulates():
     u2, _ = tx.update(grads, state, params)
     # second step carries 0.9 * first velocity on top of the fresh term
     assert abs(float(u2["kernel"][0])) > abs(float(u1["kernel"][0]))
+
+
+# -- grad_accum on a mesh with several batch shards (ISSUE 26) ----------------
+# The [B] -> [accum, B/accum] split pins each micro-batch's ROWS to the
+# mesh's batch axes, so a chip computes its share of every micro-batch and
+# nothing twice.  The witness is the compiled program.  (Where the gradient
+# all-reduce sits relative to the accumulation loop is the TPU compiler's
+# loop code motion: tests/test_tpu_compile.py reads it off a described v5e;
+# the CPU backend does not run that pass.)
+
+def _wide_mlp():
+    import analytics_zoo_tpu.nn as nn
+    return nn.Sequential([nn.Dense(256, activation="relu", name="ffn1"),
+                          nn.Dense(256, activation="relu", name="ffn2"),
+                          nn.Dense(4, name="head")])
+
+
+def _bn_net():
+    import analytics_zoo_tpu.nn as nn
+    return nn.Sequential([nn.Dense(16, name="ffn1"),
+                          nn.BatchNormalization(name="bn"),
+                          nn.Dense(4, name="head")])
+
+
+def _accum_estimator(mesh_shape, accum, model=_mlp, sharding="dp"):
+    stop_orca_context()
+    mesh = init_orca_context("local", mesh_shape=mesh_shape)
+    est = Estimator.from_keras(model(),
+                               loss="sparse_categorical_crossentropy",
+                               optimizer="sgd", learning_rate=0.1, seed=1,
+                               grad_accum=accum, sharding=sharding)
+    return est, mesh
+
+
+def _compiled_train_step(mesh_shape, batch, accum, **kw):
+    from analytics_zoo_tpu.data import shard_batch
+    est, mesh = _accum_estimator(mesh_shape, accum, **kw)
+    x, y = _data(n=batch, d=64)
+    est._ensure_initialized(x[:1])
+    placed = shard_batch({"x": x, "y": y}, mesh)
+    return est._train_step.lower(est._ts, placed).compile()
+
+
+@pytest.mark.parametrize("mesh_shape,sharding,accum", [
+    ({"data": 4}, "dp", 2), ({"data": 4}, "dp", 4), ({"data": 4}, "dp", 8),
+    ({"data": 1, "fsdp": 4}, "fsdp", 2), ({"data": 1, "fsdp": 4}, "fsdp", 8),
+    ({"data": 2, "fsdp": 2}, "fsdp", 4),
+], ids=["dp4-accum2", "dp4-accum4", "dp4-accum8", "fsdp4-accum2",
+        "fsdp4-accum8", "dp2xfsdp2-accum4"])
+def test_grad_accum_on_four_shards_computes_each_row_once(mesh_shape,
+                                                          sharding, accum):
+    """Four batch shards at global batch 4B against one device at batch B:
+    a device's FLOPs agree.  (The parent read ~2x at accum 2 and ~4x at 4
+    and 8 on {data: 4}: the [accum, B/accum] reshape had moved the batch
+    sharding onto the accumulation axis and GSPMD replicated the
+    micro-batch's rows.)"""
+    from hlo_loops import collectives
+    b = 16 * accum
+    one = _compiled_train_step({"data": 1}, b, accum, model=_wide_mlp)
+    four = _compiled_train_step(mesh_shape, 4 * b, accum, model=_wide_mlp,
+                                sharding=sharding)
+    ratio = four.cost_analysis()["flops"] / one.cost_analysis()["flops"]
+    assert 0.95 < ratio < 1.05, ratio
+    assert collectives(one.as_text()) == ({}, {})
+
+
+def _three_steps(mesh_shape, accum, model, batch=32):
+    est, _ = _accum_estimator(mesh_shape, accum, model=model)
+    x, y = _data(n=3 * batch)
+    hist = est.fit((x, y), epochs=1, batch_size=batch, verbose=False,
+                   prefetch=0)
+    assert est._py_step == 3
+    return hist["loss"], est.get_model()
+
+
+def _assert_same_training(got, want):
+    (loss_a, vars_a), (loss_b, vars_b) = got, want
+    np.testing.assert_allclose(loss_a, loss_b, rtol=1e-4)
+    for a, b in zip(jax.tree_util.tree_leaves(vars_a),
+                    jax.tree_util.tree_leaves(vars_b)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-6)
+
+
+@pytest.mark.parametrize("mesh_shape", [{"data": 4}, {"data": 1, "fsdp": 4},
+                                        {"data": 2, "fsdp": 2}],
+                         ids=["dp4", "fsdp4", "dp2xfsdp2"])
+def test_grad_accum_on_four_shards_matches_one_device(mesh_shape):
+    """Three steps of accum 2 from one seed: one device's losses and
+    parameters at rtol 1e-4."""
+    _assert_same_training(_three_steps(mesh_shape, 2, _mlp),
+                          _three_steps({"data": 1}, 2, _mlp))
+
+
+def test_grad_accum_batchnorm_statistics_span_the_micro_batch():
+    """Pins the semantics the split chose: micro-batch ``i`` is rows
+    ``i*B/accum ...`` on any mesh, and only their PLACEMENT follows the
+    mesh, so a BatchNorm layer normalises by the statistics of the whole
+    micro-batch (GSPMD reduces them over the shards), exactly as on one
+    device: dp=4 x accum 2 gives one device's accum-2 losses, parameters
+    and running statistics, and not those of accum 8 (the per-shard groups
+    of four rows a local-BN scheme would see).  The other road — strided
+    membership or per-shard accumulation — would save the input's
+    all-to-all or a reduce inside the loop and change what a stateful
+    layer computes; "same losses as one device for the same seed" was
+    worth more."""
+    on_four = _three_steps({"data": 4}, 2, _bn_net)
+    _assert_same_training(on_four, _three_steps({"data": 1}, 2, _bn_net))
+    assert not np.allclose(on_four[0],
+                           _three_steps({"data": 1}, 8, _bn_net)[0],
+                           rtol=1e-4)
+
+
+@pytest.mark.parametrize("mesh_shape,batch,pinned", [
+    ({"data": 1}, 32, False),              # one device
+    ({"data": 1, "model": 4}, 32, False),  # no batch axis to shard over
+    ({"data": 4}, 12, False),              # micro-batch of 6 rows, 4 shards
+    ({"data": 4}, 32, True),
+    ({"data": 2, "fsdp": 4}, 32, True),
+], ids=["one_device", "model4", "dp4_b12", "dp4_b32", "dp2xfsdp4_b32"])
+def test_grad_accum_pins_rows_only_where_batch_shards_divide_them(
+        mesh_shape, batch, pinned):
+    """On one batch shard the step is the program it was: no sharding
+    constraint is traced (PERF.md, PR 26: the one-chip program compiles to
+    the parent's FLOPs, bytes and memory).  With several shards the rows
+    are pinned wherever a micro-batch divides into them; where it does not,
+    GSPMD places the split as before."""
+    from analytics_zoo_tpu.data import shard_batch
+    est, mesh = _accum_estimator(mesh_shape, 2)
+    x, y = _data(n=batch)
+    est._ensure_initialized(x[:1])
+    jaxpr = str(jax.make_jaxpr(est._train_step)(
+        est._ts, shard_batch({"x": x, "y": y}, mesh)))
+    assert ("sharding_constraint" in jaxpr) == pinned

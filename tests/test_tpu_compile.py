@@ -11,14 +11,17 @@ that dispatches on the backend.
 
 import importlib
 import os
+import re
 import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 import analytics_zoo_tpu.nn as nn
 from analytics_zoo_tpu.ops.fused_bn import bn_train
@@ -32,10 +35,11 @@ HBM_BYTES = 16 * 2 ** 30  # one v5e chip
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """One described v5e device.  The persistent compilation cache is off
-    around these compiles: an entry written for a described device cannot
-    be read back without the chip, and the next run would only warn."""
+def topo():
+    """A described v5e 2x2 (four chips).  The persistent compilation cache
+    is off around these compiles: an entry written for a described device
+    cannot be read back without the chip, and the next run would only
+    warn."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     try:
@@ -46,9 +50,15 @@ def chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """One described v5e device."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _on(chip, tree):
@@ -58,11 +68,15 @@ def _on(chip, tree):
         tree)
 
 
+def _per_chip_bytes(compiled):
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+
+
 def _compile(fn, *args):
     compiled = jax.jit(fn).lower(*args).compile()
-    mem = compiled.memory_analysis()
-    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    used = _per_chip_bytes(compiled)
     assert used < HBM_BYTES, f"{used / 2**30:.1f} GiB does not fit one v5e"
     return compiled
 
@@ -133,3 +147,97 @@ def test_resnet18_serving_forward_compiles(chip):
         return model.apply(v, a, training=False)[0]
 
     _compile(fwd, _on(chip, bf16), _on(chip, x))
+
+
+def _abstract_train_step(est, mesh, x, y):
+    """``est``'s train step lowered for ``mesh`` from shapes alone, for a
+    strategy that replicates the parameters (``sharding="dp"``): the train
+    state as ``fit()`` would place it, the batch as the feed shards it.  A
+    described device holds no array, so nothing is placed and
+    ``_ensure_initialized`` is stood in for."""
+    from analytics_zoo_tpu.data import batch_sharding
+    replicated = NamedSharding(mesh, P())
+
+    def on(tree):
+        return jax.tree_util.tree_map(
+            lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype,
+                                           sharding=replicated), tree)
+
+    variables = jax.eval_shape(
+        lambda a: est.model.init(jax.random.PRNGKey(0), a, training=True), x)
+    est._ts = on({
+        "params": variables["params"], "state": variables["state"],
+        "opt_state": jax.eval_shape(est.tx.init, variables["params"]),
+        "step": jax.ShapeDtypeStruct((), jnp.int32),
+        "rng": jax.eval_shape(lambda: jax.random.PRNGKey(0)),
+        "bad_steps": jax.ShapeDtypeStruct((), jnp.int32)})
+    est._build_steps(mesh)
+    batch = {
+        "x": jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=batch_sharding(
+            mesh, x.ndim, seq_dim_size=x.shape[1], dim0_size=x.shape[0])),
+        "y": jax.ShapeDtypeStruct(y.shape, y.dtype, sharding=batch_sharding(
+            mesh, y.ndim, dim0_size=y.shape[0]))}
+    return est._train_step.lower(est._ts, batch)
+
+
+def _bert_base_step(mesh, global_batch, accum):
+    import json
+    from analytics_zoo_tpu.orca.learn import Estimator
+    from benchmark.families import bert_mlm
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark/configs/bert_base_mlm.json")) as f:
+        config = json.load(f)
+    est = Estimator.from_keras(
+        bert_mlm.build(config), loss=config["loss"],
+        optimizer=config["optimizer"]["name"],
+        learning_rate=config["optimizer"]["learning_rate"],
+        grad_accum=accum)
+    ids = jax.ShapeDtypeStruct((global_batch, 512), jnp.int32)
+    return _abstract_train_step(est, mesh, ids, ids).compile()
+
+
+def _megabytes(result_type):
+    """Bytes of an HLO result type such as ``(f32[768,768]{...}, f32[])``,
+    in MB."""
+    width = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "f16": 2}
+    total = 0
+    for dtype, dims in re.findall(r"\b(f32|s32|u32|bf16|f16)\[([\d,]*)\]",
+                                  result_type):
+        total += width[dtype] * int(np.prod(
+            [int(d) for d in dims.split(",") if d] or [1]))
+    return total / 1e6
+
+
+def test_bert_base_dp4_accum_step_computes_a_chips_rows_once(topo):
+    """The ``bert_base_fit_dp4`` cell's train step (BERT-base, seq 512,
+    global batch 128 = 4 chips x micro 16 x accum 2, mesh {data: 4}) for
+    the described 2x2: it fits a chip in the one-chip cell's memory, the
+    largest logits buffer is a chip's 16 rows of a micro-batch (the parent
+    held 32: GSPMD had replicated half of every micro-batch), and a chip's
+    FLOPs are the one-chip cell's (batch 32 on one device).
+
+    The gradient reduce: the TPU compiler's while-loop code motion moves
+    the all-reduce of each ``gsum + g`` out of the accumulation loop, so
+    436 of the 530 MB of f32 gradients meet once a step.  The exception is
+    the token table: XLA folds its gradient's scatter-add INTO the carry
+    (``scatter(gsum, ids, rows)``), which leaves no ``carry + all-reduce``
+    to move, so those 94 MB are reduced once a micro-batch (PERF.md,
+    section 7)."""
+    from hlo_loops import collectives
+    four = _bert_base_step(Mesh(np.asarray(topo.devices), ("data",)), 128, 2)
+    one = _bert_base_step(Mesh(np.asarray(topo.devices[:1]), ("data",)),
+                          32, 2)
+    assert _per_chip_bytes(four) < HBM_BYTES
+    assert _per_chip_bytes(four) <= 1.05 * _per_chip_bytes(one)
+    ratio = four.cost_analysis()["flops"] / one.cost_analysis()["flops"]
+    assert 0.98 < ratio < 1.02, ratio
+    text = four.as_text()
+    logits_rows = {int(m) for m in re.findall(
+        r"(?:f32|bf16)\[(\d+),512,30522\]", text)}
+    assert max(logits_rows) == 16, logits_rows
+    in_loop, outside = collectives(text)
+    assert set(in_loop) <= {"all-reduce"}, in_loop
+    looped = sum(map(_megabytes, in_loop.get("all-reduce", [])))
+    sunk = sum(map(_megabytes, outside.get("all-reduce", [])))
+    assert looped < 95 and sunk > 430, (looped, sunk)
+    assert collectives(one.as_text()) == ({}, {})
