@@ -4,7 +4,9 @@ Every family from the reference's Scala+Py twin zoo, rebuilt as pure-JAX
 modules over analytics_zoo_tpu.nn: recommendation (NeuralCF, WideAndDeep,
 SessionRecommender), text classification, text matching (KNRM), anomaly
 detection, seq2seq, image classification (ResNet), object detection (SSD),
-plus the BERT family the reference shipped through TFPark.
+plus the BERT family the reference shipped through TFPark, and a hybrid
+linear-attention / sparse-expert causal decoder (Qwen3Next) the reference
+had no analog of.
 """
 
 from .common import ZooModel
@@ -18,6 +20,7 @@ from .seq2seq import Seq2seq, RNNEncoder, RNNDecoder
 from .image import ImageClassifier, ResNet
 from .objectdetection import ObjectDetector, SSDLite, Visualizer
 from .bert import BERT, BERTClassifier, BERTNER, BERTSQuAD
+from .qwen3_next import Qwen3Next
 from .graphnet import GraphNet
 from .net import ForeignNet, Net
 
@@ -27,5 +30,5 @@ __all__ = [
     "UserItemFeature", "UserItemPrediction", "TextClassifier", "KNRM",
     "AnomalyDetector", "unroll", "Seq2seq", "RNNEncoder", "RNNDecoder",
     "ImageClassifier", "ResNet", "ObjectDetector", "SSDLite", "Visualizer",
-    "BERT", "BERTClassifier", "BERTNER", "BERTSQuAD",
+    "BERT", "BERTClassifier", "BERTNER", "BERTSQuAD", "Qwen3Next",
 ]

@@ -4,11 +4,12 @@ from . import activations, initializers, losses, metrics
 from .attention import (MultiHeadAttention, TransformerLayer,
                         dot_product_attention)
 from .layers import (Activation, Add, AveragePooling2D, BatchNormalization,
-                     Concatenate, Conv1D, Conv2D, Dense, Dropout, Embedding,
+                     CausalConv1D, Concatenate, Conv1D, Conv2D, Dense,
+                     Dropout, Embedding,
                      Flatten, GlobalAveragePooling1D, GlobalAveragePooling2D,
                      GlobalMaxPooling1D, GlobalMaxPooling2D, Lambda,
                      LayerNormalization, MaxPooling2D, Multiply, Reshape,
-                     ScaledWSConv2D, Sequential, ZeroPadding2D)
+                     RMSNorm, ScaledWSConv2D, SwiGLU, Sequential, ZeroPadding2D)
 from .layers_extra import (AveragePooling1D, AveragePooling3D, Average,
                            Conv2DTranspose, Conv3D, Cropping1D, Cropping2D,
                            Cropping3D, DepthwiseConv2D, Dot, ELU,
@@ -32,6 +33,7 @@ from .layers_zoo import (ActivityRegularization, AddConstant, AlphaDropout,
                          SoftShrink, Sqrt, Square, Threshold, WordEmbedding,
                          Merge, merge)
 from .functional import Input, Model, SymbolicTensor
+from .linear_attention import GatedDeltaNet
 from .module import Module, Scope, param_count
 from .recurrent import (GRU, LSTM, Bidirectional, SimpleRNN, TimeDistributed)
 
@@ -61,6 +63,7 @@ __all__ = [
     "GlobalAveragePooling2D", "GlobalMaxPooling2D", "GlobalAveragePooling1D",
     "GlobalMaxPooling1D", "ZeroPadding2D", "BatchNormalization",
     "LayerNormalization", "Concatenate", "Add", "Multiply", "Sequential",
+    "RMSNorm", "SwiGLU", "CausalConv1D", "GatedDeltaNet",
     "LSTM", "GRU", "SimpleRNN", "Bidirectional", "TimeDistributed",
     "MultiHeadAttention", "TransformerLayer", "dot_product_attention",
     # extended Keras-1.2 zoo (layers_extra)

@@ -19,18 +19,19 @@ import jax
 import jax.numpy as jnp
 
 from . import initializers
-from .layers import Dense, Dropout, LayerNormalization
+from .layers import Dense, Dropout, LayerNormalization, RMSNorm
 from .module import Module, Scope
 
 
 # use_flash="auto" switches to the Pallas flash kernel at this kv length.
-# Measured crossover (BERT-base, v5e, fixed global batch, ms/step best):
-#   seq  512: dense+remat  99.9 vs flash 124.6  -> dense wins
-#   seq 1024: dense+remat  67.1 vs flash  82.0  -> dense wins
-#   seq 2048: dense+remat 314.5 vs flash 201.6  -> flash 1.56x
-#   seq 4096: dense+remat 764.6 vs flash 377.0  -> flash 2.03x
-# Below ~2k the kernel's blocked-backward overhead exceeds the saved
-# T x T traffic; above it, not materializing the maps dominates.
+# The crossover timings that stood here (BERT-base, seq 512-4096) came through
+# a route to the device that is gone (PERF.md); none of them survives.  What
+# this constant has been read against since, on a directly attached v5e
+# (PR 27, one reading each, the whole layer with its projections): 16 query /
+# 2 kv heads of 256, causal, one row of 8192 tokens, forward 14.8 ms with the
+# kernel against 453.1 ms dense, forward + backward 49.2 ms against 938.7 ms
+# dense + remat; at two rows the dense path's [2, 16, 8192, 8192] float32
+# logits do not fit the chip.  Nothing has been read near 2048 itself.
 FLASH_AUTO_MIN_SEQ = 2048
 
 
@@ -59,14 +60,58 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
 
 
+def rotary_embedding(x: jax.Array, rotary_dim: int,
+                     theta: float = 10000.0) -> jax.Array:
+    """Rotary position embedding on the first ``rotary_dim`` dims of every
+    head of ``x`` ([B, T, H, D]; positions 0..T-1), the rest passed through.
+    Half-split pairing: dim ``i`` turns with dim ``i + rotary_dim/2`` by the
+    angle ``t * theta^(-2i/rotary_dim)``.  Angles and the rotation in
+    float32 (at theta 1e7 and T 8192 a bf16 angle is off by whole turns)."""
+    half = rotary_dim // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0
+                         / rotary_dim)
+    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:rotary_dim].astype(jnp.float32)
+    turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                             axis=-1).astype(x.dtype)
+    return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
+
+
 class MultiHeadAttention(Module):
+    """Multi-head attention, ``[B, T, D] -> [B, T, D]``.  The defaults are
+    the plain layer of the BERT family.  A decoder block of today's kind
+    sets, in any combination: ``num_kv_heads`` (grouped-query attention:
+    each key/value head serves ``num_heads / num_kv_heads`` query heads),
+    ``qk_norm`` (a zero-centred RMSNorm over the head dim on q and on k,
+    before the rotation), ``rotary_dim`` / ``rope_theta`` (rotary embedding
+    on the first ``rotary_dim`` dims of each head; self-attention from
+    position 0), ``gate`` (``wq`` is twice as wide, split a head into query
+    and gate; the context is multiplied by ``sigmoid(gate)`` before ``wo``).
+    All of them go through the one dense / flash / ring dispatch below."""
+
     def __init__(self, num_heads: int, head_dim: Optional[int] = None,
                  dropout: float = 0.0,
                  use_flash: Union[bool, str] = False,
                  use_ring: bool = False, causal: bool = False,
                  remat: bool = False, dtype: Optional[Any] = None,
+                 num_kv_heads: Optional[int] = None, qk_norm: bool = False,
+                 rotary_dim: int = 0, rope_theta: float = 10000.0,
+                 gate: bool = False, norm_epsilon: float = 1e-6,
                  name: Optional[str] = None):
         super().__init__(name)
+        if num_kv_heads is not None and num_heads % num_kv_heads:
+            raise ValueError(f"num_heads {num_heads} is not a multiple of "
+                             f"num_kv_heads {num_kv_heads}")
+        if rotary_dim % 2:
+            raise ValueError(f"rotary_dim must be even; got {rotary_dim}")
+        self.num_kv_heads = num_kv_heads or num_heads
+        self.qk_norm = qk_norm
+        self.rotary_dim = rotary_dim
+        self.rope_theta = rope_theta
+        self.gate = gate
+        self.norm_epsilon = norm_epsilon
         if use_flash not in (True, False, "auto"):
             raise ValueError(
                 f"use_flash must be True, False, or 'auto'; got "
@@ -110,16 +155,33 @@ class MultiHeadAttention(Module):
         d_head = self.head_dim or d_model // h
         init = initializers.get("glorot_uniform")
 
-        def proj(name: str, src: jax.Array) -> jax.Array:
-            w = scope.param(name, init, (src.shape[-1], h * d_head))
+        def proj(name: str, src: jax.Array, heads: int = h,
+                 width: int = d_head) -> jax.Array:
+            w = scope.param(name, init, (src.shape[-1], heads * width))
             # same-dtype dot: an f32-preferred output downcast right after
             # would make both vjp matmuls mixed f32 x bf16 (see Dense)
             y = jnp.dot(src, w.astype(src.dtype))
-            return y.reshape(src.shape[:-1] + (h, d_head))
+            return y.reshape(src.shape[:-1] + (heads, width))
 
-        q = proj("wq", x)
-        k = proj("wk", kv)
-        v = proj("wv", kv)
+        kv_h = self.num_kv_heads
+        gate = None
+        if self.gate:
+            q = proj("wq", x, width=2 * d_head)
+            q, gate = q[..., :d_head], q[..., d_head:]
+        else:
+            q = proj("wq", x)
+        k = proj("wk", kv, kv_h)
+        v = proj("wv", kv, kv_h)
+        if self.qk_norm:
+            norm = RMSNorm(self.norm_epsilon, zero_centered=True)
+            q = scope.child(norm, q, name="q_norm")
+            k = scope.child(norm, k, name="k_norm")
+        if self.rotary_dim:
+            q = rotary_embedding(q, self.rotary_dim, self.rope_theta)
+            k = rotary_embedding(k, self.rotary_dim, self.rope_theta)
+        if kv_h != h:  # query head i reads key/value head i // (h / kv_h)
+            k = jnp.repeat(k, h // kv_h, axis=2)
+            v = jnp.repeat(v, h // kv_h, axis=2)
 
         use_flash = self.use_flash
         if use_flash == "auto":
@@ -141,6 +203,9 @@ class MultiHeadAttention(Module):
                     else dot_product_attention)
             ctx = attn(q, k, v, mask)
 
+        if gate is not None:
+            ctx = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)
+                                       ).astype(ctx.dtype)
         wo = scope.param("wo", init, (h * d_head, d_model))
         out = jnp.dot(ctx.reshape(x.shape[:-1] + (h * d_head,)),
                       wo.astype(x.dtype))

@@ -662,11 +662,16 @@ class Remat(Module):
     reference had no analog because BigDL kept all activations.
 
     ``Remat(TransformerLayer(8))`` drops the block's activation footprint
-    to its inputs + outputs at ~1.3x compute."""
+    to its inputs + outputs at ~1.3x compute.  ``save_names``: values the
+    inner module tagged with ``jax.ad_checkpoint.checkpoint_name`` that are
+    kept all the same (``ops.flash_attention`` tags its output and
+    log-sum-exp: small beside what recomputing the kernel costs)."""
 
-    def __init__(self, inner: Module, name: Optional[str] = None):
+    def __init__(self, inner: Module, name: Optional[str] = None,
+                 save_names: Sequence[str] = ()):
         super().__init__(name or (inner.name and f"remat_{inner.name}"))
         self.inner = inner
+        self.save_names = tuple(save_names)
 
     def forward(self, scope: Scope, x: jax.Array, **kwargs: Any) -> jax.Array:
         name = self.inner.name or "inner"
@@ -687,7 +692,9 @@ class Remat(Module):
                                          **kwargs)
             return out, new_state
 
-        out, new_state = jax.checkpoint(fn)(params, x)
+        policy = (jax.checkpoint_policies.save_only_these_names(
+            *self.save_names) if self.save_names else None)
+        out, new_state = jax.checkpoint(fn, policy=policy)(params, x)
         if new_state or state_in:
             scope.state[name] = new_state
         return out

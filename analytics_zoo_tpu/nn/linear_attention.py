@@ -1,0 +1,251 @@
+"""Linear attention with a matrix-valued recurrent state: the gated delta
+rule (Gated DeltaNet; Yang, Kautz & Hatamizadeh 2024, arXiv:2412.06464) in
+chunked form, and the mixer layer built on it.
+
+Per value head the state ``S`` (``[d_k, d_v]``, zero at the sequence's
+start) follows, position by position::
+
+    S <- exp(g_t) * S                 # forget      (g_t <= 0)
+    u  = beta_t * (v_t - S^T k_t)     # what the key does not yet recall
+    S <- S + k_t u^T                  # write
+    o_t = S^T q_t                     # read
+
+Absent from the reference (its recurrent layers are LSTM/GRU,
+``nn/recurrent.py``).  Run that way the recurrence is T dependent steps of
+rank-one updates: nothing for the MXU.  The chunked form (the WY
+representation of a product of Householder-like factors) turns ``C``
+positions into dense products: inside a chunk the ``u`` of every position
+solve one unit-lower-triangular system, ``(I + tril(diag(beta) (K K^T * D),
+-1)) U = diag(beta) (V - (K * d) S)``, and only the chunk-to-chunk carry of
+``S`` is sequential: ``T / C`` steps of two ``[C, d] x [d, d]`` products a
+head.  Everything outside that carry is computed for all chunks at once.
+The backward pass is autodiff through the same program (``lax.scan``'s
+transpose walks the chunks in reverse); wrap the layer in ``nn.Remat`` to
+keep a block's chunk states out of the saved activations.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.ad_checkpoint import checkpoint_name
+
+from .layers import CausalConv1D, Dense, RMSNorm
+from .module import Module, Scope
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+@jax.custom_vjp
+def _unit_lower_inverse(a: jax.Array) -> jax.Array:
+    """``(I + A)^-1`` for strictly lower-triangular ``A`` (``[..., C, C]``,
+    float32).  ``A`` is nilpotent, so the Neumann series ends:
+    ``(I + A)^-1 = (I - A)(I + A^2)(I + A^4)...`` — log2(C) squarings, all
+    dense products (a triangular solve would be C dependent steps).  Full
+    float32 products: the result multiplies every value of the chunk.  The
+    gradient is ``-M^T dM M^T`` from the result ``M`` alone, so the powers
+    are not kept for the backward pass."""
+    c = a.shape[-1]
+    eye = jnp.eye(c, dtype=a.dtype)
+    inv = eye - a
+    power = a
+    for _ in range(max(0, int(np.ceil(np.log2(c))) - 1)):
+        power = jnp.matmul(power, power, precision=_HIGHEST)
+        inv = jnp.matmul(inv, eye + power, precision=_HIGHEST)
+    return inv
+
+
+def _unit_lower_inverse_fwd(a):
+    inv = _unit_lower_inverse(a)
+    return inv, inv
+
+
+def _unit_lower_inverse_bwd(inv, g):
+    inv_t = jnp.swapaxes(inv, -1, -2)
+    return (-jnp.matmul(jnp.matmul(inv_t, g, precision=_HIGHEST), inv_t,
+                        precision=_HIGHEST),)
+
+
+_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
+                     g: jax.Array, beta: jax.Array, chunk: int = 64,
+                     initial_state: Optional[jax.Array] = None,
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """The recurrence of this module's docstring, in chunks of ``chunk``.
+
+    q, k: ``[B, T, H, d_k]``; v: ``[B, T, H, d_v]``; g (log decay, <= 0) and
+    beta: ``[B, T, H]`` float32.  q and k are used as given (normalise and
+    scale them outside).  Returns ``(o [B, T, H, d_v] in v's dtype, final
+    state [B, H, d_k, d_v] float32)``.  Matmul operands keep q/k/v's dtype
+    (bf16 on the MXU), sums and the state are float32.  Any T: the tail is
+    padded with g = 0, beta = 0 (a position that neither forgets nor
+    writes) and cut off again.
+    """
+    b, t, h, dk = k.shape
+    dv = v.shape[-1]
+    dt = v.dtype
+    pad = -t % chunk
+    if pad:
+        q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for a in (q, k, v))
+        g, beta = (jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
+                   for a in (g, beta))
+    n = (t + pad) // chunk
+
+    def chunks(a):  # [B, T, H, ...] -> [B, H, N, C, ...]
+        a = a.reshape((b, n, chunk) + a.shape[2:])
+        return jnp.moveaxis(a, 3, 1)
+
+    f32 = dict(preferred_element_type=jnp.float32)
+
+    def chunk_terms(q, k, v, g, beta):
+        """Everything that needs no state, for all chunks at once."""
+        g = jnp.cumsum(g, axis=-1)                           # [B,H,N,C]
+        # decay from position j to position i of a chunk, i >= j.  Masked
+        # before the exp: above the diagonal g_i - g_j > 0 can overflow, and
+        # an inf in the branch a ``where`` drops still poisons its gradient
+        causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+        decay = jnp.exp(jnp.where(causal, g[..., :, None] - g[..., None, :],
+                                  -jnp.inf))
+        k_beta = (k * beta[..., None]).astype(dt)
+        a = jnp.einsum("...ik,...jk->...ij", k_beta, k, **f32) * decay
+        a = jnp.where(jnp.tril(jnp.ones((chunk, chunk), bool), -1), a, 0.0)
+        solve = checkpoint_name(_unit_lower_inverse(a).astype(dt),
+                                "gdn_inverse")               # [B,H,N,C,C]
+        # U = T (beta V), W = T (beta K * exp(g)): the chunk's writes given
+        # a zero state, and what the incoming state takes away from them
+        u = jnp.einsum("...ij,...jd->...id", solve,
+                       (v * beta[..., None]).astype(dt), **f32)
+        w = jnp.einsum("...ij,...jd->...id", solve,
+                       (k_beta * jnp.exp(g)[..., None]).astype(dt), **f32)
+        g_last = g[..., -1:]
+        k_tail = k * jnp.exp(g_last - g)[..., None]
+        qk = jnp.einsum("...ik,...jk->...ij", q, k, **f32) * decay
+        q_in = q * jnp.exp(g)[..., None]
+        return (u, w.astype(dt), k_tail.astype(dt), jnp.exp(g_last),
+                qk.astype(dt), q_in.astype(dt))
+
+    # under an enclosing nn.Remat the block's forward is recomputed once for
+    # the backward pass; the [C, C] float32 terms of every chunk would be its
+    # largest saved activations, so they are recomputed once more instead:
+    # all but the inverse, which is a dozen passes over them and is kept
+    u, w, k_tail, decay_last, qk, q_in = jax.checkpoint(
+        chunk_terms,
+        policy=jax.checkpoint_policies.save_only_these_names("gdn_inverse"))(
+        chunks(q), chunks(k), chunks(v), chunks(g.astype(jnp.float32)),
+        chunks(beta.astype(jnp.float32)))
+
+    def carry(s, xs):
+        w_i, u_i, k_i, decay_i = xs
+        s_in = s.astype(dt)
+        v_new = (u_i - jnp.einsum("bhck,bhkd->bhcd", w_i, s_in, **f32)
+                 ).astype(dt)
+        s_next = s * decay_i[..., None] + jnp.einsum(
+            "bhck,bhcd->bhkd", k_i, v_new, **f32)
+        return s_next, (s_in, v_new)
+
+    s0 = (jnp.zeros((b, h, dk, dv), jnp.float32) if initial_state is None
+          else initial_state.astype(jnp.float32))
+    lead = lambda x: jnp.moveaxis(x, 2, 0)                   # N first
+    s_final, (s_in, v_new) = jax.lax.scan(
+        carry, s0, (lead(w), lead(u), lead(k_tail), lead(decay_last)))
+    s_in, v_new = jnp.moveaxis(s_in, 0, 2), jnp.moveaxis(v_new, 0, 2)
+
+    # read: what the incoming state answers, plus the chunk's own writes
+    o = jnp.einsum("...ck,...kd->...cd", q_in, s_in, **f32)
+    o = o + jnp.einsum("...ij,...jd->...id", qk, v_new, **f32)
+    o = jnp.moveaxis(o, 1, 3).reshape(b, n * chunk, h, dv)[:, :t]
+    return o.astype(dt), s_final
+
+
+def _dt_bias_init(low: float = 1e-3, high: float = 0.1):
+    """``softplus^-1(dt)`` with ``dt`` log-uniform in [low, high]: a head
+    forgets ``exp(A_log) * dt`` a position, from almost nothing to a few
+    tenths (the initialiser of the Gated DeltaNet reference code)."""
+    def init(rng, shape, dtype=jnp.float32):
+        dt = jnp.exp(jax.random.uniform(rng, shape, jnp.float32,
+                                        np.log(low), np.log(high)))
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+    return init
+
+
+def _a_log_init(high: float = 16.0):
+    def init(rng, shape, dtype=jnp.float32):
+        return jnp.log(jax.random.uniform(rng, shape, jnp.float32, 1e-4,
+                                          high)).astype(dtype)
+    return init
+
+
+class GatedDeltaNet(Module):
+    """Gated DeltaNet mixer: ``[B, T, D] -> [B, T, D]``, causal.
+
+    ``in_proj_qkvz`` gives q, k (``num_k_heads`` of ``k_head_dim``) and v, z
+    (``num_v_heads`` of ``v_head_dim``); ``in_proj_ba`` one write strength
+    ``beta = sigmoid(b)`` and one forget gate ``g = -exp(A_log) *
+    softplus(a + dt_bias)`` a value head (float32).  q, k, v pass a
+    depthwise causal convolution of ``conv_kernel`` positions and a SiLU;
+    q and k are L2-normalised over the head, q scaled by
+    ``1/sqrt(k_head_dim)``; each key head serves ``num_v_heads /
+    num_k_heads`` value heads.  The recurrence is :func:`gated_delta_rule`;
+    its output is RMS-normalised a head, gated by ``silu(z)`` and projected
+    back.  No biases.  Holds no cache: a sequence starts from a zero state.
+    """
+
+    def __init__(self, num_k_heads: int, num_v_heads: int, k_head_dim: int,
+                 v_head_dim: int, conv_kernel: int = 4, chunk: int = 64,
+                 epsilon: float = 1e-6, kernel_init: Any = "glorot_uniform",
+                 name: Optional[str] = None):
+        super().__init__(name)
+        if num_v_heads % num_k_heads:
+            raise ValueError(f"num_v_heads {num_v_heads} is not a multiple "
+                             f"of num_k_heads {num_k_heads}")
+        self.num_k_heads, self.num_v_heads = num_k_heads, num_v_heads
+        self.k_head_dim, self.v_head_dim = k_head_dim, v_head_dim
+        self.conv_kernel, self.chunk = conv_kernel, chunk
+        self.epsilon = epsilon
+        self.kernel_init = kernel_init
+
+    def forward(self, scope: Scope, x: jax.Array) -> jax.Array:
+        b, t, d = x.shape
+        hk, hv = self.num_k_heads, self.num_v_heads
+        dk, dv = self.k_head_dim, self.v_head_dim
+        key_dim, value_dim = hk * dk, hv * dv
+
+        def dense(units):
+            return Dense(units, use_bias=False, kernel_init=self.kernel_init)
+        qkvz = scope.child(dense(2 * key_dim + 2 * value_dim), x,
+                           name="in_proj_qkvz")
+        ba = scope.child(dense(2 * hv), x, name="in_proj_ba")
+        qkv = scope.child(CausalConv1D(self.conv_kernel, activation="silu"),
+                          qkvz[..., :2 * key_dim + value_dim], name="conv")
+        z = qkvz[..., 2 * key_dim + value_dim:].reshape(b, t, hv, dv)
+        q = qkv[..., :key_dim].reshape(b, t, hk, dk)
+        k = qkv[..., key_dim:2 * key_dim].reshape(b, t, hk, dk)
+        v = qkv[..., 2 * key_dim:].reshape(b, t, hv, dv)
+
+        a_log = scope.param("A_log", _a_log_init(), (hv,))
+        dt_bias = scope.param("dt_bias", _dt_bias_init(), (hv,))
+        ba = ba.astype(jnp.float32)
+        beta = jax.nn.sigmoid(ba[..., :hv])
+        g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias)
+
+        def l2norm(a):
+            af = a.astype(jnp.float32)
+            return af * jax.lax.rsqrt(
+                jnp.square(af).sum(-1, keepdims=True) + self.epsilon)
+        q = (l2norm(q) * dk ** -0.5).astype(x.dtype)
+        k = l2norm(k).astype(x.dtype)
+        if hv != hk:  # value head i reads key head i // (hv / hk)
+            q = jnp.repeat(q, hv // hk, axis=2)
+            k = jnp.repeat(k, hv // hk, axis=2)
+        o, _ = gated_delta_rule(q, k, v, g, beta, chunk=self.chunk)
+
+        o = scope.child(RMSNorm(self.epsilon), o, name="norm")
+        o = o * jax.nn.silu(z.astype(jnp.float32)).astype(o.dtype)
+        return scope.child(dense(d), o.reshape(b, t, value_dim),
+                           name="out_proj")

@@ -24,6 +24,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -129,6 +130,7 @@ def _flash_fwd_pallas(q, k, v, scale, causal, block_q, block_k, true_tk,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",  # the op's name in HLO and in a profile
     )(q, k, v)
     return out, lse
 
@@ -181,8 +183,20 @@ def _blocked_fwd_jax(q, k, v, scale, causal, block_k):
     return out, lse
 
 
+#: the causal backward walks the k blocks in this many groups, each with
+#: the queries that can see it (see _blocked_bwd_jax)
+_CAUSAL_BWD_GROUPS = 8
+
+
 def _blocked_bwd_jax(q, k, v, out, lse, g, scale, causal, block_k):
-    """Flash-attention-2 style backward: rematerialize p per k block."""
+    """Flash-attention-2 style backward: rematerialize p per k block.
+
+    Causal self-attention needs only the lower triangle: queries before a
+    k block's first position get nothing from it.  A ``lax.scan`` wants one
+    shape for all its steps, so the k blocks are walked in up to
+    ``_CAUSAL_BWD_GROUPS`` groups and each group's scan takes the queries
+    from the group's first key on: (G + 1) / 2G of the full square's work
+    (56% at G = 8) instead of all of it."""
     bh, tq, d = q.shape
     tk = k.shape[1]
     tk_p = _ceil_to(tk, block_k)
@@ -193,31 +207,52 @@ def _blocked_bwd_jax(q, k, v, out, lse, g, scale, causal, block_k):
     gf = g.astype(jnp.float32)
     of = out.astype(jnp.float32)
     delta = jnp.sum(of * gf, axis=-1, keepdims=True)        # [BH, Tq, 1]
-    qpos = jnp.arange(tq)[:, None]
     kb = kp.reshape(bh, nk, block_k, d).swapaxes(0, 1)
     vb = vp.reshape(bh, nk, block_k, d).swapaxes(0, 1)
 
-    def step(dq, blk):
-        kj, vj, j = blk
-        kjf = kj.astype(jnp.float32)
-        vjf = vj.astype(jnp.float32)
-        s = jnp.einsum("bqd,bkd->bqk", qf, kjf,
-                       preferred_element_type=jnp.float32) * scale
-        kpos = j * block_k + jnp.arange(block_k)[None, :]
-        mask = kpos < tk
-        if causal:
-            mask = mask & (qpos >= kpos)
-        s = jnp.where(mask, s, _NEG_INF)
-        p = jnp.exp(s - lse[..., None])                      # softmax probs
-        dp = jnp.einsum("bqd,bkd->bqk", gf, vjf)
-        ds = p * (dp - delta) * scale
-        dq = dq + jnp.einsum("bqk,bkd->bqd", ds, kjf)
-        dk_j = jnp.einsum("bqk,bqd->bkd", ds, qf)
-        dv_j = jnp.einsum("bqk,bqd->bkd", p, gf)
-        return dq, (dk_j, dv_j)
+    def walk(first_block, blocks, row0):
+        """k blocks ``first_block ..`` against the queries from ``row0``."""
+        qf_, gf_, lse_, delta_ = (a[:, row0:] for a in (qf, gf, lse, delta))
+        qpos = row0 + jnp.arange(tq - row0)[:, None]
 
-    dq0 = jnp.zeros((bh, tq, d), jnp.float32)
-    dq, (dk, dv) = jax.lax.scan(step, dq0, (kb, vb, jnp.arange(nk)))
+        def step(dq, blk):
+            kj, vj, j = blk
+            kjf = kj.astype(jnp.float32)
+            vjf = vj.astype(jnp.float32)
+            s = jnp.einsum("bqd,bkd->bqk", qf_, kjf,
+                           preferred_element_type=jnp.float32) * scale
+            kpos = j * block_k + jnp.arange(block_k)[None, :]
+            mask = kpos < tk
+            if causal:
+                mask = mask & (qpos >= kpos)
+            s = jnp.where(mask, s, _NEG_INF)
+            p = jnp.exp(s - lse_[..., None])                 # softmax probs
+            dp = jnp.einsum("bqd,bkd->bqk", gf_, vjf)
+            ds = p * (dp - delta_) * scale
+            dq = dq + jnp.einsum("bqk,bkd->bqd", ds, kjf)
+            dk_j = jnp.einsum("bqk,bqd->bkd", ds, qf_)
+            dv_j = jnp.einsum("bqk,bqd->bkd", p, gf_)
+            return dq, (dk_j, dv_j)
+
+        sel = slice(first_block, first_block + blocks)
+        return jax.lax.scan(
+            step, jnp.zeros((bh, tq - row0, d), jnp.float32),
+            (kb[sel], vb[sel], jnp.arange(first_block, first_block + blocks)))
+
+    groups = 1
+    if causal and tq == tk:
+        groups = max(n for n in range(1, _CAUSAL_BWD_GROUPS + 1)
+                     if nk % n == 0)
+    per = nk // groups
+    dq = jnp.zeros((bh, tq, d), jnp.float32)
+    dks, dvs = [], []
+    for i in range(groups):
+        row0 = i * per * block_k
+        dq_i, (dk_i, dv_i) = walk(i * per, per, row0)
+        dq = dq.at[:, row0:].add(dq_i) if row0 else dq_i
+        dks.append(dk_i)
+        dvs.append(dv_i)
+    dk, dv = jnp.concatenate(dks), jnp.concatenate(dvs)
     dk = dk.swapaxes(0, 1).reshape(bh, tk_p, d)[:, :tk]
     dv = dv.swapaxes(0, 1).reshape(bh, tk_p, d)[:, :tk]
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
@@ -281,6 +316,11 @@ def _padded_pallas(q3, k3, v3, scale, causal, block_q, block_k, interpret):
 
 def _flash_vjp_fwd(q3, k3, v3, causal, block_q, block_k):
     out, lse = _flash_fwd_dispatch(q3, k3, v3, causal, block_q, block_k)
+    # named, so that an enclosing jax.checkpoint can be told to keep them
+    # (policy save_only_these_names): [BH, T, D] + [BH, T] kept spare the
+    # recomputation a second run of the whole kernel
+    out = checkpoint_name(out, "flash_attention_out")
+    lse = checkpoint_name(lse, "flash_attention_lse")
     return out, (q3, k3, v3, out, lse)
 
 

@@ -369,6 +369,9 @@ class ZooEstimator:
         self._pred_step = None
         self._epoch = 0
         self._py_step = 0  # host-side mirror of ts["step"] (no device sync)
+        # device-side counters (layers' ``counters`` state) at their last
+        # read; None = never read, and a fresh state starts them at zero
+        self._counters_seen: Optional[Dict[str, Any]] = None
         # jax.profiler integration (SURVEY.md §5.1 tracing parity): capture
         # a device trace for steps [start, end) into profile_dir, viewable
         # in TensorBoard/XProf/Perfetto
@@ -1287,15 +1290,22 @@ class ZooEstimator:
                 # loss but did not touch params — exclude them from the
                 # epoch mean and read back the on-device bad counter.
                 stacked = jnp.stack(losses)
+                epoch_loss = (jnp.nanmean(stacked)
+                              if self.nan_policy == "skip_step"
+                              else stacked.mean())
+                # layers' device-side counters ride the loss's read-back
+                epoch_loss, counted = jax.device_get(
+                    (epoch_loss, _state_counters(self._ts["state"])))
+                epoch_loss = float(epoch_loss)
+                if counted:
+                    self._counters_seen = _publish_counters(
+                        reg, counted, self._counters_seen)
                 if self.nan_policy == "skip_step":
-                    epoch_loss = float(jnp.nanmean(stacked))
                     self.bad_steps = int(self._ts["bad_steps"])
                     if self.bad_steps > bad_before:
                         # the in-jit guard counted on device; sync the
                         # registry mirror once per epoch
                         m_bad.inc(self.bad_steps - bad_before)
-                else:
-                    epoch_loss = float(stacked.mean())
                 t_drained = time.monotonic()  # the read-back returned
                 history["loss"].append(epoch_loss)
                 if self.nan_policy is not None:
@@ -1658,6 +1668,11 @@ class ZooEstimator:
             tree = ckpt_io.restore(path, mesh=mesh)
             extra = ckpt_io.load_extra(path)
         self._py_step = int(np.asarray(tree["step"]))
+        # counters restored with the state were published by the run that
+        # saved them
+        self._counters_seen = {
+            k: np.asarray(v, np.int64) for k, v in jax.device_get(
+                _state_counters(tree.get("state", {}))).items()} or None
         if self.nan_policy == "skip_step":
             # sync the host mirror with the restored on-device counter so
             # the first post-resume epoch reports only ITS bad steps, not
@@ -1878,6 +1893,38 @@ def _to_local_rows(out: jax.Array) -> np.ndarray:
         return rows[jax.process_index() * local:
                     (jax.process_index() + 1) * local]
     return rows
+
+
+def _state_counters(state: Any) -> Dict[str, Any]:
+    """The leaves a layer keeps under a ``counters`` key of its state:
+    ``{"<path>/<series>": leaf}``.  A layer counts on the device, adding to
+    its own state every step (int32, which wraps); the host reads the totals
+    once an epoch with the loss, so counting costs the batch loop no sync."""
+    out: Dict[str, Any] = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(state)[0]:
+        keys = [getattr(p, "key", None) for p in path]
+        if len(keys) >= 2 and keys[-2] == "counters":
+            out["/".join(map(str, keys))] = leaf
+    return out
+
+
+def _publish_counters(reg: Any, now: Dict[str, Any],
+                      seen: Optional[Dict[str, Any]]) -> Dict[str, Any]:
+    """Growth of each device-side counter since the last read, into the
+    registry series of the leaf's own name (the last key of its path;
+    layers of one kind share a series).  A scalar is a counter.  A vector
+    counts per slot (rows per expert); the registry has no per-slot series,
+    so its growth's imbalance, largest slot over the mean, is observed in a
+    histogram, once a layer and read."""
+    for path, total in now.items():
+        series = path.rsplit("/", 1)[1]
+        before = (seen or {}).get(path, 0)
+        grew = (np.asarray(total, np.int64) - before) % (1 << 32)
+        if grew.ndim == 0:
+            reg.counter(series).inc(int(grew))
+        elif grew.sum() > 0:
+            reg.histogram(series).observe(float(grew.max() / grew.mean()))
+    return {k: np.asarray(v, np.int64) for k, v in now.items()}
 
 
 def _collect_aux_losses(state: Any) -> jax.Array:

@@ -12,11 +12,12 @@ way, so span naming can't drift undocumented either:
   ``core/metrics.py`` registry is found by scanning ``analytics_zoo_tpu``
   sources for ``counter("...")`` / ``gauge("...")`` /
   ``histogram("...")`` / ``inc("...")`` / ``observe("...")`` /
-  ``set_gauge("...")`` string literals, PLUS the three known dynamic
+  ``set_gauge("...")`` string literals, PLUS the four known dynamic
   registration sites (``"client." + key`` over the client's stats dict,
   ``"server." + k`` over the server's counters dict, ``"frontend." +
-  key`` over ``_FRONTEND_COUNTERS``) whose key sets are extracted from
-  the same files;
+  key`` over ``_FRONTEND_COUNTERS``, ``"moe." + key`` over the expert
+  layer's ``COUNTER_KEYS``) whose key sets are extracted from the same
+  files;
 - **spans, code side**: every span name recorded through ``core/trace.py``
   — the second argument of ``trace.record(...)`` / ``trace_lib.record``
   call sites and the first argument of ``trace.span("...")`` /
@@ -64,6 +65,10 @@ _DYNAMIC = [
      re.compile(r"self\._counters = \{([^}]*)\}", re.S)),
     ("serving/http_frontend.py", "frontend.",
      re.compile(r"_FRONTEND_COUNTERS = \(([^)]*)\)", re.S)),
+    # device-side counters: kept in the layer's state, published by the
+    # Estimator at the epoch's read-back under "moe." + key
+    ("parallel/moe.py", "moe.",
+     re.compile(r"COUNTER_KEYS = \(([^)]*)\)", re.S)),
 ]
 
 _KEY = re.compile(r'"([a-z0-9_]+)"')

@@ -241,3 +241,50 @@ def test_bert_base_dp4_accum_step_computes_a_chips_rows_once(topo):
     sunk = sum(map(_megabytes, outside.get("all-reduce", [])))
     assert looped < 95 and sunk > 430, (looped, sunk)
     assert collectives(one.as_text()) == ({}, {})
+
+
+def test_bert_base_one_chip_step_is_the_program_pr26_recorded(topo):
+    """``bert_base_fit_s512``'s train step, compiled for one described chip:
+    the FLOPs and bytes PERF.md records for PR 26, to the byte.  A change to
+    code the BERT cells share (``MultiHeadAttention``, ``Dense``, the loss,
+    the train step) that means to leave them alone shows it here."""
+    one = _bert_base_step(Mesh(np.asarray(topo.devices[:1]), ("data",)),
+                          32, 2)
+    cost = one.cost_analysis()
+    assert int(cost["flops"]) == 5922331557888
+    assert int(cost["bytes accessed"]) == 61243039744
+    assert one.memory_analysis().temp_size_in_bytes == 4455141888
+
+
+def test_qwen3next_cell_train_step_compiles_for_one_chip(topo, monkeypatch):
+    """``qwen3next_ep16_fit_s8192``'s train step at its real sizes (625.7 M
+    parameters with AdamW's moments, two rows of 8192 tokens): the chip's
+    compiler takes it — a program over the chip's memory is refused here —
+    with the flash kernel under its name, the grouped matmuls of the expert
+    layer as XLA's ragged-dot kernels, and one kernel call a step (the
+    blocks' recomputation keeps the kernel's output)."""
+    import json
+    from analytics_zoo_tpu.orca.learn import Estimator
+    from benchmark.families import qwen3_next
+    # default_backend() is "cpu" here: take the branch the chip takes
+    monkeypatch.setattr(
+        fa, "_flash_fwd_dispatch",
+        lambda q, k, v, causal, bq, bk: fa._padded_pallas(
+            q, k, v, q.shape[-1] ** -0.5, causal, bq, bk, interpret=False))
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark/configs/qwen3_next_80b_a3b_ep16.json")) as f:
+        config = json.load(f)
+    est = Estimator.from_keras(
+        qwen3_next.build(config), loss=config["loss"],
+        optimizer=config["optimizer"]["name"],
+        learning_rate=config["optimizer"]["learning_rate"])
+    ids = jax.ShapeDtypeStruct((2, 8192), jnp.int32)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    text = _abstract_train_step(est, mesh, ids, ids).compile().as_text()
+    kernels = re.findall(r"%(flash_attention_fwd[.\d]*) = ", text)
+    assert len(kernels) == 1, kernels
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) >= 6 * 4
+    held = sum(int(np.prod(l.shape)) for l in
+               jax.tree_util.tree_leaves(est._ts["params"]))
+    assert held == 625_667_136
