@@ -1,7 +1,7 @@
 """Data layer: XShards, file readers, device feed (reference L4, SURVEY.md §2.2)."""
 
-from .feed import (DataFeed, PrefetchIterator, as_feed, batch_sharding,
-                   shard_batch)
+from .feed import (DataFeed, EpochEnd, PrefetchIterator, as_feed,
+                   batch_sharding, shard_batch)
 from .readers import (FileReadahead, read_csv, read_json, read_npz,
                       read_parquet)
 from .shards import XShards
@@ -20,8 +20,8 @@ from .interop import (IterableDataFeed, from_iterator, from_tf_dataset,
 from . import readers as pandas  # noqa: F401
 
 __all__ = [
-    "XShards", "DataFeed", "PrefetchIterator", "as_feed", "batch_sharding",
-    "shard_batch",
+    "XShards", "DataFeed", "EpochEnd", "PrefetchIterator", "as_feed",
+    "batch_sharding", "shard_batch",
     "read_csv", "read_json", "read_npz", "read_parquet", "pandas",
     "FileReadahead", "StreamingDataFeed", "make_placer", "ShmBatchPool",
     "SlotBatch", "DeviceAugment", "DeviceNormalize", "DeviceRandomCrop",

@@ -111,6 +111,57 @@ def shard_batch(batch: Any, mesh: Mesh) -> Any:
     return jax.tree_util.tree_map(lambda l: place(l, True), batch)
 
 
+class EpochEnd:
+    """What a multi-epoch iterator (``FeedBase.epochs``) yields after the
+    last batch of epoch ``epoch``: the consumer tells epochs apart by it,
+    not by ``StopIteration``."""
+
+    __slots__ = ("epoch",)
+
+    def __init__(self, epoch: int):
+        self.epoch = epoch
+
+    def __repr__(self) -> str:
+        return f"EpochEnd({self.epoch})"
+
+
+class EpochsIterator:
+    """What ``FeedBase.epochs`` returns: an iterator over a generator
+    (``_gen``) of batches and ``EpochEnd`` markers that can be closed and
+    asked whether its next batch is decoded already."""
+
+    _gen: Any  # the subclass's generator
+
+    def __iter__(self) -> "EpochsIterator":
+        return self
+
+    def __next__(self):
+        return next(self._gen)
+
+    def next_is_ready(self) -> bool:
+        return False
+
+    def close(self) -> None:
+        self._gen.close()
+
+
+class EpochChain(EpochsIterator):
+    """``FeedBase.epochs``' default: one ``epoch()`` after another, an
+    ``EpochEnd`` behind each.  Nothing of epoch k+1 exists before epoch
+    k's last batch has been handed out, so nothing is ever ready ahead
+    (a ``PrefetchIterator`` over the chain still runs it ahead of the
+    consumer by its depth)."""
+
+    def __init__(self, open_epoch, first: int, last: int):
+        self._gen = self._run(open_epoch, first, last)
+
+    @staticmethod
+    def _run(open_epoch, first: int, last: int):
+        for e in range(first, last):
+            yield from open_epoch(e)  # closing the chain closes the epoch
+            yield EpochEnd(e)
+
+
 class FeedBase:
     """Shared feed contract: global-vs-local batch math, epoch step count,
     and the per-epoch shuffle index.  ``batch_size`` is the **global** batch
@@ -152,6 +203,15 @@ class FeedBase:
         if len(sel) < self._local_batch:  # pad the last partial batch
             sel = np.resize(sel, self._local_batch)
         return sel
+
+    def epochs(self, mesh: Mesh, first: int, last: int, **kw: Any):
+        """One iterator over epochs ``[first, last)``: every batch of
+        ``epoch(mesh, e, **kw)`` for each ``e`` in order, and an
+        ``EpochEnd(e)`` after each epoch's last.  ``Estimator.fit`` opens
+        it once a call.  It has ``close()`` and ``next_is_ready()`` (is
+        the next batch decoded already?).  Feeds that can work ahead
+        across the boundary override it (``StreamingDataFeed``)."""
+        return EpochChain(lambda e: self.epoch(mesh, e, **kw), first, last)
 
     def step_mask(self, step: int) -> np.ndarray:
         """Real-row weights for this process's ``step`` batch: 1.0 for rows
@@ -286,6 +346,11 @@ class PrefetchIterator:
     (its unhidden tail observed as ``feed.h2d_ms``) and the slot
     recycled.
 
+    Over a multi-epoch iterator (``FeedBase.epochs``) the producer runs
+    straight across the epoch boundary: an ``EpochEnd`` marker passes
+    through unplaced, in its position, and the first batches of epoch
+    k+1 are staged while the consumer still trains on epoch k's last.
+
     Exceptions from the producer (loader failures, injected
     ``feed.stall``-adjacent faults) re-raise in the consumer at the
     position they occurred.  ``close()`` is safe mid-epoch (rollback,
@@ -359,7 +424,8 @@ class PrefetchIterator:
     def _produce(self) -> None:
         try:
             for batch in self._it:
-                if self._place is not None:
+                if self._place is not None and \
+                        not isinstance(batch, EpochEnd):
                     batch = self._stage(batch)
                 if not self._put(("item", batch)):
                     return  # closed mid-epoch
@@ -386,6 +452,12 @@ class PrefetchIterator:
         if kind == "error":
             raise payload
         raise StopIteration
+
+    def next_is_ready(self) -> bool:
+        """Is the next item staged already, so that ``next()`` will not
+        wait?  (``Estimator.fit`` asks before an epoch's first batch:
+        registry counter ``feed.epochs_carried``.)"""
+        return not self._q.empty()
 
     def close(self, timeout: float = 5.0) -> None:
         """Stop the producer and reclaim its thread (idempotent).  The

@@ -52,6 +52,12 @@ point (core/faults.py) makes both paths deterministically testable; forked
 workers inherit the armed registry and their hit/fire counts are absorbed
 back into the parent registry at epoch end.
 
+One pipeline a ``fit()``: ``epochs(mesh, first, last)`` runs every epoch
+of the call through one set of decode threads, one native queue and one
+``ready`` map (``_ThreadEpochs``), so epoch k+1's first batches are
+decoded while epoch k's last steps train; ``epoch()`` is its one-epoch
+case.  The process backend keeps forking its workers per epoch.
+
 Same interface as DataFeed (both subclass feed.FeedBase), so Estimator.fit
 takes either interchangeably.
 """
@@ -73,7 +79,7 @@ from analytics_zoo_tpu.core import trace as trace_lib
 from analytics_zoo_tpu.core.context import config_default
 from analytics_zoo_tpu.native import NativeQueue
 from . import shm_pool
-from .feed import FeedBase, shard_batch
+from .feed import EpochEnd, EpochsIterator, FeedBase, shard_batch
 from .shm_pool import ShmBatchPool, SlotBatch
 
 logger = logging.getLogger("analytics_zoo_tpu")
@@ -128,7 +134,14 @@ class StreamingDataFeed(FeedBase):
     ``workers``: decode backend — ``"thread"`` (default; also the
     ``ZooConfig.feed_backend`` default) or ``"process"`` (shared-memory
     slot pool, see module docstring).  ``num_workers`` defaults to
-    ``ZooConfig.feed_workers`` (else 4)."""
+    ``ZooConfig.feed_workers`` (else 4).
+
+    ``load_sample``'s ``rng`` is ``np.random.default_rng((seed, epoch,
+    step))``, one per batch: what a row's augmentation draws depends on
+    where the row stands, not on which worker loaded it, so a run is
+    reproducible for any ``num_workers``.  Held ahead of the consumer:
+    at most ``prefetch_batches + num_workers`` decoded batches, across an
+    epoch boundary (``epochs``) as inside an epoch."""
 
     def __init__(self, num_samples: int,
                  load_sample: Callable[..., Dict[str, np.ndarray]],
@@ -206,33 +219,33 @@ class StreamingDataFeed(FeedBase):
         # timings over the existing control-message channel and the
         # parent records them (children can't reach the parent's ring)
         self.trace_id: Optional[str] = None
-        self._epoch_sid: Optional[str] = None
 
-    def _begin_epoch_trace(self, epoch_idx: int) -> None:
-        if trace_lib.enabled:
-            self.trace_id = trace_lib.new_trace_id()
-            self._epoch_sid = trace_lib.new_span_id()
-        else:
-            self.trace_id = self._epoch_sid = None
+    def _begin_epoch_trace(self):
+        """``(trace id, root span id, start)`` of one epoch's span tree,
+        None with spans off; ``trace_id`` names the newest epoch's."""
+        if not trace_lib.enabled:
+            self.trace_id = None
+            return None
+        self.trace_id = trace_lib.new_trace_id()
+        return self.trace_id, trace_lib.new_span_id(), time.monotonic()
 
-    def _record_decode_span(self, step: int, decode_ms: float,
+    @staticmethod
+    def _record_decode_span(tr, step: int, decode_ms: float,
                             io_ms: float) -> None:
-        if self.trace_id is not None:
+        if tr is not None:
             trace_lib.record(
-                self.trace_id, "feed.decode",
+                tr[0], "feed.decode",
                 {"step": step, "decode_ms": round(decode_ms, 3),
                  "io_wait_ms": round(io_ms, 3)},
-                parent=self._epoch_sid, dur_ms=decode_ms)
+                parent=tr[1], dur_ms=decode_ms)
 
-    def _end_epoch_trace(self, epoch_idx: int, steps: int,
-                         t0: float) -> None:
-        if self.trace_id is not None:
+    def _end_epoch_trace(self, tr, epoch_idx: int, steps: int) -> None:
+        if tr is not None:
             trace_lib.record(
-                self.trace_id, "feed.epoch",
+                tr[0], "feed.epoch",
                 {"epoch": epoch_idx, "steps": steps,
                  "backend": self.workers},
-                span_id=self._epoch_sid,
-                dur_ms=(time.monotonic() - t0) * 1000.0)
+                span_id=tr[1], dur_ms=(time.monotonic() - tr[2]) * 1000.0)
 
     # -- resilient sample loading --------------------------------------------
 
@@ -374,9 +387,25 @@ class StreamingDataFeed(FeedBase):
 
     # -- epoch iteration ------------------------------------------------------
 
+    def epochs(self, mesh: Mesh, first: int, last: int, place: bool = True):
+        """Epochs ``[first, last)`` through ONE pipeline (thread backend):
+        one set of decode threads, one native queue and one ``ready`` map
+        for all of them, so the first batches of epoch k+1 are decoded
+        while the consumer still holds epoch k's last.  Yields each
+        epoch's batches in step order and a ``feed.EpochEnd`` after its
+        last; nothing past ``last`` is ever loaded.  Held ahead of the
+        consumer: at most ``prefetch_batches + num_workers`` host
+        batches, at a boundary as in the middle of an epoch.  The process
+        backend chains ``epoch()`` calls (``FeedBase.epochs``): it forks
+        its workers anew every epoch."""
+        if self.workers == "process":
+            return super().epochs(mesh, first, last, place=place)
+        return _ThreadEpochs(self, mesh, first, last, place)
+
     def epoch(self, mesh: Mesh, epoch_idx: int = 0, place: bool = True
               ) -> Iterator[Dict[str, "np.ndarray"]]:
-        """``place=False`` yields host numpy batches (no device placement):
+        """One epoch's batches: the one-epoch case of ``epochs``.
+        ``place=False`` yields host numpy batches (no device placement):
         the consumer owns staging, e.g. to stack K batches into one
         infeed-chunk transfer for ``Estimator._multi_step_data``.  Under
         the process backend an unplaced batch is a ``SlotBatch`` of
@@ -385,7 +414,8 @@ class StreamingDataFeed(FeedBase):
         batches than the pool holds (GC releases as a safety net)."""
         if self.workers == "process":
             return self._epoch_process(mesh, epoch_idx, place)
-        return self._epoch_thread(mesh, epoch_idx, place)
+        return _batches_only(
+            _ThreadEpochs(self, mesh, epoch_idx, epoch_idx + 1, place))
 
     def _consume(self, queue: NativeQueue, ready: Dict, ready_cond,
                  errors: List[BaseException], bound: int, steps: int,
@@ -394,7 +424,10 @@ class StreamingDataFeed(FeedBase):
         draining, double-buffered placement, and (for shm batches) slot
         recycling one step behind the yield so the device copy of batch N
         completes — overlapped with the placement of N+1 — before its
-        host buffer is reused."""
+        host buffer is reused.  ``steps`` counts the positions of the
+        whole run (``ready``'s keys): one epoch's steps under the process
+        backend, those of every epoch of an ``epochs()`` call under the
+        thread backend.  Batch N+1 is taken before N is handed out."""
         m_ready = self._m_ready
 
         def take(expected_step: int) -> Dict[str, np.ndarray]:
@@ -469,90 +502,6 @@ class StreamingDataFeed(FeedBase):
         pool = getattr(self, "_active_pool", None)
         return pool.in_use() if pool is not None else 0
 
-    # -- thread backend -------------------------------------------------------
-
-    def _epoch_thread(self, mesh: Mesh, epoch_idx: int, place: bool
-                      ) -> Iterator[Dict[str, "np.ndarray"]]:
-        idx = self._epoch_index(epoch_idx)
-        steps = self.steps_per_epoch()
-        self._begin_epoch_trace(epoch_idx)
-        epoch_t0 = time.monotonic()
-
-        # the bounded native queue carries batch tokens; ready holds the
-        # actual arrays (at most prefetch_batches + num_workers entries,
-        # because push blocks when the queue is full)
-        queue = NativeQueue(max_items=self.prefetch_batches)
-        ready: Dict[int, Dict[str, np.ndarray]] = {}
-        ready_lock = threading.Lock()
-        # one condition guards BOTH ready and errors: workers notify when
-        # either changes, so the consumer never busy-waits
-        ready_cond = threading.Condition(ready_lock)
-        step_iter = iter(range(steps))
-        step_lock = threading.Lock()
-        errors: List[BaseException] = []
-
-        def worker(wid: int) -> None:
-            rng = np.random.default_rng(
-                (self.seed + epoch_idx) * 10007 + wid)
-            while True:
-                with step_lock:
-                    step = next(step_iter, None)
-                if step is None:
-                    return
-                sel = self._batch_index(idx, step)
-                try:
-                    self._hint_rows(sel)
-                    t0 = time.monotonic()
-                    io0 = self._io_wait_ms()
-                    rows = [self._load_row(int(i), rng) for i in sel]
-                    batch = {k: np.stack([r[k] for r in rows])
-                             for k in rows[0]}
-                    decode_ms = (time.monotonic() - t0) * 1000.0
-                    self._m_decode.observe(decode_ms)
-                    io_ms = self._io_wait_ms() - io0
-                    if io_ms > 0:
-                        self._m_io.observe(io_ms)
-                    self._record_decode_span(step, decode_ms,
-                                             max(0.0, io_ms))
-                except BaseException as e:          # noqa: BLE001 loader bug
-                    with ready_cond:
-                        errors.append(e)
-                        ready_cond.notify_all()
-                    try:
-                        queue.push(_ERROR_TOKEN.to_bytes(8, "big"))
-                    except RuntimeError:
-                        pass                        # consumer already gone
-                    return
-                with ready_cond:
-                    ready[step] = batch
-                    self._m_ready.set(len(ready))
-                    ready_cond.notify_all()
-                try:
-                    queue.push(step.to_bytes(8, "big"))  # blocks when full
-                except RuntimeError:                # queue closed: abandon
-                    return
-
-        workers = [threading.Thread(target=worker, args=(w,), daemon=True)
-                   for w in range(self.num_workers)]
-        for t in workers:
-            t.start()
-
-        bound = self.prefetch_batches + self.num_workers
-
-        try:
-            yield from self._consume(queue, ready, ready_cond, errors,
-                                     bound, steps, mesh, place)
-        finally:
-            queue.close()
-            for t in workers:
-                try:
-                    t.join(timeout=5)
-                except TypeError:
-                    # generator finalized during interpreter teardown:
-                    # threading internals are already torn down
-                    pass
-            self._end_epoch_trace(epoch_idx, steps, epoch_t0)
-
     # -- process backend ------------------------------------------------------
 
     def _batch_spec(self, idx: np.ndarray) -> Dict[str, tuple]:
@@ -584,8 +533,7 @@ class StreamingDataFeed(FeedBase):
         ctx = mp.get_context("fork")
         idx = self._epoch_index(epoch_idx)
         steps = self.steps_per_epoch()
-        self._begin_epoch_trace(epoch_idx)
-        epoch_t0 = time.monotonic()
+        tr = self._begin_epoch_trace()
         spec = self._batch_spec(idx)
         nslots = max(2, self.prefetch_batches + self.num_workers)
         pool = ShmBatchPool(nslots, self._local_batch, spec, ctx=ctx)
@@ -656,7 +604,7 @@ class StreamingDataFeed(FeedBase):
                     # forked workers can't reach this process's span
                     # ring — the decode timing rode the control message,
                     # so the span is recorded HERE, under the epoch root
-                    self._record_decode_span(step, decode_ms,
+                    self._record_decode_span(tr, step, decode_ms,
                                              max(0.0, io_ms))
                     batch = SlotBatch(pool.views(slot), slot, pool)
                     with ready_cond:
@@ -730,7 +678,140 @@ class StreamingDataFeed(FeedBase):
             self._active_pool = None
             pool.close()
             self._m_shm.set(0)
-            self._end_epoch_trace(epoch_idx, steps, epoch_t0)
+            self._end_epoch_trace(tr, epoch_idx, steps)
+
+
+class _ThreadEpochs(EpochsIterator):
+    """The thread backend's iterator over epochs ``[first, last)``
+    (``StreamingDataFeed.epochs``; ``epoch()`` is its one-epoch case).
+
+    Decode threads claim global positions ``(epoch - first) * steps +
+    step`` in order from one counter and go straight from an epoch's last
+    step to the next epoch's step 0: the permutation of an epoch depends
+    on ``seed + epoch`` alone.  The bounded native queue carries position
+    tokens; ``ready`` holds the decoded batches by position (at most
+    ``prefetch_batches + num_workers`` entries, because push blocks when
+    the queue is full).  A batch's rng is ``default_rng((seed, epoch,
+    step))``: what a row's augmentation draws does not depend on which
+    thread loaded it.  ``close()`` stops and joins the threads and drops
+    what they had decoded ahead."""
+
+    def __init__(self, feed: StreamingDataFeed, mesh: Mesh, first: int,
+                 last: int, place: bool):
+        self._steps = feed.steps_per_epoch()
+        self._total = max(0, last - first) * self._steps
+        self._handed = 0            # batches handed out so far
+        self.ready: Dict[int, Dict[str, np.ndarray]] = {}
+        # one condition guards BOTH ready and errors: workers notify when
+        # either changes, so the consumer never busy-waits
+        self.ready_cond = threading.Condition(threading.Lock())
+        self._gen = self._run(feed, mesh, first, place)
+
+    def next_is_ready(self) -> bool:
+        """Is the next batch decoded?  Past the run's first it is in
+        ``_consume``'s hands already (it takes N+1 before handing out N)."""
+        with self.ready_cond:
+            return (0 < self._handed < self._total
+                    or self._handed in self.ready)
+
+    def _run(self, feed: StreamingDataFeed, mesh: Mesh, first: int,
+             place: bool):
+        steps, total = self._steps, self._total
+        ready, ready_cond = self.ready, self.ready_cond
+        queue = NativeQueue(max_items=feed.prefetch_batches)
+        errors: List[BaseException] = []
+        claim_lock = threading.Lock()
+        # the claim: next position, and the newest claimed epoch's row
+        # order (an epoch with no batch raises here, on the consumer)
+        claim = {"pos": 0, "epoch": first, "idx": feed._epoch_index(first)}
+        traces = {first: feed._begin_epoch_trace()}
+
+        # the workers see none of ``self``: an iterator dropped unclosed is
+        # collected, its ``finally`` below closes the queue, and they leave
+        def worker() -> None:
+            while True:
+                with claim_lock:
+                    pos = claim["pos"]
+                    if pos >= total:
+                        return
+                    claim["pos"] = pos + 1
+                    epoch, step = first + pos // steps, pos % steps
+                    if epoch != claim["epoch"]:
+                        claim["epoch"] = epoch
+                        claim["idx"] = feed._epoch_index(epoch)
+                        traces[epoch] = feed._begin_epoch_trace()
+                    idx, tr = claim["idx"], traces[epoch]
+                sel = feed._batch_index(idx, step)
+                try:
+                    rng = np.random.default_rng((feed.seed, epoch, step))
+                    feed._hint_rows(sel)
+                    t0 = time.monotonic()
+                    io0 = feed._io_wait_ms()
+                    rows = [feed._load_row(int(i), rng) for i in sel]
+                    batch = {k: np.stack([r[k] for r in rows])
+                             for k in rows[0]}
+                    decode_ms = (time.monotonic() - t0) * 1000.0
+                    feed._m_decode.observe(decode_ms)
+                    io_ms = feed._io_wait_ms() - io0
+                    if io_ms > 0:
+                        feed._m_io.observe(io_ms)
+                    feed._record_decode_span(tr, step, decode_ms,
+                                             max(0.0, io_ms))
+                except BaseException as e:          # noqa: BLE001 loader bug
+                    with ready_cond:
+                        errors.append(e)
+                        ready_cond.notify_all()
+                    try:
+                        queue.push(_ERROR_TOKEN.to_bytes(8, "big"))
+                    except RuntimeError:
+                        pass                        # consumer already gone
+                    return
+                with ready_cond:
+                    ready[pos] = batch
+                    feed._m_ready.set(len(ready))
+                    ready_cond.notify_all()
+                try:
+                    queue.push(pos.to_bytes(8, "big"))  # blocks when full
+                except RuntimeError:                # queue closed: abandon
+                    return
+
+        workers = [threading.Thread(target=worker, daemon=True,
+                                    name=f"zoo-feed-w{w}")
+                   for w in range(feed.num_workers)]
+        for t in workers:
+            t.start()
+        bound = feed.prefetch_batches + feed.num_workers
+        try:
+            for batch in feed._consume(queue, ready, ready_cond, errors,
+                                       bound, total, mesh, place):
+                self._handed += 1
+                yield batch
+                if self._handed % steps == 0:
+                    epoch = first + self._handed // steps - 1
+                    feed._end_epoch_trace(traces.pop(epoch), epoch, steps)
+                    yield EpochEnd(epoch)
+        finally:
+            queue.close()
+            for t in workers:
+                try:
+                    t.join(timeout=5)
+                except TypeError:
+                    # generator finalized during interpreter teardown:
+                    # threading internals are already torn down
+                    pass
+            for epoch, tr in traces.items():    # begun, not handed out whole
+                feed._end_epoch_trace(tr, epoch, steps)
+
+
+def _batches_only(run) -> Iterator[Dict[str, "np.ndarray"]]:
+    """The batches of an ``epochs()`` iterator without its markers;
+    closing (or dropping) this closes ``run``."""
+    try:
+        for item in run:
+            if not isinstance(item, EpochEnd):
+                yield item
+    finally:
+        run.close()
 
 
 class _ProcShared:
@@ -814,7 +895,6 @@ def _process_worker(feed: StreamingDataFeed, idx: np.ndarray,
         # the child's metrics registry is invisible to the parent — the
         # parent observes decode/io from control messages instead
         metrics_lib.get_registry().enabled = False
-        rng = np.random.default_rng((feed.seed + epoch_idx) * 10007 + wid)
         while True:
             with sh.step.get_lock():
                 step = sh.step.value
@@ -826,6 +906,7 @@ def _process_worker(feed: StreamingDataFeed, idx: np.ndarray,
             if slot is None:
                 break                       # pool closing under us
             sel = feed._batch_index(idx, step)
+            rng = np.random.default_rng((feed.seed, epoch_idx, step))
             feed._hint_rows(sel)
             t0 = time.monotonic()
             io0 = feed._io_wait_ms()

@@ -43,7 +43,7 @@ from analytics_zoo_tpu.core import metrics as telemetry
 from analytics_zoo_tpu.core import trace as trace_lib
 from analytics_zoo_tpu.core.context import heartbeat
 from analytics_zoo_tpu.core.summary import SummaryWriter
-from analytics_zoo_tpu.data import (PrefetchIterator, as_feed,
+from analytics_zoo_tpu.data import (EpochEnd, PrefetchIterator, as_feed,
                                     batch_sharding, make_placer,
                                     shard_batch)
 from analytics_zoo_tpu.nn import losses as losses_lib
@@ -1047,6 +1047,7 @@ class ZooEstimator:
         # that first wait alone
         m_gap = reg.histogram("train.epoch_gap_ms")
         m_first_wait = reg.histogram("train.first_batch_wait_ms")
+        m_carried = reg.counter("feed.epochs_carried")
         m_steps = reg.counter("train.steps")
         m_samples = reg.counter("train.samples")
         m_bad = reg.counter("train.bad_steps")
@@ -1081,6 +1082,9 @@ class ZooEstimator:
         # epoch, across a ``continue`` and several exits, so it is held
         # here and closed wherever the loop leaves it.
         epoch_end = contextlib.ExitStack()
+        # ONE feed pipeline for the whole call (``feed.epochs``): opened
+        # at the first epoch, reopened only after a rollback
+        batch_iter = None
 
         if self._preempt is not None:
             self._preempt.active = True
@@ -1106,34 +1110,20 @@ class ZooEstimator:
                 epoch_wait = 0.0
                 bad_before = self.bad_steps
                 rolled_back = False
-                if prefetch and prefetch > 0 and _supports_host_epoch(
-                        feed):
-                    # stream feeds: iterate HOST batches and place them
-                    # inside the prefetch producer — double-buffered
-                    # device_put: the host→HBM copy of batch k+1
-                    # dispatches (and completes) while the device
-                    # computes batch k, and shared-memory pool slots
-                    # recycle the moment their transfer lands
-                    batch_iter = PrefetchIterator(
-                        feed.epoch(mesh, self._epoch, place=False),
-                        depth=prefetch, gauge=m_prefetch,
-                        place=make_placer(mesh))
-                elif prefetch and prefetch > 0:
-                    # depth-2 double buffering by default: the feed's
-                    # host work for step k+1 (slice/stack, shard_batch,
-                    # device_put dispatch) overlaps the device compute
-                    # of step k on a background thread
-                    batch_iter = PrefetchIterator(
-                        iter(feed.epoch(mesh, self._epoch)),
-                        depth=prefetch, gauge=m_prefetch)
-                else:
-                    batch_iter = iter(feed.epoch(mesh, self._epoch))
+                if batch_iter is None:
+                    batch_iter = _open_feed(
+                        feed, mesh, self._epoch, target_epoch, prefetch,
+                        m_prefetch)
+                elif batch_iter.next_is_ready():
+                    # carried: this epoch's first batch was decoded (or
+                    # placed) while the last one's final steps ran
+                    m_carried.inc()
                 try:
                     while True:
                         t_fetch = time.monotonic()
                         with trace_lib.phase("fit.data_wait"):
                             batch = next(batch_iter, None)
-                        if batch is None:
+                        if batch is None or isinstance(batch, EpochEnd):
                             break
                         wait = time.monotonic() - t_fetch
                         epoch_wait += wait
@@ -1265,11 +1255,12 @@ class ZooEstimator:
                                 self._trigger_save()
                 finally:
                     epoch_end.enter_context(trace_lib.phase("fit.epoch_end"))
-                    # mid-epoch exits (rollback, preemption, raise) must
-                    # not leak the prefetch producer thread
-                    if isinstance(batch_iter, PrefetchIterator):
-                        batch_iter.close()
                 if rolled_back:
+                    # what the feed decoded ahead belongs to epochs that
+                    # will be re-run from the restored one: dropped, never
+                    # trained on; the pipeline reopens at that epoch
+                    batch_iter.close()
+                    batch_iter = None
                     # epoch/step rewound to the restored ckpt; drop history
                     # entries for epochs about to be re-run (a mid-epoch
                     # checkpoint rewinds into an already-recorded epoch) so
@@ -1393,6 +1384,11 @@ class ZooEstimator:
                        "error": str(e)})
             raise
         finally:
+            # every way out (the last epoch's end, an exception,
+            # ``Preempted``) joins the feed's threads and drops what they
+            # hold; nothing past ``target_epoch`` was ever loaded
+            if batch_iter is not None:
+                batch_iter.close()
             epoch_end.close()  # an exception must not leave the phase open
             ZooEstimator._device_lock.release()
             if self._preempt is not None:
@@ -1801,13 +1797,36 @@ def _merge_shard_leaf(l: jax.Array) -> jax.Array:
     return l[0]
 
 
+def _open_feed(feed: Any, mesh, first: int, last: int, prefetch: int,
+               gauge: Any):
+    """The one iterator a ``fit()`` reads: ``feed.epochs(first, last)``
+    behind a ``PrefetchIterator`` (``prefetch=0``: the same iterator,
+    inline on the training thread)."""
+    if not (prefetch and prefetch > 0):
+        return feed.epochs(mesh, first, last)
+    if _supports_host_epoch(feed):
+        # stream feeds: iterate HOST batches and place them inside
+        # the prefetch producer — double-buffered device_put: the
+        # host→HBM copy of batch k+1 dispatches (and completes) while
+        # the device computes batch k, and shared-memory pool slots
+        # recycle the moment their transfer lands
+        return PrefetchIterator(
+            feed.epochs(mesh, first, last, place=False),
+            depth=prefetch, gauge=gauge, place=make_placer(mesh))
+    # depth-2 double buffering by default: the feed's host work for
+    # step k+1 (slice/stack, shard_batch, device_put dispatch)
+    # overlaps the device compute of step k on a background thread
+    return PrefetchIterator(feed.epochs(mesh, first, last),
+                            depth=prefetch, gauge=gauge)
+
+
 def _supports_host_epoch(feed: Any) -> bool:
-    """Can this feed yield host batches (``epoch(..., place=False)``)?
+    """Can this feed yield host batches (``epochs(..., place=False)``)?
     True for StreamingDataFeed; in-RAM feeds keep their own placed-epoch
     double buffering."""
     try:
         import inspect
-        return "place" in inspect.signature(feed.epoch).parameters
+        return "place" in inspect.signature(feed.epochs).parameters
     except (TypeError, ValueError):
         return False
 

@@ -384,3 +384,201 @@ class TestPrefetchIterator:
         overlapped = _t.monotonic() - t0
         # ~0.48s inline vs ~0.27s overlapped; generous margin for CI noise
         assert overlapped < inline * 0.8, (inline, overlapped)
+
+
+def _feed_threads():
+    import threading
+    return [t.name for t in threading.enumerate()
+            if t.name.startswith(("zoo-feed", "zoo-prefetch"))]
+
+
+class TestEpochsCarried:
+    """One pipeline over several epochs (ISSUE 28): ``epochs()`` yields what
+    the same number of ``epoch()`` calls would, with a marker behind each
+    epoch, from one set of workers that runs ahead across the boundary."""
+
+    BATCH, STEPS = 4, 3
+
+    def _feed(self, load=None, **kw):
+        from analytics_zoo_tpu.data import StreamingDataFeed
+
+        def by_index(i, rng=None):
+            return {"x": np.full((2,), float(i), np.float32),
+                    "y": np.int32(i)}
+        kw.setdefault("shuffle", True)
+        return StreamingDataFeed(self.BATCH * self.STEPS, load or by_index,
+                                 batch_size=self.BATCH, seed=5, **kw)
+
+    @pytest.mark.parametrize("workers", [1, 4])
+    def test_three_epochs_are_three_epoch_calls_in_order(self, workers):
+        from analytics_zoo_tpu.data import EpochEnd
+        mesh = init_orca_context("local")
+        feed = self._feed(num_workers=workers)
+        want = []
+        for e in range(3):
+            want += [np.asarray(b["y"]) for b in
+                     feed.epoch(mesh, e, place=False)] + [e]
+        got = [item.epoch if isinstance(item, EpochEnd)
+               else np.asarray(item["y"])
+               for item in feed.epochs(mesh, 0, 3, place=False)]
+        assert len(got) == len(want) == 3 * (self.STEPS + 1)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
+        # the epochs differ (shuffled), so the order above was a real check
+        assert not np.array_equal(want[0], want[self.STEPS + 1])
+        assert _feed_threads() == []
+
+    def test_the_next_epochs_first_batch_is_decoded_before_this_ones_last(
+            self):
+        """Epoch 0's last batch hangs in its loader; meanwhile the other
+        workers go on to epoch 1's step 0, which lands in ``ready`` under
+        its global position."""
+        import threading
+        import time
+        mesh = init_orca_context("local")
+        gate, seen = threading.Event(), set()
+        last_rows = set(range(self.BATCH * (self.STEPS - 1),
+                              self.BATCH * self.STEPS))
+
+        def load(i, rng=None):
+            first_time = i not in seen
+            seen.add(i)
+            if i in last_rows and first_time:
+                assert gate.wait(30)
+            return {"y": np.int32(i)}
+
+        feed = self._feed(load, shuffle=False, num_workers=4)
+        run = feed.epochs(mesh, 0, 2, place=False)
+        try:
+            assert run.next_is_ready() is False     # nothing decoded yet
+            first = next(run)       # needs step 1 decoded too, not step 2
+            np.testing.assert_array_equal(first["y"], np.arange(self.BATCH))
+            deadline = time.monotonic() + 30
+            while time.monotonic() < deadline:
+                with run.ready_cond:
+                    if self.STEPS in run.ready:     # epoch 1, step 0
+                        break
+                time.sleep(0.01)
+            with run.ready_cond:
+                assert self.STEPS in run.ready
+                assert self.STEPS - 1 not in run.ready  # epoch 0's last
+                np.testing.assert_array_equal(
+                    run.ready[self.STEPS]["y"], np.arange(self.BATCH))
+            gate.set()
+            rest = list(run)
+        finally:
+            gate.set()
+            run.close()
+        assert len(rest) == 2 * self.STEPS - 1 + 2  # batches and two markers
+        assert _feed_threads() == []
+
+    def test_nothing_past_the_last_epoch_is_loaded(self):
+        import threading
+        import time
+        mesh = init_orca_context("local")
+        calls, lock = [], threading.Lock()
+
+        def load(i, rng=None):
+            with lock:
+                calls.append(i)
+            return {"y": np.int32(i)}
+
+        feed = self._feed(load, num_workers=4, prefetch_batches=8)
+        run = feed.epochs(mesh, 3, 5, place=False)
+        next(run)
+        time.sleep(0.2)             # room to run as far ahead as allowed
+        rest = list(run)
+        assert len(rest) == 2 * self.STEPS - 1 + 2
+        # two epochs' rows exactly, each row once an epoch
+        assert sorted(calls) == sorted(2 * list(range(self.BATCH
+                                                      * self.STEPS)))
+
+    def test_a_batchs_rng_follows_seed_epoch_and_step(self):
+        """What augmentation draws no longer depends on which worker got
+        the step: two runs with four workers give the same bytes, and they
+        are ``default_rng((seed, epoch, step))``'s."""
+        mesh = init_orca_context("local")
+
+        def load(i, rng=None):
+            return {"x": rng.random(3), "y": np.int32(i)}
+
+        runs = [[b["x"] for b in self._feed(load, num_workers=4).epochs(
+                    mesh, 1, 3, place=False) if isinstance(b, dict)]
+                for _ in range(2)]
+        for a, b in zip(*runs):
+            np.testing.assert_array_equal(a, b)
+        want = np.random.default_rng((5, 2, 1))
+        np.testing.assert_array_equal(
+            runs[0][self.STEPS + 1],
+            np.stack([want.random(3) for _ in range(self.BATCH)]))
+
+    def test_closing_mid_run_joins_the_workers_and_drops_what_is_ahead(self):
+        mesh = init_orca_context("local")
+        run = self._feed(num_workers=4).epochs(mesh, 0, 50, place=False)
+        for _ in range(self.STEPS + 2):
+            next(run)
+        assert any(n.startswith("zoo-feed-w") for n in _feed_threads())
+        run.close()
+        assert _feed_threads() == []
+        assert next(run, None) is None
+
+    def test_many_workers_claim_every_position_once_and_in_order(self):
+        """Stress: more workers than cores over many short epochs, with the
+        interpreter switching threads every 10 us — a lost or doubled
+        claim, or a batch filed under the wrong position, breaks the
+        sequence."""
+        import sys
+        from analytics_zoo_tpu.data import EpochEnd
+        mesh = init_orca_context("local")
+        epochs, rows = 40, self.BATCH * self.STEPS
+        feed = self._feed(num_workers=32, prefetch_batches=2)
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            got = list(feed.epochs(mesh, 0, epochs, place=False))
+        finally:
+            sys.setswitchinterval(old)
+        assert [g.epoch for g in got if isinstance(g, EpochEnd)] \
+            == list(range(epochs))
+        seen = [np.asarray(g["y"]) for g in got if isinstance(g, dict)]
+        assert len(seen) == epochs * self.STEPS
+        for e in range(epochs):
+            order = np.arange(rows)
+            np.random.default_rng(5 + e).shuffle(order)
+            np.testing.assert_array_equal(
+                np.concatenate(seen[e * self.STEPS:(e + 1) * self.STEPS]),
+                order)
+        assert _feed_threads() == []
+
+    def test_the_default_chains_epoch_calls(self):
+        """``FeedBase.epochs``: an in-RAM feed's epochs one after another;
+        nothing is ever ready ahead of the consumer."""
+        from analytics_zoo_tpu.data import EpochEnd
+        mesh = init_orca_context("local")
+        x = np.arange(24, dtype=np.float32).reshape(12, 2)
+        feed = DataFeed({"x": x}, batch_size=4, shuffle=True, seed=3)
+        want = [np.asarray(b["x"]) for e in (2, 3)
+                for b in feed.epoch(mesh, e)]
+        run = feed.epochs(mesh, 2, 4)
+        assert run.next_is_ready() is False
+        got = list(run)
+        assert [g.epoch for g in got if isinstance(g, EpochEnd)] == [2, 3]
+        assert isinstance(got[3], EpochEnd) and isinstance(got[7], EpochEnd)
+        for g, w in zip([g for g in got if isinstance(g, dict)], want):
+            np.testing.assert_array_equal(np.asarray(g["x"]), w)
+
+    def test_the_prefetcher_passes_a_marker_through_unplaced(self):
+        from analytics_zoo_tpu.data import EpochEnd, PrefetchIterator
+        placed = []
+
+        def place(item):
+            placed.append(item)
+            return item * 10
+
+        it = PrefetchIterator(iter([1, 2, EpochEnd(0), 3, EpochEnd(1)]),
+                              depth=2, place=place)
+        got = list(it)
+        assert [g for g in got if not isinstance(g, EpochEnd)] == [10, 20, 30]
+        assert [type(g) for g in got].index(EpochEnd) == 2
+        assert got[-1].epoch == 1 and placed == [1, 2, 3]
+        assert it.next_is_ready() is False

@@ -99,6 +99,150 @@ def test_the_epoch_boundary_is_observed_once_an_epoch():
         >= snap["train.first_batch_wait_ms"]["sum"] > 0
 
 
+# -- one feed pipeline a fit() (ISSUE 28) ------------------------------------
+
+def _indexed_stream(calls=None, steps=STEPS, fail_at=None, **kw):
+    """Rows that differ by index, shuffled anew each epoch, so a batch of
+    the wrong epoch changes the loss; the loader ignores ``rng``."""
+    seen = {"n": 0}
+
+    def load_sample(i, rng=None):
+        seen["n"] += 1
+        if calls is not None:
+            calls.append(i)
+        if fail_at is not None and seen["n"] > fail_at:
+            raise OSError("the disk went away")
+        x = np.random.default_rng(i).normal(size=8).astype(np.float32)
+        return {"x": x, "y": np.int32(i % 4)}
+    return StreamingDataFeed(num_samples=steps * BATCH,
+                             load_sample=load_sample, batch_size=BATCH,
+                             shuffle=True, seed=11, **kw)
+
+
+def _pipeline_threads():
+    import threading
+    return [t.name for t in threading.enumerate()
+            if t.name.startswith(("zoo-prefetch", "zoo-feed"))]
+
+
+@pytest.mark.parametrize("epochs", [1, 4])
+def test_epochs_carried_counts_every_boundary_but_the_first(epochs):
+    """Inline (``prefetch=0``) the answer is exact: past a run's first
+    batch the feed's consumer always holds the next one decoded."""
+    init_orca_context("local")
+    _estimator().fit(_indexed_stream(), epochs=epochs, batch_size=BATCH,
+                     verbose=False, prefetch=0)
+    snap = metrics.get_registry().snapshot()
+    assert snap.get("feed.epochs_carried", 0) == epochs - 1
+    assert snap["train.first_batch_wait_ms"]["count"] == epochs
+    assert _pipeline_threads() == []
+
+
+def test_epochs_are_carried_behind_the_prefetcher_too():
+    """With the prefetcher, carried means: placed in its queue when
+    ``fit()`` asks.  A step that takes 30 ms (armed delay) leaves the feed
+    all the time it needs."""
+    from analytics_zoo_tpu.core import faults
+    init_orca_context("local")
+    est = _estimator()
+    with faults.get_registry().armed("worker.hang", delay=0.03):
+        est.fit(_indexed_stream(), epochs=3, batch_size=BATCH,
+                verbose=False)
+    snap = metrics.get_registry().snapshot()
+    assert snap.get("feed.epochs_carried", 0) == 2
+    assert snap["train.steps"] == 3 * STEPS
+    assert _pipeline_threads() == []
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+def test_one_fit_of_three_epochs_is_three_fits_of_one(prefetch):
+    init_orca_context("local")
+    whole = _estimator(seed=3).fit(_indexed_stream(), epochs=3,
+                                   batch_size=BATCH, verbose=False,
+                                   prefetch=prefetch)["loss"]
+    est = _estimator(seed=3)
+    apart = [est.fit(_indexed_stream(), epochs=1, batch_size=BATCH,
+                     verbose=False, prefetch=prefetch)["loss"][0]
+             for _ in range(3)]
+    assert whole == apart           # bit for bit
+    assert len(set(whole)) == 3
+
+
+def test_fit_loads_nothing_past_its_last_epoch():
+    init_orca_context("local")
+    calls = []
+    est = _estimator()
+    est.fit(_indexed_stream(calls, num_workers=4, prefetch_batches=8),
+            epochs=2, batch_size=BATCH, verbose=False)
+    rows = list(range(STEPS * BATCH))
+    assert sorted(calls) == sorted(2 * rows)
+    # the next call goes on at epoch 2, again with its own rows only
+    est.fit(_indexed_stream(calls), epochs=1, batch_size=BATCH,
+            verbose=False)
+    assert sorted(calls) == sorted(3 * rows)
+
+
+def test_a_rollback_drops_what_was_decoded_ahead(tmp_path):
+    """NaN in the middle of the second epoch, with the third already in
+    the feed's hands: the epoch is re-run from the checkpoint on its own
+    batches, and the history is a clean run's."""
+    from analytics_zoo_tpu.core import faults
+    init_orca_context("local")
+    clean = _estimator(seed=3).fit(_indexed_stream(), epochs=3,
+                                   batch_size=BATCH, verbose=False)
+    est = _estimator(seed=3, nan_policy="rollback",
+                     model_dir=str(tmp_path / "ckpt"))
+    with faults.get_registry().armed("step.nan", times=1, after=STEPS + 1):
+        hist = est.fit(_indexed_stream(num_workers=4), epochs=3,
+                       batch_size=BATCH, verbose=False,
+                       checkpoint_trigger="every_epoch")
+    assert est._rollbacks == 1
+    assert est._py_step == 3 * STEPS
+    assert len(hist["loss"]) == 3
+    np.testing.assert_allclose(hist["loss"], clean["loss"], rtol=1e-6)
+    assert _pipeline_threads() == []
+
+
+def test_a_loader_exception_leaves_no_thread_behind():
+    init_orca_context("local")
+    est = _estimator()
+    with pytest.raises(OSError, match="the disk went away"):
+        est.fit(_indexed_stream(fail_at=STEPS * BATCH + 3), epochs=3,
+                batch_size=BATCH, verbose=False)
+    assert _pipeline_threads() == []
+    assert est._epoch <= 1
+
+
+def test_a_preemption_leaves_no_thread_behind(tmp_path):
+    from analytics_zoo_tpu.core.failover import Preempted
+    init_orca_context("local")
+    est = _estimator(model_dir=str(tmp_path / "ckpt"),
+                     preemption_checkpoint=True, preemption_sync_every=1)
+    try:
+        est.fit(_indexed_stream(), epochs=1, batch_size=BATCH,
+                verbose=False)
+        est._preempt._flag = True       # what the signal handler stores
+        with pytest.raises(Preempted):
+            est.fit(_indexed_stream(), epochs=3, batch_size=BATCH,
+                    verbose=False)
+    finally:
+        est._preempt.uninstall()
+    assert _pipeline_threads() == []
+
+
+def test_the_process_backend_goes_through_the_same_fit():
+    from analytics_zoo_tpu.data import shm_pool
+    if not shm_pool.available():
+        pytest.skip("process backend unavailable")
+    init_orca_context("local")
+    hist = _estimator().fit(
+        _indexed_stream(workers="process", num_workers=2), epochs=2,
+        batch_size=BATCH, verbose=False)
+    assert len(hist["loss"]) == 2
+    assert metrics.get_registry().snapshot()["train.steps"] == 2 * STEPS
+    assert _pipeline_threads() == []
+
+
 def test_the_kill_switch_silences_the_epoch_boundary_too():
     init_orca_context("local")
     reg = metrics.get_registry()
