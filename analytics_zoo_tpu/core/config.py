@@ -152,7 +152,7 @@ class ZooConfig:
     # gradient-collective compression (orca/learn/estimator.py
     # grad_compression=): None = feature off (today's implicit-psum path,
     # zero overhead); "none" = uncompressed but metered
-    # (train.comm_ms/train.grad_bytes); "bf16"/"int8" = per-shard
+    # (train.grad_bytes); "bf16"/"int8" = per-shard
     # quantized all-reduce compiled into the train step (int8 carries
     # error-feedback residuals in the train state).
     grad_compression: Optional[str] = None
@@ -234,11 +234,6 @@ class ZooConfig:
     # dumping; the ZOO_FLIGHTREC_DIR env var (set by the zoo-launch
     # supervisor next to --metrics-dir) is the fallback.
     flightrec_dir: Optional[str] = None
-    # step profiler (orca/learn/estimator.py Estimator(profile=)): the
-    # per-device peak FLOP/s the train.mfu gauge divides by.  None uses
-    # the published peak of the device_kind (core/device.py); on a
-    # platform without one (CPU) the gauge then stays unset.
-    device_peak_flops: Optional[float] = None
 
     # worker liveness (core/launcher.py gang supervision): a file this
     # process touches at init and then on training progress, so a

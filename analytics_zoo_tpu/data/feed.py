@@ -343,7 +343,7 @@ class PrefetchIterator:
     ``release()`` handle (shared-memory pool slots from the streaming
     feed's process backend) are retired one item behind the placement:
     once the NEXT item is dispatched, the previous transfer is synced
-    (its unhidden tail observed as ``feed.h2d_ms``) and the slot
+    (dispatch plus that tail observed as ``feed.h2d_ms``) and the slot
     recycled.
 
     Over a multi-epoch iterator (``FeedBase.epochs``) the producer runs
@@ -370,8 +370,7 @@ class PrefetchIterator:
         self._gauge = gauge  # e.g. the train.prefetch_depth gauge
         self._place = place
         self._staged = None  # (placed, releasable_raw, dispatch_ms)
-        self._m_h2d = (_metrics_lib.get_registry().histogram("feed.h2d_ms")
-                       if place is not None else None)
+        self._m_h2d = None  # feed.h2d_ms, made when a pool slot retires
         self._stop = threading.Event()
         self._thread = threading.Thread(target=self._produce, daemon=True,
                                         name="zoo-prefetch")
@@ -409,17 +408,15 @@ class PrefetchIterator:
         if staged is None:
             return
         placed, raw, disp_ms = staged
-        if raw is not None:
-            t0 = time.monotonic()
-            jax.block_until_ready(placed)
-            if self._m_h2d is not None:
-                self._m_h2d.observe(
-                    disp_ms + (time.monotonic() - t0) * 1000.0)
-            raw.release()
-        elif self._m_h2d is not None:
-            # no slot to recycle (thread backend): no forced sync, but
-            # the dispatch half keeps per-backend h2d comparable
-            self._m_h2d.observe(disp_ms)
+        if raw is None:  # nothing to recycle: no forced sync, no copy time
+            return
+        t0 = time.monotonic()
+        jax.block_until_ready(placed)
+        if self._m_h2d is None:
+            self._m_h2d = _metrics_lib.get_registry().histogram(
+                "feed.h2d_ms")
+        self._m_h2d.observe(disp_ms + (time.monotonic() - t0) * 1000.0)
+        raw.release()
 
     def _produce(self) -> None:
         try:

@@ -205,14 +205,15 @@ class StreamingDataFeed(FeedBase):
         # batches as fast as the workers decode them — the feed, not the
         # device, is the bottleneck
         self._m_ready = reg.gauge("feed.ready_depth")
-        # per-stage breakdown of the input pipeline (bench.py
-        # input_pipeline reads these): whole-batch decode wall, the part
-        # of it spent blocked on storage, shm-slot occupancy, and the
-        # host→device copy time not hidden by the pipeline
+        # per-stage breakdown of the input pipeline: whole-batch decode
+        # wall, the part of it spent blocked on storage, shm-slot
+        # occupancy, and (process backend: only a pool slot's copy is
+        # synced before the slot is recycled) the host→device copy time
         self._m_decode = reg.histogram("feed.decode_ms")
         self._m_io = reg.histogram("feed.io_wait_ms")
         self._m_shm = reg.gauge("feed.shm_in_use")
-        self._m_h2d = reg.histogram("feed.h2d_ms")
+        self._m_h2d = (reg.histogram("feed.h2d_ms")
+                       if workers == "process" else None)
         # span tree (core/trace.py): one trace id per epoch; per-batch
         # decode spans hang under the epoch root — the thread backend
         # records them in the worker, the process backend forwards the
@@ -406,12 +407,12 @@ class StreamingDataFeed(FeedBase):
               ) -> Iterator[Dict[str, "np.ndarray"]]:
         """One epoch's batches: the one-epoch case of ``epochs``.
         ``place=False`` yields host numpy batches (no device placement):
-        the consumer owns staging, e.g. to stack K batches into one
-        infeed-chunk transfer for ``Estimator._multi_step_data``.  Under
-        the process backend an unplaced batch is a ``SlotBatch`` of
-        zero-copy views over its shm slot — copy (``np.stack`` /
-        ``np.asarray``) or call ``.release()`` before asking for more
-        batches than the pool holds (GC releases as a safety net)."""
+        the consumer owns staging, as ``fit()``'s ``PrefetchIterator``
+        does with ``make_placer``.  Under the process backend an unplaced
+        batch is a ``SlotBatch`` of zero-copy views over its shm slot —
+        copy (``np.stack`` / ``np.asarray``) or call ``.release()``
+        before asking for more batches than the pool holds (GC releases
+        as a safety net)."""
         if self.workers == "process":
             return self._epoch_process(mesh, epoch_idx, place)
         return _batches_only(
@@ -474,11 +475,6 @@ class StreamingDataFeed(FeedBase):
                     disp_ms + (time.monotonic() - t0) * 1000.0)
                 slot.release()
                 self._m_shm.set(self._pool_in_use())
-            elif disp_ms is not None:
-                # thread backend: no slot to recycle, so no forced sync —
-                # observe the dispatch half so per-backend h2d numbers
-                # (bench input_pipeline) stay comparable
-                self._m_h2d.observe(disp_ms)
             return out
 
         pending = None

@@ -37,9 +37,9 @@ class BERT(Module):
         ~1.3x compute, the long-sequence training recipe.
 
         ``remat_attention``: checkpoint only the attention core
-        (logits/softmax recomputed in backward) — the measured training
-        throughput default at seq 512 (bench.py bert: 53.5% -> 62.9%
-        MFU on v5e); exact, and much cheaper recompute than ``remat``."""
+        (logits/softmax recomputed in backward) — what the benchmark's
+        BERT cells train with at seq 512; exact, and much cheaper
+        recompute than ``remat``."""
         super().__init__(name)
         self.remat = remat
         self.remat_attention = remat_attention

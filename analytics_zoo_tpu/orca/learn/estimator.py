@@ -200,8 +200,8 @@ class ZooEstimator:
         - ``None`` (default): feature off — today's implicit-psum step,
           bit-for-bit unchanged, zero overhead.
         - ``"none"``: uncompressed but METERED — the same step numerics
-          (bit-identical loss history, the bisection baseline) plus
-          ``train.comm_ms`` / ``train.grad_bytes`` telemetry.
+          (bit-identical loss history, the bisection baseline) plus the
+          ``train.grad_bytes`` counter.
         - ``"bf16"``: each batch shard's gradient contribution rounds to
           bfloat16 before the reduce (2 bytes/param on the wire, f32
           accumulation).
@@ -301,19 +301,13 @@ class ZooEstimator:
           ``train.compile`` span, so "why was step 847 slow?" has an
           answer (``InferenceModel.compile_count``'s pattern, applied
           to training);
-        - **MFU**: for models that declare ``flops_per_sample`` (an
-          attribute, or the dict key) — the analytic per-sample
-          training FLOPs — each epoch sets the ``train.mfu`` gauge to
-          ``flops_per_sample × samples_per_sec / (peak × n_devices)``.
-          ``peak`` comes from the dict's ``peak_flops``, then
-          ``ZooConfig.device_peak_flops``, then the published peak of
-          the ``device_kind`` (``core/device.py``); with none of the
-          three (the CPU backend) the gauge stays unset;
         - **device trace**: dict keys ``trace_dir`` + ``trace_steps``
           ``(k, k+n)`` capture a ``jax.profiler`` trace for steps
           [k, k+n) — the same machinery as the ``profile_dir`` /
           ``profile_steps`` constructor args, reachable from the one
-          ``profile=`` knob."""
+          ``profile=`` knob.
+
+        Any other key in the dict raises ``ValueError``."""
         self.model = model
         self.loss_fn = losses_lib.get(loss)
         self.tx = opt_lib.get(optimizer, learning_rate, grad_clip_norm)
@@ -356,7 +350,6 @@ class ZooEstimator:
         self._learning_rate = learning_rate
         self._sparse_paths: tuple = ()  # ShardedEmbedding table paths
         self._grad_bytes_step = 0   # analytic wire bytes per train step
-        self._comm_fn = None        # jitted all-reduce-only probe
         self._warned_mesh = False
         self.bad_steps = 0       # total non-finite steps seen (host mirror)
         self._rollbacks = 0
@@ -364,7 +357,6 @@ class ZooEstimator:
                         if log_dir else None)
         self._ts: Optional[Dict[str, Any]] = None  # train state pytree
         self._train_step = None
-        self._multi_step = None
         self._eval_step = None
         self._pred_step = None
         self._epoch = 0
@@ -378,14 +370,16 @@ class ZooEstimator:
         self.profile_dir = profile_dir
         self.profile_steps = tuple(profile_steps)
         self._profiling = False
-        # step profiler (ISSUE 9): compile events + MFU; trace_dir /
-        # trace_steps in the dict ride the jax.profiler machinery above
-        self._profile_cfg: Optional[Dict[str, Any]] = None
+        # step profiler (ISSUE 9): compile events; trace_dir / trace_steps
+        # in the dict ride the jax.profiler machinery above
+        self._profile_on = bool(profile)
         if profile:
             pcfg = {} if profile is True else dict(profile)
-            self._profile_cfg = {
-                "flops_per_sample": pcfg.get("flops_per_sample"),
-                "peak_flops": pcfg.get("peak_flops")}
+            unknown = sorted(set(pcfg) - {"trace_dir", "trace_steps"})
+            if unknown:
+                raise ValueError(
+                    f"profile= takes the keys 'trace_dir' and 'trace_steps', "
+                    f"got {unknown}")
             if pcfg.get("trace_dir"):
                 self.profile_dir = pcfg["trace_dir"]
                 self.profile_steps = tuple(
@@ -912,23 +906,7 @@ class ZooEstimator:
                                   "state": ts["state"]}, x, training=False)
             return out
 
-        def multi_step(ts, batch, k):
-            def body(carry, _):
-                carry, loss_val = train_step(carry, batch)
-                return carry, loss_val
-            return jax.lax.scan(body, ts, None, length=k)
-
-        def multi_step_data(ts, batches):
-            """K train steps over K DISTINCT batches (leading [K] axis) in
-            one executable — the infeed-chunk pattern: one host→device
-            transfer and one dispatch amortize over K steps, while every
-            step still consumes fresh data."""
-            return jax.lax.scan(train_step, ts, batches)
-
         self._train_step = jax.jit(train_step, donate_argnums=0)
-        self._multi_step = jax.jit(multi_step, static_argnums=2,
-                                   donate_argnums=0)
-        self._multi_step_data = jax.jit(multi_step_data, donate_argnums=0)
         self._eval_step = jax.jit(eval_step)
         self._pred_step = jax.jit(pred_step)
         if comp is not None:
@@ -939,49 +917,6 @@ class ZooEstimator:
                 # wire meter covers the dense leaves only
                 metered = emb_lib.split_sparse(metered)[0]
             self._grad_bytes_step = grad_wire_bytes(metered, comp)
-            self._comm_fn = None  # probe (re)compiles against this mesh
-
-    def _measure_comm_ms(self) -> Optional[float]:
-        """Wall time of the gradient all-reduce ALONE at the configured
-        wire width (``train.comm_ms``): a jitted program that materializes
-        a gradient-shaped ``[n_shards, ...]`` payload and reduces it
-        through the exact ``compressed_allreduce`` the train step
-        compiles.  The payload is filled from a runtime scalar INSIDE the
-        program — nothing params-sized stays resident between epochs, and
-        a constant input can't let XLA fold the reduce away.  Run once per
-        epoch — a dispatch, not a profiler; the compile call is warmed and
-        discarded.  Comparing the series across ``grad_compression``
-        settings is the measurable collective win (the identical fill cost
-        cancels in the comparison)."""
-        if self.grad_compression is None or self._ts is None:
-            return None
-        from analytics_zoo_tpu.parallel.util import (batch_shard_count,
-                                                     batch_shard_spec,
-                                                     compressed_allreduce)
-        mesh = get_mesh()
-        comp = self.grad_compression
-        if self._comm_fn is None:
-            s = batch_shard_count(mesh)
-            probe_params = self._ts["params"]
-            if self._sparse_paths:
-                from analytics_zoo_tpu.parallel import embedding as emb_lib
-                probe_params = emb_lib.split_sparse(probe_params)[0]
-            shapes = [tuple(p.shape) for p in
-                      jax.tree_util.tree_leaves(probe_params)]
-
-            def probe(t):
-                tree = [jax.lax.with_sharding_constraint(
-                    jnp.full((s,) + shp, t, jnp.float32),
-                    NamedSharding(mesh,
-                                  batch_shard_spec(mesh, 1 + len(shp))))
-                    for shp in shapes]
-                return compressed_allreduce(tree, comp)[0]
-
-            self._comm_fn = jax.jit(probe)
-            jax.block_until_ready(self._comm_fn(0.0))  # compile, discard
-        t0 = time.monotonic()
-        jax.block_until_ready(self._comm_fn(0.0))
-        return (time.monotonic() - t0) * 1000.0
 
     # -- training -------------------------------------------------------------
 
@@ -1030,16 +965,13 @@ class ZooEstimator:
         faults = faults_lib.get_registry()
         host_nan_check = self.nan_policy in ("warn", "rollback", "raise")
         # step-loop telemetry (core/metrics.py): handles hoisted out of
-        # the loop so the per-step cost is two monotonic reads and two
-        # histogram observes.  ``train.data_wait_ms`` is the time this
-        # loop spent blocked on the feed (input-bound signal);
-        # ``train.step_ms`` is the full iteration wall — under async
-        # dispatch the device compute of step N overlaps the host work of
-        # step N+1, so the split is "host waited on data" vs "everything
-        # else", and a rising data fraction means the input pipeline, not
-        # the TPU, is the bottleneck.
+        # the loop.  ``train.data_wait_ms`` is the time this loop spent
+        # blocked on the feed (input-bound signal): a rising share of the
+        # wall means the input pipeline, not the TPU, is the bottleneck.
+        # The loop keeps no step clock of its own: the train step is
+        # dispatched asynchronously, so a step's time is the wall of a
+        # whole fit() over its steps, or the device's from a trace.
         reg = telemetry.get_registry()
-        m_step = reg.histogram("train.step_ms")
         m_wait = reg.histogram("train.data_wait_ms")
         # the epoch boundary, once an epoch: the host time from a drained
         # device to the epoch's first dispatch (epoch-end bookkeeping, the
@@ -1053,17 +985,15 @@ class ZooEstimator:
         m_bad = reg.counter("train.bad_steps")
         m_prefetch = reg.gauge("train.prefetch_depth")
         # scale-out telemetry (docs/distributed-training.md): analytic
-        # wire bytes of the gradient collective per step, and a per-epoch
-        # all-reduce-only probe — both zero-cost unless grad_compression
-        # is configured (incl. "none", the metered uncompressed baseline)
-        m_comm = reg.histogram("train.comm_ms")
+        # wire bytes of the gradient collective per step — zero-cost
+        # unless grad_compression is configured (incl. "none", the
+        # metered uncompressed baseline)
         m_grad_bytes = reg.counter("train.grad_bytes")
-        # step profiler (profile=): compile events + the MFU gauge —
-        # handles exist only when the profiler is on, so the catalog
-        # guard and the zero-overhead default both hold
-        if self._profile_cfg is not None:
+        # step profiler (profile=): compile events — the handle exists
+        # only when the profiler is on, so the catalog guard and the
+        # zero-overhead default both hold
+        if self._profile_on:
             m_compiles = reg.counter("train.compiles")
-            m_mfu = reg.gauge("train.mfu")
         cache_prev: Optional[int] = None
         # span tree (core/trace.py): one trace per fit() — epochs under
         # the fit root, steps under their epoch — so the training loop's
@@ -1091,8 +1021,7 @@ class ZooEstimator:
         ZooEstimator._device_lock.acquire()
         try:
             first = True
-            if (self._profile_cfg is not None
-                    and self._train_step is not None):
+            if self._profile_on and self._train_step is not None:
                 # resumed fit: baseline the executable cache so only NEW
                 # compiles in this fit count as compile events
                 cache_prev = _jit_cache_size(self._train_step)
@@ -1140,7 +1069,7 @@ class ZooEstimator:
                         if first:
                             self._ensure_initialized(batch["x"])
                             first = False
-                            if self._profile_cfg is not None:
+                            if self._profile_on:
                                 # freshly built steps: cache starts
                                 # empty, so the first step's compile IS
                                 # a counted event
@@ -1177,7 +1106,7 @@ class ZooEstimator:
                         # self._ts["step"] would force a device sync on
                         # every iteration
                         self._py_step += 1
-                        if self._profile_cfg is not None:
+                        if self._profile_on:
                             # compile-event probe: the executable cache
                             # grew during THIS step ⇒ it paid a retrace
                             # (new input shape/dtype) — name the step
@@ -1191,16 +1120,15 @@ class ZooEstimator:
                                      "compiles": cs - cache_prev},
                                     parent=epoch_sid)
                             cache_prev = cs
-                        step_ms_i = (time.monotonic() - t_fetch) * 1000.0
-                        m_step.observe(step_ms_i)
                         if record_spans:
                             trace_lib.record(
                                 fit_tid, "train.step",
                                 {"step": self._py_step,
-                                 "step_ms": round(step_ms_i, 3),
                                  "data_wait_ms": round(wait * 1000.0,
                                                        3)},
-                                parent=epoch_sid, dur_ms=step_ms_i)
+                                parent=epoch_sid,
+                                dur_ms=(time.monotonic() - t_fetch)
+                                * 1000.0)
                         m_steps.inc()
                         m_samples.inc(feed.global_batch)
                         if self._grad_bytes_step:
@@ -1304,20 +1232,12 @@ class ZooEstimator:
                         self.bad_steps - bad_before)
                 dt = time.monotonic() - t0
                 n = len(losses) * feed.global_batch
-                comm_ms = self._measure_comm_ms()  # None unless configured
-                if comm_ms is not None:
-                    m_comm.observe(comm_ms)
-                # epoch-granularity telemetry mirror: the same numbers
-                # land in the registry (histograms above) AND the
-                # SummaryWriter scalars, so both snapshot() and
-                # TensorBoard answer "is the loop data-bound?"
+                # epoch-granularity means from the epoch wall, for the
+                # epoch span and the SummaryWriter scalars
                 step_ms = 1000.0 * dt / len(losses)
                 wait_ms = 1000.0 * epoch_wait / len(losses)
                 compute_ms = max(0.0, step_ms - wait_ms)
                 samples_per_sec = n / dt
-                mfu = self._measure_mfu(samples_per_sec)
-                if mfu is not None:
-                    m_mfu.set(mfu)
                 if record_spans:
                     trace_lib.record(
                         fit_tid, "train.epoch",
@@ -1406,34 +1326,6 @@ class ZooEstimator:
                     span_id=fit_sid,
                     dur_ms=(time.monotonic() - fit_t0) * 1000.0)
         return history
-
-    def _measure_mfu(self, samples_per_sec: float) -> Optional[float]:
-        """Analytic model-FLOPs utilization for the ``train.mfu`` gauge:
-        ``flops_per_sample × samples/sec / (peak_flops × n_devices)``.
-        None (gauge untouched) unless the profiler is on AND the model
-        declares ``flops_per_sample`` (or the profile dict supplies it).
-        The peak is ``profile['peak_flops']`` → ``ZooConfig.
-        device_peak_flops`` → the published peak of this ``device_kind``
-        (core/device.py; an unlisted TPU kind raises).  On a platform
-        with no published peak and none configured the gauge stays
-        unset."""
-        if self._profile_cfg is None:
-            return None
-        fps = (self._profile_cfg.get("flops_per_sample")
-               or getattr(self.model, "flops_per_sample", None))
-        if not fps:
-            return None
-        peak = self._profile_cfg.get("peak_flops")
-        if peak is None:
-            from analytics_zoo_tpu.core.context import config_default
-            peak = config_default("device_peak_flops", None)
-        if peak is None:
-            from analytics_zoo_tpu.core.device import peak_bf16_flops
-            peak = peak_bf16_flops()
-        if peak is None:
-            return None
-        return float(fps) * samples_per_sec / (float(peak)
-                                               * jax.device_count())
 
     def _rollback_to_checkpoint(self) -> None:
         """nan_policy="rollback": restore the latest ``model_dir``

@@ -52,8 +52,8 @@ from analytics_zoo_tpu.serving import (ClusterServing, InferenceModel,
                                        InputQueue, OutputQueue,
                                        enable_aot_cache)
 
-#: BERT-base as published (models/bert.py defaults) and the training recipe
-#: of bench.py's bert config: seq 512, global batch 32 = micro 4 x accum 8.
+#: BERT-base as published (models/bert.py defaults) at seq 512, global
+#: batch 32 = micro 4 x accum 8.
 REAL = dict(vocab=30522, hidden=768, layers=12, heads=12, seq=512,
             micro=4, accum=8, image=224, classes=1000, width=64,
             flash_bh=(4, 12), flash_d=64, flash_t=((2048, False),
@@ -96,8 +96,7 @@ class BertMLM(Module):
 
 
 class ServeNet(Module):
-    """uint8 NHWC -> on-device normalize -> ResNet-18 classifier (the model
-    of bench.py's serving config)."""
+    """uint8 NHWC -> on-device normalize -> ResNet-18 classifier."""
 
     def __init__(self, classes: int = 1000, width: int = 64):
         super().__init__()

@@ -288,6 +288,56 @@ def test_replica_hard_kill_mid_job_zero_lost_rows(tmp_path):
     np.testing.assert_allclose(rep.output(), rows * 2.0, rtol=1e-6)
 
 
+def test_interactive_clients_see_zero_errors_while_a_job_streams(tmp_path):
+    """Batch and interactive traffic share one 2-replica pool: while a
+    journaled job streams ``klass="batch"`` rows through it, closed-loop
+    interactive clients get every answer (zero client-visible errors,
+    each the model's answer for its own row) and the job's output is
+    row-for-row exact."""
+    rows = _rows(600)
+    with _serve(_Model(delay=0.002)) as s1, \
+            _serve(_Model(delay=0.002)) as s2:
+        rs = ReplicaSet([(s1.host, s1.port), (s2.host, s2.port)],
+                        retry=_fast_retry(max_attempts=4))
+        job_done = threading.Event()
+        errors, served = [], []
+
+        def interactive(i):
+            x = rows[i]
+            while not job_done.is_set():
+                try:
+                    out = rs.predict(x, deadline=15.0, klass="interactive")
+                except Exception as e:  # noqa: BLE001 — the failure record
+                    errors.append(f"{type(e).__name__}: {e}"[:200])
+                    continue
+                if out is None:
+                    errors.append("timeout")
+                elif not np.allclose(out, x * 2.0, rtol=1e-6):
+                    errors.append(f"wrong answer for row {i}: {out}")
+                else:
+                    served.append(i)
+
+        clients = [threading.Thread(target=interactive, args=(i,))
+                   for i in range(3)]
+        try:
+            for t in clients:
+                t.start()
+            with BatchScorer(rs, str(tmp_path / "job"), shard_size=50,
+                             max_inflight=4, retry=_fast_retry(),
+                             request_timeout=30.0) as sc:
+                rep = sc.score(rows)
+        finally:
+            job_done.set()
+            for t in clients:
+                t.join(timeout=30.0)
+            rs.close()
+        assert not any(t.is_alive() for t in clients)
+    assert errors == [], errors[:5]
+    assert len(served) > 0, "no interactive request ran beside the job"
+    assert (rep.rows, rep.n_shards, rep.scored_shards) == (600, 12, 12)
+    np.testing.assert_allclose(rep.output(), rows * 2.0, rtol=1e-6)
+
+
 # -- shadow validation + promotion --------------------------------------------
 
 def test_shadow_validation_promotes_identical_candidate(tmp_path):

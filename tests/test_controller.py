@@ -321,33 +321,83 @@ def test_controller_scales_up_then_down_with_zero_errors(tmp_path):
     errors = []
     x = np.ones((2,), np.float32)
 
-    def drive(n):
-        for _ in range(n):
-            try:
-                out = rs.predict(x, deadline=10.0)
-                assert np.allclose(out, 2.0)
-            except Exception as e:  # noqa: BLE001 - counted, not masked
-                errors.append(e)
+    burst_over = threading.Event()
 
+    def request():
+        try:
+            out = rs.predict(x, deadline=10.0)
+            assert np.allclose(out, 2.0)
+        except Exception as e:  # noqa: BLE001 - counted, not masked
+            errors.append(e)
+
+    def burst_client():
+        while not burst_over.is_set():
+            request()
+
+    # The window the next ``ctl.tick()`` will judge is what moved since
+    # the controller last read ``client.request_ms``; ``window_open``
+    # mirrors that baseline so the test can look before the tick does.
+    window_open = {}
+
+    def window_p99():
+        delta = metrics_lib.snapshot_delta(
+            window_open, metrics_lib.get_registry().snapshot())
+        window = metrics_lib.MetricsRegistry.merge(
+            [{"client.request_ms": v} for s, v in delta.items()
+             if s.startswith("client.request_ms")],
+            drop_labels=("replica",)).get("client.request_ms")
+        return metrics_lib.quantile_from_snapshot(window, 0.99) or 0.0
+
+    def advance(read):
+        """``ctl.tick`` judges the window and moves the baseline;
+        ``ctl.signals`` moves it and decides nothing (reads a window
+        away)."""
+        out = read()
+        window_open.clear()
+        window_open.update(metrics_lib.get_registry().snapshot())
+        return out
+
+    def trickle(n):
+        """n sequential requests that read calm.  A loaded host can delay
+        one past the low-water mark; such a window is read away and the
+        trickle sent again."""
+        deadline = time.monotonic() + 30.0
+        while True:
+            for _ in range(n):
+                request()
+            if window_p99() <= pol.slo_p99_ms * pol.low_water_frac:
+                return
+            assert time.monotonic() < deadline, \
+                f"a sequential trickle never read calm: {window_p99()}"
+            advance(ctl.signals)
+
+    threads = [threading.Thread(target=burst_client) for _ in range(40)]
     try:
         # calm baseline: sequential trickle stays under the SLO
-        drive(5)
-        assert ctl.tick() == 0 and len(rs.replicas) == 1
-        # 10x step: concurrent closed-loop clients queue behind the
-        # 10ms-per-batch model and p99 blows through the SLO
-        threads = [threading.Thread(target=drive, args=(10,))
-                   for _ in range(10)]
+        trickle(5)
+        assert advance(ctl.tick) == 0 and len(rs.replicas) == 1
+        # the step: 40 closed-loop clients queue behind the 10ms-per-batch
+        # model (batches of 4) and p99 blows through the SLO
         for t in threads:
             t.start()
-        time.sleep(0.3)  # mid-burst: the tick sees hot signals
-        assert ctl.tick() == 1
+        # mid-burst: wait for the hot signals the tick is about to judge
+        deadline = time.monotonic() + 30.0
+        while window_p99() <= pol.slo_p99_ms:
+            assert time.monotonic() < deadline, \
+                f"the burst never went hot: p99 {window_p99()}"
+            time.sleep(0.02)
+        assert advance(ctl.tick) == 1
         assert len(rs.replicas) == 2
+        burst_over.set()
         for t in threads:
-            t.join()
+            t.join(timeout=30.0)
+        assert not any(t.is_alive() for t in threads)
         # load drops: two calm ticks later the added replica drains out
-        drive(3)
-        ctl.tick()
-        assert ctl.tick() == -1
+        advance(ctl.signals)  # the burst's tail
+        trickle(3)
+        assert advance(ctl.tick) == 0
+        trickle(3)
+        assert advance(ctl.tick) == -1
         assert len(rs.replicas) == 1
         assert not errors, errors
         assert [e["direction"] for e in ctl.events] == ["up", "down"]
@@ -364,6 +414,7 @@ def test_controller_scales_up_then_down_with_zero_errors(tmp_path):
         assert ctx["replica"] == ctl.events[-1]["replica"]
         assert "p99_ms" in ctx and "queue_depth" in ctx
     finally:
+        burst_over.set()
         ctl.close()
         rs.close()
         seed.stop()

@@ -24,3 +24,15 @@ def test_doc_snippets_execute(doc):
             exec(compile(code, f"{doc.name}[block {i}]", "exec"), ns)
         except Exception as e:
             pytest.fail(f"{doc.name} block {i} failed: {e}")
+
+
+def test_docs_quote_no_deleted_benchmark():
+    """The trainer is measured in one place, ``benchmark/``: what a user
+    reads first (the README, ``docs/``) sends them to no second one.
+    The records (CHANGES.md, PERF.md, ROADMAP.md, BASELINE.md, PARITY.md,
+    benchmark/README.md) may name what was deleted."""
+    readme = pathlib.Path(__file__).parent.parent / "README.md"
+    for page in [readme, *DOCS]:
+        text = page.read_text()
+        for gone in ("bench.py", "BENCH_r0"):
+            assert gone not in text, f"{page.name} names {gone}"

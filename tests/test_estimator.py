@@ -349,3 +349,27 @@ def test_fit_prefetch_with_streaming_feed():
     hist = est.fit(feed, epochs=2, batch_size=32, verbose=False,
                    prefetch=2)
     assert len(hist["loss"]) == 2
+
+
+def test_repeated_fits_of_one_shape_compile_the_train_step_once():
+    """The benchmark's own condition (``correct`` needs ``compile_count``
+    1 after set-up and no more in the window): a one-step fit, a
+    one-epoch fit and a three-epoch fit of the same batch shape on one
+    estimator share one train-step executable."""
+    from analytics_zoo_tpu.data import StreamingDataFeed
+    init_orca_context("local")
+    rng = np.random.default_rng(0)
+    xs = rng.normal(size=(128, 8)).astype(np.float32)
+    ys = (xs.sum(axis=1, keepdims=True) > 0).astype(np.float32)
+
+    def feed(rows):
+        return StreamingDataFeed(
+            rows, lambda i, rng=None: {"x": xs[i], "y": ys[i]},
+            batch_size=32, shuffle=True, seed=1, num_workers=2)
+
+    est = Estimator.from_keras(nn.Sequential([nn.Dense(1)]), loss="mse",
+                               learning_rate=1e-2, profile=True)
+    for rows, epochs in ((32, 1), (128, 1), (128, 3)):
+        est.fit(feed(rows), epochs=epochs, batch_size=32, verbose=False)
+        assert est.compile_count == 1, (rows, epochs)
+    assert est._py_step == 1 + 4 + 12

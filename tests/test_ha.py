@@ -528,7 +528,10 @@ def test_ha_acceptance_replica_kill_and_rolling_restart_zero_failures():
     for t in threads + [poller]:
         t.start()
     try:
-        time.sleep(0.4)                      # steady state, both serving
+        deadline = time.monotonic() + 10     # steady state, both serving
+        while not served and not failures:
+            assert time.monotonic() < deadline, "no request was served"
+            time.sleep(0.01)
         n_steady = len(served)
         assert n_steady > 0 and not failures
 
@@ -538,13 +541,20 @@ def test_ha_acceptance_replica_kill_and_rolling_restart_zero_failures():
         while not servers[0]._stop.is_set():
             assert time.monotonic() < deadline, "kill fault never fired"
             time.sleep(0.01)
-        time.sleep(0.6)                      # load keeps flowing degraded
-        hz = rs.healthz()
-        assert not hz["replicas"][names[0]]["available"]
-        # the circuit opened (breaker) — the dead replica costs nothing
-        snap = metrics_lib.get_registry().snapshot()
-        assert snap.get(f"router.breaker_opens{{replica={names[0]}}}",
-                        0) >= 1
+        # load keeps flowing degraded until the router has noticed: the
+        # replica is reported unavailable and its circuit has opened
+        # (breaker) — the dead replica costs nothing
+        opens = f"router.breaker_opens{{replica={names[0]}}}"
+        deadline = time.monotonic() + 10
+        while True:
+            rep = rs.healthz()["replicas"][names[0]]
+            snap = metrics_lib.get_registry().snapshot()
+            if not rep["available"] and snap.get(opens, 0) >= 1:
+                break
+            assert time.monotonic() < deadline, \
+                f"killed replica never ejected: {rep}, opens=" \
+                f"{snap.get(opens, 0)}"
+            time.sleep(0.02)
 
         # ---- replica returns: circuit re-closes, health re-admits -------
         servers[0] = _restart_on_port(_Model(), ports[0])

@@ -386,11 +386,9 @@ def test_fit_reports_step_time_and_data_wait_split(tmp_path):
     est, hist = _tiny_fit(log_dir=str(tmp_path), epochs=2)
     steps = 2 * (128 // 32)
     snap = metrics.get_registry().snapshot()
-    assert snap["train.step_ms"]["count"] == steps
     assert snap["train.data_wait_ms"]["count"] == steps
     assert snap["train.steps"] == steps
     assert snap["train.samples"] == steps * 32
-    assert snap["train.step_ms"]["sum"] >= snap["train.data_wait_ms"]["sum"]
     for tag in ("step_time_ms", "data_wait_ms", "compute_ms",
                 "samples_per_sec", "throughput", "loss"):
         scalars = est.get_train_summary(tag)
@@ -504,30 +502,62 @@ def test_gang_status_tolerates_legacy_touch_files(tmp_path):
     assert _read_heartbeat_payload(str(hb)) == {}
 
 
-def test_bench_registry_detail_populates_after_fit():
-    """bench.py's record detail carries the step-time p50/p99 snapshot
-    (the bench-trajectory satellite)."""
-    import os
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
+# The series PR 22 judged "wrong source, not read": an asynchronous
+# dispatch timed as a step, an MFU over an epoch wall with feed start-up in
+# it, a probe program's all-reduce, and the dispatch half of a copy.  The
+# step clocks, the MFU and the collective times are the benchmark's.
+
+def _stream_fit(**est_kw):
+    from analytics_zoo_tpu.data import StreamingDataFeed
+    from analytics_zoo_tpu.orca.learn import Estimator
+    rng = np.random.default_rng(0)
+    xs = rng.normal(size=(64, 4)).astype(np.float32)
+    ys = rng.normal(size=(64, 1)).astype(np.float32)
+    feed = StreamingDataFeed(
+        64, lambda i, rng=None: {"x": xs[i], "y": ys[i]}, batch_size=16,
+        shuffle=False, num_workers=2, workers="thread")
+    est = Estimator.from_keras(nn.Sequential([nn.Dense(1)]), loss="mse",
+                               learning_rate=1e-3, profile=True, **est_kw)
+    est.fit(feed, epochs=2, batch_size=16, verbose=False, prefetch=2)
+    return est
+
+
+@pytest.mark.parametrize("series, est_kw", [
+    ("train.step_ms", {}),
+    ("train.mfu", {}),
+    ("train.comm_ms", {"grad_compression": "none"}),
+    ("feed.h2d_ms", {}),
+])
+def test_fit_moves_no_wrong_source_series(series, est_kw):
     init_orca_context("local")
-    _tiny_fit(epochs=1)
-    out = bench._train_registry_detail()
-    for key in ("train.step_ms.p50", "train.step_ms.p99",
-                "train.data_wait_ms.p50", "train.steps", "train.samples"):
-        assert key in out, key
-    assert out["train.steps"] == 4
+    est = _stream_fit(**est_kw)
+    assert est.compile_count == 1
+    snap = metrics.get_registry().snapshot()
+    assert snap["train.steps"] == 8
+    if series.startswith("train."):
+        assert series not in snap  # nothing in the program makes it
+    else:
+        # a process-backend fit in this process may have made the handle
+        # (test_stream_shm.py); a thread-backend fit must not feed it
+        assert snap.get(series, {"count": 0})["count"] == 0
+
+
+@pytest.mark.parametrize("key", ["flops_per_sample", "peak_flops"])
+def test_profile_rejects_unknown_keys(key):
+    from analytics_zoo_tpu.orca.learn import Estimator
+    init_orca_context("local")
+    with pytest.raises(ValueError, match=key):
+        Estimator.from_keras(nn.Sequential([nn.Dense(1)]), loss="mse",
+                             profile={key: 1e9})
 
 
 # -- overhead guard -----------------------------------------------------------
 
 @pytest.mark.slow
 def test_step_loop_instrumentation_overhead_under_5_percent():
-    """Acceptance criterion: the per-step telemetry (two histogram
-    observes + two counter incs + the heartbeat check) costs < 5% of a
-    tiny model's step loop.  Best-of-5 epochs per mode to shave CPU
+    """Acceptance criterion: the per-step telemetry (a histogram
+    observe + a span record + two counter incs + the heartbeat check)
+    costs < 5% of a tiny model's step loop.  Best-of-5 epochs per mode to shave CPU
     scheduling noise; compiled executables are warmed first."""
     from analytics_zoo_tpu.orca.learn import Estimator
     init_orca_context("local")

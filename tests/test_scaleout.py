@@ -249,14 +249,12 @@ def test_grad_compression_quantized_tracks_uncompressed():
     assert float(np.abs(np.asarray(ef0)).sum()) > 0  # banked rounding error
 
 
-def test_grad_bytes_and_comm_ms_metered():
-    """train.grad_bytes asserts the ≥4× int8 wire cut; train.comm_ms
-    records the per-epoch all-reduce probe."""
+def test_grad_bytes_metered():
+    """train.grad_bytes asserts the ≥4× int8 wire cut."""
     _fit({"data": 8}, grad_compression="none", epochs=1)
     snap = metrics.get_registry().snapshot()
     none_bytes = snap["train.grad_bytes"]
     assert none_bytes > 0
-    assert snap["train.comm_ms"]["count"] >= 1
     metrics.get_registry().reset()
     _fit({"data": 8}, grad_compression="int8", epochs=1)
     int8_bytes = metrics.get_registry().snapshot()["train.grad_bytes"]

@@ -209,6 +209,29 @@ class TestProcessBackend:
         assert not _shm_leaks()
 
 
+@needs_process
+def test_process_backend_observes_the_whole_copy_in_feed_h2d_ms():
+    """Under ``workers="process"`` a batch's host->device copy is synced
+    before its shm slot is recycled, so ``feed.h2d_ms`` holds one whole
+    copy (dispatch + synced tail) a batch placed."""
+    from analytics_zoo_tpu.core import metrics
+    init_orca_context("local")
+    feed = StreamingDataFeed(
+        32, lambda i, rng=None: {"x": np.full((3,), float(i), np.float32),
+                                 "y": np.float32([i % 2])},
+        batch_size=8, shuffle=False, num_workers=2, workers="process")
+    est = Estimator.from_keras(nn.Sequential([nn.Dense(1)]), loss="mse",
+                               learning_rate=1e-3)
+    est.fit(feed, epochs=2, batch_size=8, verbose=False, prefetch=2)
+    snap = metrics.get_registry().snapshot()
+    assert snap["train.steps"] == 8
+    # every batch but the last retires inside the producer's loop; the
+    # last one's slot is left to close()
+    assert snap["feed.h2d_ms"]["count"] in (7, 8)
+    assert snap["feed.h2d_ms"]["sum"] > 0
+    assert not _shm_leaks()
+
+
 # -- pooled tail loading ------------------------------------------------------
 
 class TestTailThroughWorkerPool:

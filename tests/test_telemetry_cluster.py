@@ -10,8 +10,8 @@ rotation, THE acceptance criteria — a hedged two-replica request whose
 ``trace.tree`` reconstructs root → attempt spans → server-side
 assembly/inference/reply spans, and a hard-killed replica whose flight
 record names its in-flight trace ids with zero client-visible failures
-— plus the estimator's step profiler (train.mfu, compile events, fit
-span tree) and the serving-side instrumentation overhead guard (slow).
+— plus the estimator's step profiler (compile events, fit span tree)
+and the serving-side instrumentation overhead guard (slow).
 """
 
 import glob
@@ -557,25 +557,20 @@ def test_cluster_scope_scrape_merges_replica_registries():
 
 # -- step profiler ------------------------------------------------------------
 
-def test_step_profiler_mfu_compiles_and_fit_span_tree():
+def test_step_profiler_compiles_and_fit_span_tree():
     from analytics_zoo_tpu.orca.learn import Estimator
-    init_orca_context("local", config=ZooConfig(device_peak_flops=1e9))
+    init_orca_context("local")
     rng = np.random.default_rng(0)
     x = rng.normal(size=(128, 4)).astype(np.float32)
     y = rng.normal(size=(128, 1)).astype(np.float32)
     model = nn.Sequential([nn.Dense(8, activation="relu"), nn.Dense(1)])
     est = Estimator.from_keras(model, loss="mse", learning_rate=1e-3,
-                               profile={"flops_per_sample": 1e6})
+                               profile=True)
     est.fit((x, y), epochs=2, batch_size=32, verbose=False)
     snap = metrics_lib.get_registry().snapshot()
     # compile events: the first step's XLA compile was detected
     assert snap["train.compiles"] >= 1
     assert est.compile_count >= 1
-    # MFU: flops_per_sample × samples/s ÷ (peak × devices) — positive
-    # and consistent with the declared analytics
-    mfu = snap["train.mfu"]["value"]
-    assert mfu > 0
-    assert snap["train.mfu"]["max"] >= mfu
     # the fit's span tree: train.fit → train.epoch ×2 → train.step ×4
     (root,) = trace_lib.tree(est.trace_id)
     assert root.name == "train.fit"
@@ -602,7 +597,6 @@ def test_profiler_off_registers_no_profiler_series():
     # profiler series may linger (zeroed) from another test's pinned
     # handles on the process-global registry — what matters is that an
     # unprofiled fit never MOVES them
-    assert snap.get("train.mfu", {"value": 0})["value"] == 0
     assert snap.get("train.compiles", 0) == 0
 
 
@@ -626,7 +620,7 @@ def test_heartbeat_embeds_registry_snapshot_when_supervised(
     payload = json.loads(hb.read_text())
     snap = payload["metrics"]
     assert snap["train.steps"] == 2
-    assert snap["train.step_ms"]["count"] == 2
+    assert snap["train.data_wait_ms"]["count"] >= 2
     # the payload is exactly what _fold_gang_snapshots consumes
     merged = _fold_gang_snapshots({(0, 0): snap, (1, 0): snap})
     assert merged["train.steps"] == 4
