@@ -109,11 +109,16 @@ class Qwen3Next(ZooModel):
         for i in range(self.n_layers):
             block = self._block(i)
             if self.remat:
-                # the flash kernel's output is kept: 0.13 GB a layer at
-                # 8k x 2 rows against a second run of the kernel
+                # what the kernels' backward passes read is kept, so a
+                # block's recomputation runs neither again: the flash
+                # kernel's output (0.13 GB a layer at 8k x 2 rows), the
+                # delta rule's output, chunk-start states and inverses
+                # (0.13 + 0.27 + 0.13 GB)
                 x = scope.child(
-                    nn.Remat(block, save_names=("flash_attention_out",
-                                                "flash_attention_lse")),
+                    nn.Remat(block, save_names=(
+                        "flash_attention_out", "flash_attention_lse",
+                        "gated_delta_rule_out", "gated_delta_rule_states",
+                        "gated_delta_rule_inverse")),
                     x, name=f"remat_{i}")
             else:
                 x = scope.child(block, x, name=f"layer_{i}")

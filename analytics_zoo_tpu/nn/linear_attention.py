@@ -18,10 +18,22 @@ positions into dense products: inside a chunk the ``u`` of every position
 solve one unit-lower-triangular system, ``(I + tril(diag(beta) (K K^T * D),
 -1)) U = diag(beta) (V - (K * d) S)``, and only the chunk-to-chunk carry of
 ``S`` is sequential: ``T / C`` steps of two ``[C, d] x [d, d]`` products a
-head.  Everything outside that carry is computed for all chunks at once.
-The backward pass is autodiff through the same program (``lax.scan``'s
-transpose walks the chunks in reverse); wrap the layer in ``nn.Remat`` to
-keep a block's chunk states out of the saved activations.
+head.
+
+Two implementations of that one algorithm, chosen by :func:`gated_delta_rule`
+from the backend and the shapes.  On a TPU, for heads of whole 128-lane
+tiles, the Pallas kernels of ``ops/gated_delta_rule.py``: a chunk's ``[C, C]``
+terms and the carried state live in VMEM, forward (``gated_delta_rule_fwd``)
+and backward (``gated_delta_rule_bwd``, a ``jax.custom_vjp`` that walks the
+chunks in reverse and reads the chunk-start states and the inverses the
+forward kept).  Elsewhere the ``jax.numpy`` form below, where everything
+outside the carry is computed for all chunks at once and the backward pass
+is autodiff through the same program (``lax.scan``'s transpose walks the
+chunks in reverse); it is also the oracle of the kernels' tests.  Wrap the
+layer in ``nn.Remat`` to keep a block's activations out of the saved set;
+``save_names=("gated_delta_rule_out", "gated_delta_rule_states",
+"gated_delta_rule_inverse")`` keeps what the backward kernel reads, so the
+recomputation runs no kernel.
 """
 
 from __future__ import annotations
@@ -33,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from ..ops import gated_delta_rule as kernels
 from .layers import CausalConv1D, Dense, RMSNorm
 from .module import Module, Scope
 
@@ -78,24 +91,55 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
                      ) -> Tuple[jax.Array, jax.Array]:
     """The recurrence of this module's docstring, in chunks of ``chunk``.
 
-    q, k: ``[B, T, H, d_k]``; v: ``[B, T, H, d_v]``; g (log decay, <= 0) and
-    beta: ``[B, T, H]`` float32.  q and k are used as given (normalise and
-    scale them outside).  Returns ``(o [B, T, H, d_v] in v's dtype, final
-    state [B, H, d_k, d_v] float32)``.  Matmul operands keep q/k/v's dtype
-    (bf16 on the MXU), sums and the state are float32.  Any T: the tail is
-    padded with g = 0, beta = 0 (a position that neither forgets nor
-    writes) and cut off again.
+    q, k: ``[B, T, H_k, d_k]``; v: ``[B, T, H, d_v]`` with ``H`` a multiple
+    of ``H_k`` (value head ``i`` reads key head ``i // (H / H_k)``); g (log
+    decay, <= 0) and beta: ``[B, T, H]`` float32.  q and k are used as given
+    (normalise and scale them outside).  Returns ``(o [B, T, H, d_v] in v's
+    dtype, final state [B, H, d_k, d_v] float32)``.  Matmul operands keep
+    q/k/v's dtype (bf16 on the MXU), sums and the state are float32.  Any T:
+    the tail is padded with g = 0, beta = 0 (a position that neither forgets
+    nor writes) and cut off again.
+
+    One algorithm, two implementations, chosen from what the call can see:
+    on backend ``tpu``, for heads of whole 128-lane tiles and the chunk the
+    kernels were written for, the Pallas kernels of
+    ``ops/gated_delta_rule.py`` (or the compiler's error); elsewhere the
+    ``jax.numpy`` form below, which is also the oracle of the kernels'
+    tests (they set ``ops.gated_delta_rule.INTERPRET``).
     """
-    b, t, h, dk = k.shape
-    dv = v.shape[-1]
-    dt = v.dtype
-    pad = -t % chunk
+    b, t, hk, dk = k.shape
+    h, dv = v.shape[2:]
+    if h % hk:
+        raise ValueError(f"{h} value heads are no multiple of {hk} key heads")
+    interpret = kernels.dispatch(dk, dv, chunk)
+    use_kernels = interpret is not None
+    pad = -t % (kernels.tile_rows(chunk) if use_kernels else chunk)
     if pad:
         q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
                    for a in (q, k, v))
         g, beta = (jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
                    for a in (g, beta))
-    n = (t + pad) // chunk
+    g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
+    s0 = (jnp.zeros((b, h, dk, dv), jnp.float32) if initial_state is None
+          else initial_state.astype(jnp.float32))
+    if use_kernels:
+        o, s_final = kernels.chunk_kernels(q, k, v, g, beta, s0, chunk,
+                                           interpret)
+    else:
+        if h != hk:
+            q, k = (jnp.repeat(a, h // hk, axis=2) for a in (q, k))
+        o, s_final = _chunked_jax(q, k, v, g, beta, s0, chunk)
+    return o[:, :t], s_final
+
+
+def _chunked_jax(q, k, v, g, beta, s0, chunk):
+    """The chunked form in ``jax.numpy``: every head its own q and k, T a
+    multiple of ``chunk``, g and beta float32.  Autodiff gives its backward
+    pass (``lax.scan``'s transpose walks the chunks in reverse)."""
+    b, t, h, dk = k.shape
+    dv = v.shape[-1]
+    dt = v.dtype
+    n = t // chunk
 
     def chunks(a):  # [B, T, H, ...] -> [B, H, N, C, ...]
         a = a.reshape((b, n, chunk) + a.shape[2:])
@@ -137,8 +181,7 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
     u, w, k_tail, decay_last, qk, q_in = jax.checkpoint(
         chunk_terms,
         policy=jax.checkpoint_policies.save_only_these_names("gdn_inverse"))(
-        chunks(q), chunks(k), chunks(v), chunks(g.astype(jnp.float32)),
-        chunks(beta.astype(jnp.float32)))
+        chunks(q), chunks(k), chunks(v), chunks(g), chunks(beta))
 
     def carry(s, xs):
         w_i, u_i, k_i, decay_i = xs
@@ -149,8 +192,6 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
             "bhck,bhcd->bhkd", k_i, v_new, **f32)
         return s_next, (s_in, v_new)
 
-    s0 = (jnp.zeros((b, h, dk, dv), jnp.float32) if initial_state is None
-          else initial_state.astype(jnp.float32))
     lead = lambda x: jnp.moveaxis(x, 2, 0)                   # N first
     s_final, (s_in, v_new) = jax.lax.scan(
         carry, s0, (lead(w), lead(u), lead(k_tail), lead(decay_last)))
@@ -159,7 +200,7 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
     # read: what the incoming state answers, plus the chunk's own writes
     o = jnp.einsum("...ck,...kd->...cd", q_in, s_in, **f32)
     o = o + jnp.einsum("...ij,...jd->...id", qk, v_new, **f32)
-    o = jnp.moveaxis(o, 1, 3).reshape(b, n * chunk, h, dv)[:, :t]
+    o = jnp.moveaxis(o, 1, 3).reshape(b, t, h, dv)
     return o.astype(dt), s_final
 
 
@@ -240,9 +281,7 @@ class GatedDeltaNet(Module):
                 jnp.square(af).sum(-1, keepdims=True) + self.epsilon)
         q = (l2norm(q) * dk ** -0.5).astype(x.dtype)
         k = l2norm(k).astype(x.dtype)
-        if hv != hk:  # value head i reads key head i // (hv / hk)
-            q = jnp.repeat(q, hv // hk, axis=2)
-            k = jnp.repeat(k, hv // hk, axis=2)
+        # value head i reads key head i // (hv / hk)
         o, _ = gated_delta_rule(q, k, v, g, beta, chunk=self.chunk)
 
         o = scope.child(RMSNorm(self.epsilon), o, name="norm")

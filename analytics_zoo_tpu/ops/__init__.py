@@ -4,7 +4,10 @@ Reference parity note (SURVEY.md §2.10): the reference's native kernel layer
 was Intel MKL/MKL-DNN behind BigDL's JNI `Engine`.  The TPU-native equivalent
 is (a) XLA's own fusions for almost everything, plus (b) the Pallas kernels in
 this package for the few ops where a hand schedule beats XLA — today that is
-flash attention (O(T) memory softmax-attention, MXU-tiled).
+flash attention (O(T) memory softmax-attention, MXU-tiled) and the chunked
+gated delta rule (``gated_delta_rule``: the linear-attention recurrence with
+a chunk's terms and the state in VMEM, forward and backward; called through
+``nn.linear_attention.gated_delta_rule``).
 """
 
 from .flash_attention import flash_attention, mha_reference

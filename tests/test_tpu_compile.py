@@ -30,6 +30,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 # the module, not the function ops/__init__ re-exports under its name
 fa = importlib.import_module("analytics_zoo_tpu.ops.flash_attention")
+gdr = importlib.import_module("analytics_zoo_tpu.ops.gated_delta_rule")
 
 HBM_BYTES = 16 * 2 ** 30  # one v5e chip
 
@@ -102,6 +103,28 @@ def test_flash_backward_compiles(chip):
     lse = _on(chip, jax.ShapeDtypeStruct((48, t), jnp.float32))
     _compile(lambda q, k, v, o, l, g: fa._blocked_bwd_jax(
         q, k, v, o, l, g, 0.125, True, 256), x, x, x, x, lse, x)
+
+
+# one DeltaNet layer of the Qwen3-Next cell: two rows of 8192 tokens, 16 key
+# and 32 value heads of 128, chunks of 64 in tiles of 128 rows
+@pytest.mark.parametrize("which", ["forward", "forward_for_a_gradient",
+                                   "backward"])
+def test_delta_rule_kernels_compile(chip, which):
+    b, t, hk, hv, d = 2, 8192, 16, 32, 128
+    on = lambda shape, dtype: _on(chip, jax.ShapeDtypeStruct(shape, dtype))
+    qk, v = on((b, t, hk, d), jnp.bfloat16), on((b, t, hv, d), jnp.bfloat16)
+    gate, state = on((b, t, hv), jnp.float32), on((b, hv, d, d), jnp.float32)
+    if which == "backward":
+        states = on((b, hv, t // 64, d, d), jnp.bfloat16)
+        inverse = on((b, hv, t // 128, 64, 128), jnp.float32)
+        compiled = _compile(
+            lambda *a: gdr._bwd_call(*a, 64, False), qk, qk, v, gate, gate,
+            states, inverse, v, state)
+    else:
+        compiled = _compile(
+            lambda *a: gdr._fwd_call(*a, 64, which != "forward", False),
+            qk, qk, v, gate, gate, state)
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_bert_base_layer_fwd_bwd_compiles(chip):
@@ -260,9 +283,11 @@ def test_qwen3next_cell_train_step_compiles_for_one_chip(topo, monkeypatch):
     """``qwen3next_ep16_fit_s8192``'s train step at its real sizes (625.7 M
     parameters with AdamW's moments, two rows of 8192 tokens): the chip's
     compiler takes it — a program over the chip's memory is refused here —
-    with the flash kernel under its name, the grouped matmuls of the expert
-    layer as XLA's ragged-dot kernels, and one kernel call a step (the
-    blocks' recomputation keeps the kernel's output)."""
+    with the flash kernel and the two delta-rule kernels under their names,
+    the grouped matmuls of the expert layer as XLA's ragged-dot kernels,
+    and one call of each kernel a layer and step (the blocks'
+    recomputation keeps what the kernels' backward passes read: three
+    DeltaNet layers, one attention layer)."""
     import json
     from analytics_zoo_tpu.orca.learn import Estimator
     from benchmark.families import qwen3_next
@@ -271,6 +296,7 @@ def test_qwen3next_cell_train_step_compiles_for_one_chip(topo, monkeypatch):
         fa, "_flash_fwd_dispatch",
         lambda q, k, v, causal, bq, bk: fa._padded_pallas(
             q, k, v, q.shape[-1] ** -0.5, causal, bq, bk, interpret=False))
+    monkeypatch.setattr(gdr, "dispatch", lambda dk, dv, chunk: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(
             root, "benchmark/configs/qwen3_next_80b_a3b_ep16.json")) as f:
@@ -281,9 +307,14 @@ def test_qwen3next_cell_train_step_compiles_for_one_chip(topo, monkeypatch):
         learning_rate=config["optimizer"]["learning_rate"])
     ids = jax.ShapeDtypeStruct((2, 8192), jnp.int32)
     mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
-    text = _abstract_train_step(est, mesh, ids, ids).compile().as_text()
+    compiled = _abstract_train_step(est, mesh, ids, ids).compile()
+    assert _per_chip_bytes(compiled) < HBM_BYTES
+    text = compiled.as_text()
     kernels = re.findall(r"%(flash_attention_fwd[.\d]*) = ", text)
     assert len(kernels) == 1, kernels
+    for name in ("gated_delta_rule_fwd", "gated_delta_rule_bwd"):
+        calls = re.findall(rf"%({name}[.\d]*) = ", text)
+        assert len(calls) == 3, (name, calls)
     assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) >= 6 * 4
     held = sum(int(np.prod(l.shape)) for l in
                jax.tree_util.tree_leaves(est._ts["params"]))
