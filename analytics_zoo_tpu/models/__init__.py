@@ -4,9 +4,10 @@ Every family from the reference's Scala+Py twin zoo, rebuilt as pure-JAX
 modules over analytics_zoo_tpu.nn: recommendation (NeuralCF, WideAndDeep,
 SessionRecommender), text classification, text matching (KNRM), anomaly
 detection, seq2seq, image classification (ResNet), object detection (SSD),
-plus the BERT family the reference shipped through TFPark, and a hybrid
-linear-attention / sparse-expert causal decoder (Qwen3Next) the reference
-had no analog of.
+plus the BERT family the reference shipped through TFPark, and two
+sparse-expert causal decoders the reference had no analog of: a hybrid
+linear-attention one (Qwen3Next) and a sliding-window / full-attention one
+with a bias-balanced sigmoid router (AFMoE).
 """
 
 from .common import ZooModel
@@ -21,6 +22,7 @@ from .image import ImageClassifier, ResNet
 from .objectdetection import ObjectDetector, SSDLite, Visualizer
 from .bert import BERT, BERTClassifier, BERTNER, BERTSQuAD
 from .qwen3_next import Qwen3Next
+from .afmoe import AFMoE
 from .graphnet import GraphNet
 from .net import ForeignNet, Net
 
@@ -31,4 +33,5 @@ __all__ = [
     "AnomalyDetector", "unroll", "Seq2seq", "RNNEncoder", "RNNDecoder",
     "ImageClassifier", "ResNet", "ObjectDetector", "SSDLite", "Visualizer",
     "BERT", "BERTClassifier", "BERTNER", "BERTSQuAD", "Qwen3Next",
+    "AFMoE",
 ]

@@ -31,16 +31,29 @@ from .module import Module, Scope
 # 2 kv heads of 256, causal, one row of 8192 tokens, forward 14.8 ms with the
 # kernel against 453.1 ms dense, forward + backward 49.2 ms against 938.7 ms
 # dense + remat; at two rows the dense path's [2, 16, 8192, 8192] float32
-# logits do not fit the chip.  Nothing has been read near 2048 itself.
+# logits do not fit the chip.  At 32 query heads of 128 (kv heads repeated
+# to 32), causal, one row, the attention core alone (PR 31, one reading
+# each, forward / forward + backward, dense under jax.checkpoint): at 2048
+# tokens, the shortest length read, the kernel 1.57 / 3.66 ms against 4.27 /
+# 7.84 dense, and with a window of 1024 1.38 / 4.31 against 4.28 / 7.82; at
+# 4096 tokens 6.06 / 13.69 against 16.07 / 29.92, and with a window of 2048
+# 4.31 / 11.58 against 16.02 / 29.88; at 16,384 tokens 88.1 / 252.8 full and
+# 20.4 / 56.2 with a window of 2048 (dense does not fit).  So at these heads
+# the kernel already leads at 2048; BERT's 12 x 64 heads and batches of rows
+# have not been read there, and the constant stays.
 FLASH_AUTO_MIN_SEQ = 2048
 
 
-def causal_mask(tq: int, tk: Optional[int] = None) -> jax.Array:
+def causal_mask(tq: int, tk: Optional[int] = None,
+                window: Optional[int] = None) -> jax.Array:
     """[1, 1, Tq, Tk] lower-triangular attend-mask (shared by the dense path
     and ring_attention's no-seq-axis fallback); handles Tq != Tk
-    (cross-attention) by comparing absolute positions."""
+    (cross-attention) by comparing absolute positions.  With a ``window``
+    the band: position i sees the ``window`` keys ``i - window + 1 .. i``."""
     tk = tq if tk is None else tk
-    return (jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :])[None, None]
+    seen = jnp.arange(tq)[:, None] - jnp.arange(tk)[None, :]
+    mask = seen >= 0 if window is None else (seen >= 0) & (seen < window)
+    return mask[None, None]
 
 
 def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -84,12 +97,17 @@ class MultiHeadAttention(Module):
     the plain layer of the BERT family.  A decoder block of today's kind
     sets, in any combination: ``num_kv_heads`` (grouped-query attention:
     each key/value head serves ``num_heads / num_kv_heads`` query heads),
-    ``qk_norm`` (a zero-centred RMSNorm over the head dim on q and on k,
-    before the rotation), ``rotary_dim`` / ``rope_theta`` (rotary embedding
-    on the first ``rotary_dim`` dims of each head; self-attention from
-    position 0), ``gate`` (``wq`` is twice as wide, split a head into query
-    and gate; the context is multiplied by ``sigmoid(gate)`` before ``wo``).
-    All of them go through the one dense / flash / ring dispatch below."""
+    ``qk_norm`` (an RMSNorm over the head dim on q and on k, before the
+    rotation; zero-centred unless ``qk_norm_zero_centered=False``, which
+    stores the scale itself, initialised 1), ``rotary_dim`` / ``rope_theta``
+    (rotary embedding on the first ``rotary_dim`` dims of each head, 0 for
+    none; self-attention from position 0), ``gate`` (``wq`` is twice as
+    wide, split a head into query and gate; the context is multiplied by
+    ``sigmoid(gate)`` before ``wo``), ``window`` (sliding-window attention,
+    causal only: position i sees the ``window`` keys ``i - window + 1 ..
+    i``; the flash path visits the band's blocks alone, the dense path
+    builds the band mask).  All of them go through the one dense / flash /
+    ring dispatch below."""
 
     def __init__(self, num_heads: int, head_dim: Optional[int] = None,
                  dropout: float = 0.0,
@@ -99,8 +117,16 @@ class MultiHeadAttention(Module):
                  num_kv_heads: Optional[int] = None, qk_norm: bool = False,
                  rotary_dim: int = 0, rope_theta: float = 10000.0,
                  gate: bool = False, norm_epsilon: float = 1e-6,
+                 window: Optional[int] = None,
+                 qk_norm_zero_centered: bool = True,
                  name: Optional[str] = None):
         super().__init__(name)
+        if window is not None and (not causal or use_ring or window < 1):
+            raise ValueError("window is sliding-window causal attention on "
+                             "the dense or flash path: it needs causal=True, "
+                             f"no ring and window >= 1; got {window}")
+        self.window = window
+        self.qk_norm_zero_centered = qk_norm_zero_centered
         if num_kv_heads is not None and num_heads % num_kv_heads:
             raise ValueError(f"num_heads {num_heads} is not a multiple of "
                              f"num_kv_heads {num_kv_heads}")
@@ -173,7 +199,8 @@ class MultiHeadAttention(Module):
         k = proj("wk", kv, kv_h)
         v = proj("wv", kv, kv_h)
         if self.qk_norm:
-            norm = RMSNorm(self.norm_epsilon, zero_centered=True)
+            norm = RMSNorm(self.norm_epsilon,
+                           zero_centered=self.qk_norm_zero_centered)
             q = scope.child(norm, q, name="q_norm")
             k = scope.child(norm, k, name="k_norm")
         if self.rotary_dim:
@@ -192,12 +219,21 @@ class MultiHeadAttention(Module):
             ctx = ring_self_attention(q, k, v, causal=self.causal)
         elif use_flash and mask is None:
             from analytics_zoo_tpu.ops import flash_attention
-            ctx = flash_attention(q, k, v, causal=self.causal)
+            ctx = flash_attention(q, k, v, causal=self.causal,
+                                  window=self.window)
         else:
             # explicit mask: dense path (flash/ring kernels take no mask);
             # causal still applies — combine, never silently drop it
+            if self.window is not None and mask is not None \
+                    and kv.shape[1] >= FLASH_AUTO_MIN_SEQ:
+                # the band exists to spare the [T, T] maps: a mask that
+                # forces them at such a length is refused, not obeyed
+                raise ValueError(
+                    f"a window with an explicit mask takes the dense path; "
+                    f"at {kv.shape[1]} keys (>= {FLASH_AUTO_MIN_SEQ}) that "
+                    "is [T, T] maps the window was meant to spare")
             if self.causal:
-                cm = causal_mask(x.shape[1], kv.shape[1])
+                cm = causal_mask(x.shape[1], kv.shape[1], self.window)
                 mask = cm if mask is None else (mask.astype(bool) & cm)
             attn = (jax.checkpoint(dot_product_attention) if self.remat
                     else dot_product_attention)

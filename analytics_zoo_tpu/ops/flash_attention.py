@@ -12,6 +12,13 @@ gradients are computed by a blocked pure-JAX backward (rematerializes logits
 one k-block at a time under `lax.scan` — the standard flash-attention-2
 recomputation trade: extra FLOPs for O(T) memory).
 
+``window`` (causal only) is sliding-window attention: position i sees the
+``window`` keys ``i - window + 1 .. i``.  Every path then visits the blocks
+that intersect that band and no other, so the work is ``T * window`` and not
+``T^2``: the kernel's k axis covers the band's blocks of a query block (its
+own op name, ``flash_attention_window_fwd``), and the blocked forms slice the
+band out of k (forward) or out of q (backward) block by block.
+
 On platform ``tpu`` the forward is always the compiled kernel (or the
 compiler's error).  On other backends it is the same blocked pure-JAX math,
 or the kernel in Pallas interpret mode when a test sets ``INTERPRET``.
@@ -41,14 +48,19 @@ def _ceil_to(x: int, m: int) -> int:
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale: float, causal: bool,
-                block_q: int, block_k: int, seq_k: int):
+                block_q: int, block_k: int, seq_k: int,
+                window: Optional[int] = None):
     """Grid = (BH, Tq/bq, Tk/bk); k-block is the innermost (sequential) axis,
-    so VMEM scratch carries the online-softmax state across k blocks."""
+    so VMEM scratch carries the online-softmax state across k blocks.  With
+    a ``window`` the innermost axis walks the band's k blocks only (see
+    ``_band_block``): ``ki`` is then the k block this step was given."""
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = pl.program_id(2)
     nk = pl.num_programs(2)
+    ki = step if window is None else _band_block(qi, step, nk, block_q,
+                                                 block_k)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -67,6 +79,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             qpos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 0)
             mask = mask & (qpos >= kpos)
+            if window is not None:
+                mask = mask & (qpos - kpos < window)
         s = jnp.where(mask, s, _NEG_INF)
 
         m_prev = m_scr[...]                        # [bq, 1] broadcast lanes
@@ -81,7 +95,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_scr[...] = m_new
         l_scr[...] = l_new
 
-    if causal:
+    if window is not None:
+        # the band's first blocks of the first query blocks lie before the
+        # sequence (their index map clamps to block 0: nothing is fetched),
+        # and a block may end before the band's first key
+        @pl.when((ki >= 0) & (ki * block_k < seq_k)
+                 & (ki * block_k + block_k - 1 > qi * block_q - window))
+        def _run():
+            _body()
+    elif causal:
         # whole block strictly above the diagonal: nothing to do
         @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
         def _run():
@@ -89,30 +111,60 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     else:
         _body()
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == nk - 1)
     def _finish():
         denom = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
         lse_ref[0, 0] = (m_scr[...] + jnp.log(denom))[:, 0]
 
 
-def _flash_fwd_pallas(q, k, v, scale, causal, block_q, block_k, true_tk,
-                      interpret):
-    """q,k,v: [BH, T, D] (D padded to 128, T padded to block).  ``true_tk``
-    is the unpadded key length: padded key positions are masked out."""
-    bh, tq, d = q.shape
-    tk = k.shape[1]
-    grid = (bh, tq // block_q, tk // block_k)
+def _band_blocks(window: int, block_q: int, block_k: int) -> int:
+    """k blocks a query block's band can touch: the keys ``first row -
+    window + 1 .. last row``, ``block_q + window - 1`` of them, ending at a
+    block's last row when ``block_k`` divides ``block_q`` and anywhere
+    inside a block otherwise."""
+    span = block_q + window - 1
+    return -(-span // block_k) + (1 if block_q % block_k else 0)
+
+
+def _band_block(qi, step, steps: int, block_q: int, block_k: int):
+    """The k block of step ``step`` of query block ``qi``: the last step is
+    the block that holds the query block's last row (the diagonal), the
+    others the ones before it; negative before the sequence's start."""
+    return (qi * block_q + block_q - 1) // block_k - (steps - 1) + step
+
+
+@functools.lru_cache(maxsize=64)
+def _fwd_call(bh, tq, tk, d, dtype, scale, causal, block_q, block_k, true_tk,
+              interpret, window):
+    """The forward ``pallas_call`` over q, k, v of ``[BH, T, D]`` (D padded
+    to 128, T padded to block; ``true_tk`` is the unpadded key length:
+    padded key positions are masked out): built once for its sizes, so that
+    the layers of a model (and its ``init``, ``predict`` and train-step
+    programs) trace the kernel once between them."""
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
-                               seq_k=true_tk)
-    out, lse = pl.pallas_call(
+                               seq_k=true_tk, window=window)
+    if window is None:
+        steps = tk // block_k
+
+        def k_block(b, i, j):
+            return (b, j, 0)
+    else:
+        # the innermost axis is the band's blocks, not the row's: a block
+        # outside the band is neither fetched nor computed
+        steps = min(_band_blocks(window, block_q, block_k), tk // block_k)
+
+        def k_block(b, i, j):
+            return (b, jnp.clip(_band_block(i, j, steps, block_q, block_k),
+                                0, tk // block_k - 1), 0)
+    return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bh, tq // block_q, steps),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), k_block),
+            pl.BlockSpec((1, block_k, d), k_block),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -121,7 +173,7 @@ def _flash_fwd_pallas(q, k, v, scale, causal, block_q, block_k, true_tk,
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, tq, d), dtype),
             jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32),
         ],
         scratch_shapes=[
@@ -130,9 +182,11 @@ def _flash_fwd_pallas(q, k, v, scale, causal, block_q, block_k, true_tk,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_attention_fwd",  # the op's name in HLO and in a profile
-    )(q, k, v)
-    return out, lse
+        # the op's name in HLO and in a profile: a trace tells a windowed
+        # layer's kernel from a full one's
+        name=("flash_attention_fwd" if window is None
+              else "flash_attention_window_fwd"),
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -259,47 +313,148 @@ def _blocked_bwd_jax(q, k, v, out, lse, g, scale, causal, block_k):
 
 
 # ---------------------------------------------------------------------------
+# The same two passes over a band (sliding-window attention)
+# ---------------------------------------------------------------------------
+
+def _band_fwd_jax(q, k, v, scale, window, block):
+    """Windowed causal forward, a query block at a time: the block's band
+    (``block + window - 1`` keys ending at its last row) is sliced out of k
+    and v and takes one plain softmax.  Work ``T * (block + window)``."""
+    bh, t, d = q.shape
+    nq = -(-t // block)
+    span = block + window - 1
+    qb = jnp.pad(q, ((0, 0), (0, nq * block - t), (0, 0))).reshape(
+        bh, nq, block, d).swapaxes(0, 1)
+    # key position p sits at index p + window - 1 of the padded k
+    kp = jnp.pad(k, ((0, 0), (window - 1, nq * block - t), (0, 0)))
+    vp = jnp.pad(v, ((0, 0), (window - 1, nq * block - t), (0, 0)))
+
+    def rows(_, blk):
+        qi, i = blk
+        kj = jax.lax.dynamic_slice_in_dim(kp, i * block, span, axis=1)
+        vj = jax.lax.dynamic_slice_in_dim(vp, i * block, span, axis=1)
+        s = jnp.einsum("bqd,bkd->bqk", qi.astype(jnp.float32),
+                       kj.astype(jnp.float32),
+                       preferred_element_type=jnp.float32) * scale
+        qpos = i * block + jnp.arange(block)[:, None]
+        kpos = i * block - (window - 1) + jnp.arange(span)[None, :]
+        mask = (kpos >= 0) & (kpos < t) & (kpos <= qpos) \
+            & (qpos - kpos < window)
+        s = jnp.where(mask, s, _NEG_INF)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+        out = jnp.einsum("bqk,bkd->bqd", p, vj.astype(jnp.float32)) / l
+        return None, (out.astype(q.dtype), (m + jnp.log(l))[..., 0])
+
+    _, (out, lse) = jax.lax.scan(rows, None, (qb, jnp.arange(nq)))
+    out = out.swapaxes(0, 1).reshape(bh, nq * block, d)[:, :t]
+    lse = lse.swapaxes(0, 1).reshape(bh, nq * block)[:, :t]
+    return out, lse
+
+
+def _band_bwd_jax(q, k, v, out, lse, g, scale, window, block):
+    """Windowed causal backward, a k block at a time: the queries that see
+    the block (``block + window - 1`` rows from its first key on) are
+    sliced out, p is rematerialized for them, dk and dv of the block come
+    out whole and dq is added into its rows.  Work ``T * (block + window)``
+    where the plain causal walk does ``T^2 / 2``."""
+    bh, t, d = q.shape
+    nk = -(-t // block)
+    span = block + window - 1
+    rows = nk * block + window - 1        # every slice lies inside the pad
+    of, gf = out.astype(jnp.float32), g.astype(jnp.float32)
+    delta = jnp.sum(of * gf, axis=-1, keepdims=True)
+
+    def pad(a):
+        return jnp.pad(a, ((0, 0), (0, rows - t)) + ((0, 0),) * (a.ndim - 2))
+    qf, gf, lse_p, delta = (pad(a) for a in (
+        q.astype(jnp.float32), gf, lse, delta))
+    kb = jnp.pad(k, ((0, 0), (0, nk * block - t), (0, 0))).reshape(
+        bh, nk, block, d).swapaxes(0, 1)
+    vb = jnp.pad(v, ((0, 0), (0, nk * block - t), (0, 0))).reshape(
+        bh, nk, block, d).swapaxes(0, 1)
+
+    def step(dq, blk):
+        kj, vj, j = blk
+        kjf, vjf = kj.astype(jnp.float32), vj.astype(jnp.float32)
+        q_, g_, lse_, delta_ = (
+            jax.lax.dynamic_slice_in_dim(a, j * block, span, axis=1)
+            for a in (qf, gf, lse_p, delta))
+        s = jnp.einsum("bqd,bkd->bqk", q_, kjf,
+                       preferred_element_type=jnp.float32) * scale
+        qpos = j * block + jnp.arange(span)[:, None]
+        kpos = j * block + jnp.arange(block)[None, :]
+        mask = (qpos < t) & (kpos < t) & (kpos <= qpos) \
+            & (qpos - kpos < window)
+        p = jnp.where(mask, jnp.exp(s - lse_[..., None]), 0.0)
+        dp = jnp.einsum("bqd,bkd->bqk", g_, vjf)
+        ds = p * (dp - delta_) * scale
+        dq_rows = jax.lax.dynamic_slice_in_dim(dq, j * block, span, axis=1)
+        dq = jax.lax.dynamic_update_slice_in_dim(
+            dq, dq_rows + jnp.einsum("bqk,bkd->bqd", ds, kjf), j * block,
+            axis=1)
+        return dq, (jnp.einsum("bqk,bqd->bkd", ds, q_),
+                    jnp.einsum("bqk,bqd->bkd", p, g_))
+
+    dq, (dk, dv) = jax.lax.scan(
+        step, jnp.zeros((bh, rows, d), jnp.float32),
+        (kb, vb, jnp.arange(nk)))
+    dk = dk.swapaxes(0, 1).reshape(bh, nk * block, d)[:, :t]
+    dv = dv.swapaxes(0, 1).reshape(bh, nk * block, d)[:, :t]
+    return dq[:, :t].astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+# ---------------------------------------------------------------------------
 # Public API
 # ---------------------------------------------------------------------------
 
-def mha_reference(q, k, v, causal: bool = False) -> jax.Array:
-    """Materialized-logits reference ([B, T, H, D]) for differential tests."""
+def mha_reference(q, k, v, causal: bool = False,
+                  window: Optional[int] = None) -> jax.Array:
+    """Materialized-logits reference ([B, T, H, D]) for differential tests;
+    ``window`` as in :func:`flash_attention`."""
     d = q.shape[-1]
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32) / jnp.sqrt(
         jnp.asarray(d, jnp.float32))
     if causal:
         tq, tk = s.shape[-2:]
-        mask = jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :]
+        seen = jnp.arange(tq)[:, None] - jnp.arange(tk)[None, :]
+        mask = seen >= 0 if window is None else (seen >= 0) & (seen < window)
         s = jnp.where(mask, s, _NEG_INF)
     w = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", w, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q3, k3, v3, causal, block_q, block_k):
-    out, _ = _flash_fwd_dispatch(q3, k3, v3, causal, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q3, k3, v3, causal, block_q, block_k, window=None):
+    out, _ = _flash_fwd_dispatch(q3, k3, v3, causal, block_q, block_k,
+                                 window)
     return out
 
 
 INTERPRET = False  # tests set True to exercise the Pallas kernel on CPU
 
 
-def _flash_fwd_dispatch(q3, k3, v3, causal, block_q, block_k):
+def _flash_fwd_dispatch(q3, k3, v3, causal, block_q, block_k, window=None):
     scale = 1.0 / (q3.shape[-1] ** 0.5)
     if jax.default_backend() == "tpu":
         # the compiled kernel or the compiler's error: no interpret mode,
         # no pure-JAX stand-in on the device the kernel was written for
         return _padded_pallas(q3, k3, v3, scale, causal, block_q, block_k,
-                              interpret=False)
+                              interpret=False, window=window)
     if INTERPRET:
         return _padded_pallas(q3, k3, v3, scale, causal, block_q, block_k,
-                              interpret=True)
+                              interpret=True, window=window)
+    if window is not None:
+        return _band_fwd_jax(q3, k3, v3, scale, window,
+                             min(block_k, k3.shape[1]))
     return _blocked_fwd_jax(q3, k3, v3, scale, causal,
                             min(block_k, k3.shape[1]))
 
 
-def _padded_pallas(q3, k3, v3, scale, causal, block_q, block_k, interpret):
+def _padded_pallas(q3, k3, v3, scale, causal, block_q, block_k, interpret,
+                   window=None):
     """Pad T to block multiples and D to the 128-lane tile, run the kernel."""
     bh, tq, d = q3.shape
     tk = k3.shape[1]
@@ -309,13 +464,14 @@ def _padded_pallas(q3, k3, v3, scale, causal, block_q, block_k, interpret):
     qp = jnp.pad(q3, ((0, 0), (0, tq_p - tq), (0, d_p - d)))
     kp = jnp.pad(k3, ((0, 0), (0, tk_p - tk), (0, d_p - d)))
     vp = jnp.pad(v3, ((0, 0), (0, tk_p - tk), (0, d_p - d)))
-    out, lse = _flash_fwd_pallas(qp, kp, vp, scale, causal, bq, bk,
-                                 true_tk=tk, interpret=interpret)
+    out, lse = _fwd_call(bh, tq_p, tk_p, d_p, jnp.dtype(q3.dtype), scale,
+                         causal, bq, bk, tk, interpret, window)(qp, kp, vp)
     return out[:, :tq, :d], lse[:, 0, :tq]
 
 
-def _flash_vjp_fwd(q3, k3, v3, causal, block_q, block_k):
-    out, lse = _flash_fwd_dispatch(q3, k3, v3, causal, block_q, block_k)
+def _flash_vjp_fwd(q3, k3, v3, causal, block_q, block_k, window=None):
+    out, lse = _flash_fwd_dispatch(q3, k3, v3, causal, block_q, block_k,
+                                   window)
     # named, so that an enclosing jax.checkpoint can be told to keep them
     # (policy save_only_these_names): [BH, T, D] + [BH, T] kept spare the
     # recomputation a second run of the whole kernel
@@ -324,9 +480,12 @@ def _flash_vjp_fwd(q3, k3, v3, causal, block_q, block_k):
     return out, (q3, k3, v3, out, lse)
 
 
-def _flash_vjp_bwd(causal, block_q, block_k, res, g):
+def _flash_vjp_bwd(causal, block_q, block_k, window, res, g):
     q3, k3, v3, out, lse = res
     scale = 1.0 / (q3.shape[-1] ** 0.5)
+    if window is not None:
+        return _band_bwd_jax(q3, k3, v3, out, lse, g, scale, window,
+                             min(block_k, k3.shape[1]))
     return _blocked_bwd_jax(q3, k3, v3, out, lse, g, scale, causal,
                             min(block_k, k3.shape[1]))
 
@@ -336,16 +495,27 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False, block_q: int = 256,
-                    block_k: int = 256) -> jax.Array:
+                    block_k: int = 256,
+                    window: Optional[int] = None) -> jax.Array:
     """Flash attention over [B, T, H, D] tensors (softmax scale 1/sqrt(D)).
 
     Differentiable; O(T·D) memory.  Matches :func:`mha_reference` to fp
-    tolerance (see tests/test_ops.py).
+    tolerance (see tests/test_ops.py).  ``window`` (causal self-attention
+    only): position i sees keys ``i - window + 1 .. i``; forward and
+    backward then visit the band's blocks alone.  A window that covers the
+    row is plain causal attention and takes its path.
     """
     b, tq, h, d = q.shape
     tk = k.shape[1]
+    if window is not None:
+        if not causal or tq != tk or window < 1:
+            raise ValueError("window needs causal self-attention and a "
+                             f"window >= 1; got causal={causal}, Tq={tq}, "
+                             f"Tk={tk}, window={window}")
+        if window >= tk:
+            window = None
     q3 = q.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
     k3 = k.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
     v3 = v.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
-    out = _flash(q3, k3, v3, causal, block_q, block_k)
+    out = _flash(q3, k3, v3, causal, block_q, block_k, window)
     return out.reshape(b, h, tq, d).transpose(0, 2, 1, 3)

@@ -1826,9 +1826,13 @@ def _publish_counters(reg: Any, now: Dict[str, Any],
     layers of one kind share a series).  A scalar is a counter.  A vector
     counts per slot (rows per expert); the registry has no per-slot series,
     so its growth's imbalance, largest slot over the mean, is observed in a
-    histogram, once a layer and read."""
+    histogram, once a layer and read.  A floating-point leaf is a level and
+    not a count (a router's largest bias): observed as it stands."""
     for path, total in now.items():
         series = path.rsplit("/", 1)[1]
+        if np.issubdtype(np.asarray(total).dtype, np.floating):
+            reg.histogram(series).observe(float(total))
+            continue
         before = (seen or {}).get(path, 0)
         grew = (np.asarray(total, np.int64) - before) % (1 << 32)
         if grew.ndim == 0:

@@ -120,6 +120,11 @@ class MoE(Module):
 COUNTER_KEYS = ("pairs_total", "pairs_local", "pairs_dropped",
                 "load_max_over_mean")
 
+#: a level, not a count, kept beside the counters by a layer that balances
+#: its router by a bias (``balance_coeff``): the largest |bias| of the
+#: layer, published as the histogram ``moe.<key>`` at the same read-back
+LEVEL_KEYS = ("expert_bias_abs_max",)
+
 #: rows of one window of DroplessMoE's buffer, over the rows a balanced
 #: router sends to the held experts: a balanced step fits one window
 FAST_BUFFER = 2.0
@@ -202,21 +207,40 @@ def _top_k(x: jax.Array, k: int):
 
 
 class _Router(Module):
-    """``softmax(x W)`` over every expert, matmul and softmax in float32
-    at full precision (on a TPU a float32 product takes bf16 passes unless
-    told otherwise, and the top-k is decided by the last bits)."""
+    """``softmax(x W)`` (or ``sigmoid(x W)``: ``score_func``) over every
+    expert, matmul and scores in float32 at full precision (on a TPU a
+    float32 product takes bf16 passes unless told otherwise, and the top-k
+    is decided by the last bits)."""
 
-    def __init__(self, num_experts: int, kernel_init: Any):
+    def __init__(self, num_experts: int, kernel_init: Any,
+                 score_func: str = "softmax"):
         super().__init__("router")
+        if score_func not in ("softmax", "sigmoid"):
+            raise ValueError("score_func must be 'softmax' or 'sigmoid'; "
+                             f"got {score_func!r}")
         self.num_experts = num_experts
         self.kernel_init = initializers.get(kernel_init)
+        self.score_func = score_func
 
     def forward(self, scope: Scope, x: jax.Array) -> jax.Array:
         w = scope.param("kernel", self.kernel_init,
                         (x.shape[-1], self.num_experts))
         logits = jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
+        if self.score_func == "sigmoid":
+            return jax.nn.sigmoid(logits)
         return jax.nn.softmax(logits, axis=-1)
+
+
+def balance_bias(bias: jax.Array, picks: jax.Array,
+                 coeff: float) -> jax.Array:
+    """One step of balancing without an auxiliary loss (arXiv:2408.15664):
+    from the step's picks of each expert, ``d = coeff * sign(mean(picks) -
+    picks)`` raises the bias of the experts picked less than the mean and
+    lowers the others', and ``bias + d - mean(d)`` keeps the bias centred."""
+    c = picks.astype(jnp.float32)
+    d = coeff * jnp.sign(c.mean() - c)
+    return bias + d - d.mean()
 
 
 class DroplessMoE(Module):
@@ -230,8 +254,20 @@ class DroplessMoE(Module):
     out, which is one chip's part of an expert-parallel layer before the
     exchange (held = num_experts is the whole layer).  Each expert is a
     SwiGLU of width ``expert_units``.  ``shared_units > 0`` adds a shared
-    expert every token passes, behind a sigmoid gate; it is computed whole
-    on every share.
+    expert every token passes, behind a sigmoid gate unless
+    ``shared_gate=False``; it is computed whole on every share.
+
+    The router's scores are a softmax over the experts or, with
+    ``score_func="sigmoid"``, a sigmoid of each; the kept scores are
+    renormalised to ``w / (sum(w) + norm_epsilon)`` and multiplied by
+    ``route_scale``.  ``balance_coeff`` balances the router by a bias and
+    not by a loss: the top-k is taken of ``score + expert_bias``, a state
+    variable over all experts (zeros at first, no gradient) that every
+    training forward moves by :func:`balance_bias` from the step's picks
+    and inference leaves alone; the kept weights are the scores without
+    it.  Such a layer publishes no ``aux_loss``.  A share sees its own
+    tokens only; a deployment sums the picks over its data-parallel chips
+    before the sign.
 
     No pick of a held expert is ever dropped.  The (token, pick) pairs are
     sorted by expert, the held ones first, and that order is walked in
@@ -253,13 +289,18 @@ class DroplessMoE(Module):
     counters the Estimator reads once an epoch (``COUNTER_KEYS``,
     docs/observability.md): ``moe.pairs_total``, ``moe.pairs_local``,
     ``moe.pairs_dropped`` (picks of held experts that found no row in the
-    buffer: stays 0) and ``moe.load_max_over_mean`` (rows per held expert).
+    buffer: stays 0) and ``moe.load_max_over_mean`` (rows per held expert),
+    and under ``balance_coeff`` the level ``moe.expert_bias_abs_max``;
+    ``expert_bias`` — the selection bias, under ``balance_coeff`` only.
     """
 
     def __init__(self, num_experts: int, top_k: int, expert_units: int,
                  experts_held: Optional[int] = None, first_expert: int = 0,
                  shared_units: int = 0, norm_topk_prob: bool = True,
                  kernel_init: Any = "glorot_uniform",
+                 score_func: str = "softmax", route_scale: float = 1.0,
+                 norm_epsilon: float = 0.0, shared_gate: bool = True,
+                 balance_coeff: Optional[float] = None,
                  name: Optional[str] = None):
         super().__init__(name or "moe")
         held = num_experts if experts_held is None else experts_held
@@ -273,6 +314,9 @@ class DroplessMoE(Module):
         self.shared_units = shared_units
         self.norm_topk_prob = norm_topk_prob
         self.kernel_init = kernel_init
+        self.score_func, self.route_scale = score_func, route_scale
+        self.norm_epsilon, self.shared_gate = norm_epsilon, shared_gate
+        self.balance_coeff = balance_coeff
 
     def forward(self, scope: Scope, x: jax.Array) -> jax.Array:
         b, t, d = x.shape
@@ -281,10 +325,21 @@ class DroplessMoE(Module):
         init = initializers.get(self.kernel_init)
         xs = x.reshape(s, d)
 
-        probs = scope.child(_Router(e, self.kernel_init), xs, name="router")
-        top_w, top_e = _top_k(probs, k)                          # [S, K]
+        probs = scope.child(_Router(e, self.kernel_init, self.score_func),
+                            xs, name="router")
+        if self.balance_coeff is None:
+            top_w, top_e = _top_k(probs, k)                      # [S, K]
+        else:  # picked with the bias, weighted without it
+            bias = scope.variable("expert_bias",
+                                  lambda: jnp.zeros((e,), jnp.float32))
+            _, top_e = _top_k(probs + bias, k)
+            top_w = jnp.take_along_axis(probs, top_e, axis=-1)
         if self.norm_topk_prob:
-            top_w = top_w / top_w.sum(axis=-1, keepdims=True)
+            total = top_w.sum(axis=-1, keepdims=True)
+            top_w = top_w / (total + self.norm_epsilon
+                             if self.norm_epsilon else total)
+        if self.route_scale != 1.0:
+            top_w = top_w * self.route_scale
 
         # sort the pairs by expert, held experts first (absent ones share
         # the key ``held``): the first n_local rows of that order are ours
@@ -310,18 +365,29 @@ class DroplessMoE(Module):
             shared = scope.child(SwiGLU(self.shared_units,
                                         kernel_init=self.kernel_init),
                                  xs, name="shared_expert")
-            g = scope.child(Dense(1, use_bias=False,
-                                  kernel_init=self.kernel_init),
-                            xs, name="shared_gate")
-            out = out + jax.nn.sigmoid(g.astype(jnp.float32)) * shared
+            if self.shared_gate:
+                g = scope.child(Dense(1, use_bias=False,
+                                      kernel_init=self.kernel_init),
+                                xs, name="shared_gate")
+                shared = jax.nn.sigmoid(g.astype(jnp.float32)) * shared
+            out = out + shared
 
-        scope.variable("aux_loss", lambda: jnp.zeros((), jnp.float32))
-        scope.put_variable("aux_loss", e * jnp.sum(
-            picks.astype(jnp.float32) / s * probs.mean(axis=0)))
+        levels = {}
+        if self.balance_coeff is None:
+            scope.variable("aux_loss", lambda: jnp.zeros((), jnp.float32))
+            scope.put_variable("aux_loss", e * jnp.sum(
+                picks.astype(jnp.float32) / s * probs.mean(axis=0)))
+        else:
+            if scope.training:
+                bias = balance_bias(bias, picks, self.balance_coeff)
+                scope.put_variable("expert_bias", bias)
+            levels = {"moe." + LEVEL_KEYS[0]: jnp.abs(bias).max()}
         grew = dict(zip(COUNTER_KEYS, (s * k, n_local, n_local - placed, load)))
         seen = scope.variable("counters", lambda: {
-            "moe." + key: jnp.zeros_like(v, jnp.int32)
-            for key, v in grew.items()})
+            **{"moe." + key: jnp.zeros_like(v, jnp.int32)
+               for key, v in grew.items()},
+            **{key: jnp.zeros((), jnp.float32) for key in levels}})
         scope.put_variable("counters", {
-            "moe." + key: seen["moe." + key] + v for key, v in grew.items()})
+            **{"moe." + key: seen["moe." + key] + v
+               for key, v in grew.items()}, **levels})
         return out.reshape(b, t, d).astype(x.dtype)

@@ -16,7 +16,7 @@ way, so span naming can't drift undocumented either:
   registration sites (``"client." + key`` over the client's stats dict,
   ``"server." + k`` over the server's counters dict, ``"frontend." +
   key`` over ``_FRONTEND_COUNTERS``, ``"moe." + key`` over the expert
-  layer's ``COUNTER_KEYS``) whose key sets are extracted from the same
+  layer's ``COUNTER_KEYS`` and ``LEVEL_KEYS``) whose key sets are extracted from the same
   files;
 - **spans, code side**: every span name recorded through ``core/trace.py``
   — the second argument of ``trace.record(...)`` / ``trace_lib.record``
@@ -69,6 +69,9 @@ _DYNAMIC = [
     # Estimator at the epoch's read-back under "moe." + key
     ("parallel/moe.py", "moe.",
      re.compile(r"COUNTER_KEYS = \(([^)]*)\)", re.S)),
+    # ... and the levels kept beside them (a bias-balanced router)
+    ("parallel/moe.py", "moe.",
+     re.compile(r"LEVEL_KEYS = \(([^)]*)\)", re.S)),
 ]
 
 _KEY = re.compile(r'"([a-z0-9_]+)"')

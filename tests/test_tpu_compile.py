@@ -105,6 +105,36 @@ def test_flash_backward_compiles(chip):
         q, k, v, o, l, g, 0.125, True, 256), x, x, x, x, lse, x)
 
 
+# a sliding layer of the Trinity-Mini cell: one row of 16,384 tokens, 32
+# heads of 128, a window of 2048; 200 with a window of 50 is the unaligned
+# case (row and window no multiple of the block)
+@pytest.mark.parametrize("t,window", [(16384, 2048), (200, 50)])
+def test_windowed_flash_forward_kernel_compiles(chip, t, window):
+    qkv = _on(chip, jax.ShapeDtypeStruct((32, t, 128), jnp.bfloat16))
+    compiled = _compile(
+        lambda q, k, v: fa._padded_pallas(q, k, v, 128 ** -0.5, True, 256,
+                                          256, interpret=False,
+                                          window=window),
+        qkv, qkv, qkv)
+    assert re.findall(r"%(flash_attention_window_fwd[.\d]*) = ",
+                      compiled.as_text())
+
+
+def test_windowed_flash_backward_compiles_in_the_bands_memory(chip):
+    """... and its temporaries are the band's (a k block against the 2303
+    queries that see it), a third of what the causal walk of the same row
+    takes."""
+    t = 16384
+    x = _on(chip, jax.ShapeDtypeStruct((32, t, 128), jnp.bfloat16))
+    lse = _on(chip, jax.ShapeDtypeStruct((32, t), jnp.float32))
+    band = _compile(lambda q, k, v, o, l, g: fa._band_bwd_jax(
+        q, k, v, o, l, g, 128 ** -0.5, 2048, 256), x, x, x, x, lse, x)
+    whole = _compile(lambda q, k, v, o, l, g: fa._blocked_bwd_jax(
+        q, k, v, o, l, g, 128 ** -0.5, True, 256), x, x, x, x, lse, x)
+    assert band.memory_analysis().temp_size_in_bytes \
+        < 0.5 * whole.memory_analysis().temp_size_in_bytes
+
+
 # one DeltaNet layer of the Qwen3-Next cell: two rows of 8192 tokens, 16 key
 # and 32 value heads of 128, chunks of 64 in tiles of 128 rows
 @pytest.mark.parametrize("which", ["forward", "forward_for_a_gradient",
@@ -294,8 +324,9 @@ def test_qwen3next_cell_train_step_compiles_for_one_chip(topo, monkeypatch):
     # default_backend() is "cpu" here: take the branch the chip takes
     monkeypatch.setattr(
         fa, "_flash_fwd_dispatch",
-        lambda q, k, v, causal, bq, bk: fa._padded_pallas(
-            q, k, v, q.shape[-1] ** -0.5, causal, bq, bk, interpret=False))
+        lambda q, k, v, causal, bq, bk, window=None: fa._padded_pallas(
+            q, k, v, q.shape[-1] ** -0.5, causal, bq, bk, interpret=False,
+            window=window))
     monkeypatch.setattr(gdr, "dispatch", lambda dk, dv, chunk: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(
@@ -319,3 +350,41 @@ def test_qwen3next_cell_train_step_compiles_for_one_chip(topo, monkeypatch):
     held = sum(int(np.prod(l.shape)) for l in
                jax.tree_util.tree_leaves(est._ts["params"]))
     assert held == 625_667_136
+
+
+def test_trinity_mini_cell_train_step_compiles_for_one_chip(topo,
+                                                            monkeypatch):
+    """``trinity_mini_ep8_fit_s16384``'s train step at its real sizes
+    (705.5 M parameters with AdamW's moments, one row of 16,384 tokens):
+    the chip's compiler takes it inside the chip's memory, with the
+    windowed kernel once a sliding layer and the plain one once for the
+    full layer (the blocks' recomputation keeps what the backward passes
+    read), and the expert layers' grouped matmuls as ragged-dot kernels."""
+    import json
+    from analytics_zoo_tpu.orca.learn import Estimator
+    from benchmark.families import afmoe
+    monkeypatch.setattr(
+        fa, "_flash_fwd_dispatch",
+        lambda q, k, v, causal, bq, bk, window=None: fa._padded_pallas(
+            q, k, v, q.shape[-1] ** -0.5, causal, bq, bk, interpret=False,
+            window=window))
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark/configs/trinity_mini_ep8.json")) as f:
+        config = json.load(f)
+    est = Estimator.from_keras(
+        afmoe.build(config), loss=config["loss"],
+        optimizer=config["optimizer"]["name"],
+        learning_rate=config["optimizer"]["learning_rate"])
+    ids = jax.ShapeDtypeStruct((1, 16384), jnp.int32)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    compiled = _abstract_train_step(est, mesh, ids, ids).compile()
+    assert _per_chip_bytes(compiled) < HBM_BYTES
+    text = compiled.as_text()
+    window = re.findall(r"%(flash_attention_window_fwd[.\d]*) = ", text)
+    full = re.findall(r"%(flash_attention_fwd[.\d]*) = ", text)
+    assert (len(window), len(full)) == (4, 1), (window, full)
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) >= 6 * 4
+    held = sum(int(np.prod(l.shape)) for l in
+               jax.tree_util.tree_leaves(est._ts["params"]))
+    assert held == 705_473_792
