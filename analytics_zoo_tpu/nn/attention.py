@@ -32,15 +32,18 @@ from .module import Module, Scope
 # kernel against 453.1 ms dense, forward + backward 49.2 ms against 938.7 ms
 # dense + remat; at two rows the dense path's [2, 16, 8192, 8192] float32
 # logits do not fit the chip.  At 32 query heads of 128 (kv heads repeated
-# to 32), causal, one row, the attention core alone (PR 31, one reading
-# each, forward / forward + backward, dense under jax.checkpoint): at 2048
-# tokens, the shortest length read, the kernel 1.57 / 3.66 ms against 4.27 /
-# 7.84 dense, and with a window of 1024 1.38 / 4.31 against 4.28 / 7.82; at
-# 4096 tokens 6.06 / 13.69 against 16.07 / 29.92, and with a window of 2048
-# 4.31 / 11.58 against 16.02 / 29.88; at 16,384 tokens 88.1 / 252.8 full and
-# 20.4 / 56.2 with a window of 2048 (dense does not fit).  So at these heads
-# the kernel already leads at 2048; BERT's 12 x 64 heads and batches of rows
-# have not been read there, and the constant stays.
+# to 32), causal, one row, the attention core alone (PR 32, with the backward
+# a Pallas kernel too; one reading each, forward / forward + backward, dense
+# under jax.checkpoint; PR 31's readings with the blocked jax.numpy backward
+# in brackets): at 2048 tokens, the shortest length read, the kernels 1.59 /
+# 2.44 ms (1.57 / 3.66) against 4.26 / 7.82 dense, and with a window of 1024
+# 1.39 / 2.14 (1.38 / 4.31) against 4.23 / 7.84; at 4096 tokens 6.03 / 8.71
+# (6.06 / 13.69) against 16.04 / 29.93, and with a window of 2048 4.32 / 6.91
+# (4.31 / 11.58) against 16.04 / 29.93; at 16,384 tokens 88.1 / 119.6
+# (88.1 / 252.8) full and 20.5 / 32.1 (20.4 / 56.2) with a window of 2048
+# (dense does not fit).  So at these heads the kernels lead from 2048 on, by
+# 3.2-4.3x forward + backward; BERT's 12 x 64 heads and batches of rows have
+# not been read there, and the constant stays.
 FLASH_AUTO_MIN_SEQ = 2048
 
 

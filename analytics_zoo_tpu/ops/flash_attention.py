@@ -7,21 +7,34 @@ materializes the [Tq, Tk] logits matrix in HBM — running max/sum ("online
 softmax") accumulate per q-block while k/v blocks stream through VMEM, so
 memory is O(T·D) and the two matmuls per block tile onto the MXU.
 
-Backward pass: `jax.custom_vjp` whose residuals are just (q, k, v, out, lse);
-gradients are computed by a blocked pure-JAX backward (rematerializes logits
-one k-block at a time under `lax.scan` — the standard flash-attention-2
-recomputation trade: extra FLOPs for O(T) memory).
+Backward pass: `jax.custom_vjp` whose residuals are just (q, k, v, out, lse).
+One more kernel (``flash_attention_bwd``; ``flash_attention_window_bwd`` on
+a band) recomputes a tile's probabilities from q, k and lse — the standard
+flash-attention-2 trade, extra FLOPs for O(T) memory — and walks the (key
+block, query block) tiles that hold a visible pair, key block outermost:
+the triangle when causal, the band with a ``window``, the tile list read by
+the index maps (``_bwd_tiles``), so a tile outside is neither fetched nor
+computed and only the tiles an edge crosses build a mask.  Scores,
+probabilities, dp and ds of a tile exist only in VMEM; dk and dv of the key
+block and dq of the whole head accumulate in float32 scratch and reach HBM
+once.  Five matmuls a tile, on operands in the inputs' dtype (p and ds cast
+to it, as XLA:TPU's default precision rounds the operands of the
+``jax.numpy`` form's float32 einsums to bf16); s, exp, lse, delta and the
+accumulators are float32.
 
 ``window`` (causal only) is sliding-window attention: position i sees the
 ``window`` keys ``i - window + 1 .. i``.  Every path then visits the blocks
 that intersect that band and no other, so the work is ``T * window`` and not
-``T^2``: the kernel's k axis covers the band's blocks of a query block (its
-own op name, ``flash_attention_window_fwd``), and the blocked forms slice the
+``T^2``: the forward kernel's k axis covers the band's blocks of a query
+block (its own op name, ``flash_attention_window_fwd``), the backward
+kernel's tile list holds the band's tiles, and the blocked forms slice the
 band out of k (forward) or out of q (backward) block by block.
 
-On platform ``tpu`` the forward is always the compiled kernel (or the
-compiler's error).  On other backends it is the same blocked pure-JAX math,
-or the kernel in Pallas interpret mode when a test sets ``INTERPRET``.
+On platform ``tpu`` forward and backward are always the compiled kernels (or
+the compiler's error).  On other backends they are the same math blocked in
+``jax.numpy`` (``_blocked_*_jax``, ``_band_*_jax``: a ``lax.scan`` over k
+blocks), or the kernels in Pallas interpret mode when a test sets
+``INTERPRET``.
 """
 
 from __future__ import annotations
@@ -31,6 +44,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -190,7 +204,213 @@ def _fwd_call(bh, tq, tk, d, dtype, scale, causal, block_q, block_k, true_tk,
 
 
 # ---------------------------------------------------------------------------
-# Blocked pure-JAX math (fallback forward + the backward pass)
+# Pallas backward kernel
+# ---------------------------------------------------------------------------
+
+# what a tile of the backward's walk has to do besides its products
+_FIRST_OF_K, _LAST_OF_K, _MASKED = 1, 2, 4
+
+
+def _bwd_tiles(tq, tk, block_q, block_k, true_tk, causal, window):
+    """The (key block, query block) tiles the backward visits, key block
+    outermost, as three int32 arrays the kernel's index maps read: key
+    block, query block, flags.  Causal: the tiles with a visible pair (the
+    triangle); with a ``window`` the band's alone.  ``_MASKED`` is set only
+    where a tile holds a hidden pair too: the diagonal crosses it, the
+    band's far edge does, or it holds padded keys.  A key block no query
+    sees (causal, ``Tk > Tq``) keeps one tile, fully masked, to write its
+    zeros."""
+    kjs, qis, flags = [], [], []
+    for j in range(tk // block_k):
+        k0, k1 = j * block_k, j * block_k + block_k - 1
+        seen = []
+        for i in range(tq // block_q):
+            q0, q1 = i * block_q, i * block_q + block_q - 1
+            if causal and q1 < k0:
+                continue
+            if window is not None and q0 - k1 >= window:
+                continue
+            masked = (k1 >= true_tk or (causal and q0 < k1)
+                      or (window is not None and q1 - k0 >= window))
+            seen.append((i, _MASKED if masked else 0))
+        seen = seen or [(tq // block_q - 1, _MASKED)]
+        for n, (i, flag) in enumerate(seen):
+            kjs.append(j)
+            qis.append(i)
+            flags.append(flag | (_FIRST_OF_K if n == 0 else 0)
+                         | (_LAST_OF_K if n == len(seen) - 1 else 0))
+    return tuple(np.asarray(a, np.int32) for a in (kjs, qis, flags))
+
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
+
+
+def _bwd_kernel(kj_ref, qi_ref, flag_ref, q_ref, k_ref, v_ref, g_ref,
+                lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+                dq_scr, dk_scr, dv_scr, *, scale: float, causal: bool,
+                block_q: int, block_k: int, seq_k: int,
+                window: Optional[int]):
+    """Grid = (BH, tiles): a head's tiles in the order of ``_bwd_tiles``.
+    Scores are held transposed, ``[block_k, block_q]``: lse and delta are
+    then rows (lanes), and dv and dk are plain products.  dk and dv of the
+    key block and dq of the WHOLE head accumulate in float32 VMEM scratch;
+    ``scale`` is applied once, where they are written."""
+    t = pl.program_id(1)
+    kj, qi, flag = kj_ref[t], qi_ref[t], flag_ref[t]
+    rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+
+    @pl.when(t == 0)
+    def _new_head():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    @pl.when(flag & _FIRST_OF_K != 0)
+    def _new_key_block():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    def tile(masked: bool):
+        q, g = q_ref[0], g_ref[0]                  # [bq, d]
+        k, v = k_ref[0], v_ref[0]                  # [bk, d]
+        s = jax.lax.dot_general(k, q, _NT,
+                                preferred_element_type=jnp.float32) * scale
+        if masked:
+            kpos = kj * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0)
+            mask = kpos < seq_k
+            if causal:
+                qpos = qi * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1)
+                mask = mask & (qpos >= kpos)
+                if window is not None:
+                    mask = mask & (qpos - kpos < window)
+            s = jnp.where(mask, s, _NEG_INF)
+        p = jnp.exp(s - lse_ref[0])                # [bk, bq]
+        dv_scr[...] += jnp.dot(p.astype(g.dtype), g,
+                               preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v, g, _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[0])).astype(q.dtype)
+        dk_scr[...] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+        dq_scr[rows, :] += jax.lax.dot_general(
+            ds, k, _TN, preferred_element_type=jnp.float32)
+
+    pl.when(flag & _MASKED != 0)(lambda: tile(True))
+    pl.when(flag & _MASKED == 0)(lambda: tile(False))
+
+    @pl.when(flag & _LAST_OF_K != 0)
+    def _write_key_block():
+        dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _write_head():
+        dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
+
+
+@functools.lru_cache(maxsize=64)
+def _bwd_call(bh, tq, tk, d, dtype, scale, causal, block_q, block_k, true_tk,
+              interpret, window):
+    """The backward ``pallas_call`` over q, k, v, g of ``[BH, T, D]`` and
+    lse, delta of ``[BH, 1, Tq]`` (padded as for ``_fwd_call``), giving dq,
+    dk, dv: built once for its sizes.  A head's dq stays in VMEM until its
+    last tile (8 MB of float32 at 16,384 x 128 or 8,192 x 256, and the
+    output block twice), so the limit is raised to what the sizes need; a
+    head too long for the chip's VMEM is the compiler's to refuse."""
+    tiles = _bwd_tiles(tq, tk, block_q, block_k, true_tk, causal, window)
+    kernel = functools.partial(_bwd_kernel, scale=scale, causal=causal,
+                               block_q=block_q, block_k=block_k,
+                               seq_k=true_tk, window=window)
+
+    def q_block(b, t, kj, qi, flag):
+        return (b, qi[t], 0)
+
+    def k_block(b, t, kj, qi, flag):
+        return (b, kj[t], 0)
+
+    def q_row(b, t, kj, qi, flag):
+        return (b, 0, qi[t])
+
+    size = jnp.dtype(dtype).itemsize
+    # dq of a head, float32, and its double-buffered output; the streamed
+    # blocks twice; dk, dv; a handful of [block_k, block_q] float32 terms
+    vmem = (tq * d * (4 + 2 * size) + 4 * (block_q + 2 * block_k) * d * size
+            + 2 * block_k * d * 4 + 8 * block_q * block_k * 4)
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(bh, len(tiles[0])),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), q_block),
+                pl.BlockSpec((1, block_k, d), k_block),
+                pl.BlockSpec((1, block_k, d), k_block),
+                pl.BlockSpec((1, block_q, d), q_block),
+                pl.BlockSpec((1, 1, block_q), q_row),
+                pl.BlockSpec((1, 1, block_q), q_row),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, tq, d), lambda b, t, *_: (b, 0, 0)),
+                pl.BlockSpec((1, block_k, d), k_block),
+                pl.BlockSpec((1, block_k, d), k_block),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((tq, d), jnp.float32),
+                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, d), jnp.float32),
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, tq, d), dtype),
+            jax.ShapeDtypeStruct((bh, tk, d), dtype),
+            jax.ShapeDtypeStruct((bh, tk, d), dtype),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem + (16 << 20)),
+        interpret=interpret,
+        name=("flash_attention_bwd" if window is None
+              else "flash_attention_window_bwd"),
+    )
+    return functools.partial(call, *tiles)
+
+
+def _bwd_blocks(tq: int, tk: int, window: Optional[int]):
+    """(query block, key block) of the backward kernel.  A grid step costs
+    about what a 256 x 256 tile's products do, so tiles are large: 1,024
+    where the triangle or the square is walked (32.4 ms at 32 x 16,384 x 128
+    against 36.7 at 512 and 76.4 at 256, on a v5e); 512 on a band, whose
+    edge tiles compute pairs outside it (a band of 2,048: 11.2 ms at 512,
+    12.0 at 1,024 x 512, 20.6 at 256).  Multiples of the 128 lanes; a
+    shorter sequence is one block."""
+    block = 1024 if window is None else 512
+    return min(block, _ceil_to(tq, 128)), min(block, _ceil_to(tk, 128))
+
+
+def _padded_pallas_bwd(q3, k3, v3, out, lse, g, scale, causal, interpret,
+                       window=None):
+    """The backward kernel on q, k, v, out, g of ``[BH, T, D]``: T padded to
+    the blocks (zero rows add nothing; padded keys are masked), D to the
+    128-lane tile."""
+    bh, tq, d = q3.shape
+    tk = k3.shape[1]
+    bq, bk = _bwd_blocks(tq, tk, window)
+    tq_p, tk_p, d_p = _ceil_to(tq, bq), _ceil_to(tk, bk), _ceil_to(d, 128)
+    delta = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
+
+    def pad(a, t_p):
+        return jnp.pad(a, ((0, 0), (0, t_p - a.shape[1]), (0, d_p - d)))
+
+    def row(a):
+        return jnp.pad(a, ((0, 0), (0, tq_p - tq)))[:, None, :]
+    dq, dk, dv = _bwd_call(
+        bh, tq_p, tk_p, d_p, jnp.dtype(q3.dtype), scale, causal, bq, bk, tk,
+        interpret, window)(pad(q3, tq_p), pad(k3, tk_p), pad(v3, tk_p),
+                           pad(g, tq_p), row(lse), row(delta))
+    return dq[:, :tq, :d], dk[:, :tk, :d], dv[:, :tk, :d]
+
+
+# ---------------------------------------------------------------------------
+# Blocked pure-JAX math (forward and backward off the chip)
 # ---------------------------------------------------------------------------
 
 def _blocked_fwd_jax(q, k, v, scale, causal, block_k):
@@ -480,14 +700,25 @@ def _flash_vjp_fwd(q3, k3, v3, causal, block_q, block_k, window=None):
     return out, (q3, k3, v3, out, lse)
 
 
-def _flash_vjp_bwd(causal, block_q, block_k, window, res, g):
-    q3, k3, v3, out, lse = res
+def _flash_bwd_dispatch(q3, k3, v3, out, lse, g, causal, block_k,
+                        window=None):
+    """As ``_flash_fwd_dispatch``: on the chip the compiled kernel or the
+    compiler's error, elsewhere the blocked ``jax.numpy`` form, or the
+    kernel in interpret mode when a test asks for it."""
     scale = 1.0 / (q3.shape[-1] ** 0.5)
+    if jax.default_backend() == "tpu" or INTERPRET:
+        return _padded_pallas_bwd(q3, k3, v3, out, lse, g, scale, causal,
+                                  interpret=jax.default_backend() != "tpu",
+                                  window=window)
     if window is not None:
         return _band_bwd_jax(q3, k3, v3, out, lse, g, scale, window,
                              min(block_k, k3.shape[1]))
     return _blocked_bwd_jax(q3, k3, v3, out, lse, g, scale, causal,
                             min(block_k, k3.shape[1]))
+
+
+def _flash_vjp_bwd(causal, block_q, block_k, window, res, g):
+    return _flash_bwd_dispatch(*res, g, causal, block_k, window)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -500,8 +731,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """Flash attention over [B, T, H, D] tensors (softmax scale 1/sqrt(D)).
 
     Differentiable; O(T·D) memory.  Matches :func:`mha_reference` to fp
-    tolerance (see tests/test_ops.py).  ``window`` (causal self-attention
-    only): position i sees keys ``i - window + 1 .. i``; forward and
+    tolerance (see tests/test_ops.py).  ``block_q`` and ``block_k`` are the
+    forward's (and the ``jax.numpy`` backward's k block); the backward
+    kernel takes its blocks from the shapes.  ``window`` (causal
+    self-attention only): position i sees keys ``i - window + 1 .. i``; forward and
     backward then visit the band's blocks alone.  A window that covers the
     row is plain causal attention and takes its path.
     """

@@ -79,6 +79,145 @@ def test_pallas_kernel_interpret_causal(rng):
                                atol=2e-5, rtol=2e-5)
 
 
+# -- the backward kernels (flash_attention_bwd / flash_attention_window_bwd) ---
+#
+# tq, tk, d, causal, window, dtype: causal and not, a band of 2,048 in 4,096
+# (whole tiles inside the band, masked ones at its two edges), 50 in 200
+# (nothing aligned: T padded to the block, D to the lanes, padded keys
+# masked), a window that covers the row (flash_attention takes the causal
+# path), heads of 64 / 128 / 256, Tq != Tk, float32 and bf16 operands
+BWD_CASES = {
+    "causal_d64": (256, 256, 64, True, None, jnp.float32),
+    "full_d64": (256, 256, 64, False, None, jnp.float32),
+    "causal_d128_two_blocks": (1536, 1536, 128, True, None, jnp.float32),
+    "causal_d256": (640, 640, 256, True, None, jnp.float32),
+    "window_2048_of_4096": (4096, 4096, 128, True, 2048, jnp.float32),
+    "window_50_of_200": (200, 200, 64, True, 50, jnp.float32),
+    "window_covers_the_row": (200, 200, 64, True, 200, jnp.float32),
+    "unaligned_causal": (200, 200, 40, True, None, jnp.float32),
+    "cross_tq_gt_tk": (700, 200, 64, False, None, jnp.float32),
+    "cross_tq_lt_tk": (200, 700, 64, False, None, jnp.float32),
+    "causal_bf16": (1024, 1024, 128, True, None, jnp.bfloat16),
+    "window_bf16": (1024, 1024, 128, True, 300, jnp.bfloat16),
+    "full_bf16_d256": (256, 640, 256, False, None, jnp.bfloat16),
+}
+
+
+def _bwd_inputs(rng, tq, tk, d, dtype, heads=2):
+    q, g = (jnp.asarray(rng.normal(size=(1, tq, heads, d)), dtype)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.normal(size=(1, tk, heads, d)), dtype)
+            for _ in range(2))
+    return q, k, v, g
+
+
+@pytest.mark.parametrize("case", list(BWD_CASES))
+def test_backward_kernel_matches_reference_and_blocked_form(rng, case,
+                                                            monkeypatch):
+    """The Pallas backward (interpret mode) against ``mha_reference``'s
+    gradients — to 1e-5 of their size in float32 — and against the blocked
+    ``jax.numpy`` backward it replaces on the chip, from the same
+    residuals."""
+    tq, tk, d, causal, window, dtype = BWD_CASES[case]
+    q, k, v, g = _bwd_inputs(rng, tq, tk, d, dtype)
+    f32 = [a.astype(jnp.float32) for a in (q, k, v, g)]
+
+    def grads(attend, q, k, v, g):
+        return jax.grad(lambda q, k, v: jnp.sum(
+            attend(q, k, v).astype(jnp.float32) * g.astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, window=window)
+
+    want = grads(lambda q, k, v: mha_reference(q, k, v, causal=causal,
+                                               window=window), *f32)
+    blocked = grads(flash, q, k, v, g)
+    monkeypatch.setattr(fa_mod, "INTERPRET", True)
+    got = grads(flash, q, k, v, g)
+    exact = dtype == jnp.float32
+    for a, b, c in zip(got, blocked, want):
+        assert a.dtype == dtype and a.shape == c.shape
+        a, b, c = (np.asarray(x, np.float32) for x in (a, b, c))
+        size = np.abs(c).max()
+        assert np.abs(a - c).max() <= (1e-5 if exact else 3e-2) * size
+        assert np.abs(a - b).max() <= (1e-5 if exact else 2e-2) * size
+
+
+def test_backward_kernel_band_edges_by_hand(rng, monkeypatch):
+    """The band's edge tiles, worked out in numpy: the first row (one key:
+    no gradient for q), the last row, the last key (one query sees it) and
+    the first key (the first ``window`` rows see it).  600 rows in blocks of
+    512 with a window of 130: every tile is an edge tile of some kind."""
+    t, d, window = 600, 64, 130
+    q, k, v, g = (np.asarray(a[0, :, 0], np.float64)
+                  for a in _bwd_inputs(rng, t, t, d, jnp.float32, heads=1))
+    scale = d ** -0.5
+
+    def row(i):
+        """p, ds of query i over the keys it sees, and their first index."""
+        lo = max(0, i - window + 1)
+        s = k[lo:i + 1] @ q[i] * scale
+        p = np.exp(s - s.max())
+        p /= p.sum()
+        dp = v[lo:i + 1] @ g[i]
+        return p, p * (dp - p @ dp) * scale, lo
+
+    monkeypatch.setattr(fa_mod, "INTERPRET", True)
+    as4 = lambda a: jnp.asarray(a, jnp.float32)[None, :, None, :]
+    dq, dk, dv = (np.asarray(a[0, :, 0]) for a in jax.grad(
+        lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, window=window) * as4(g)),
+        argnums=(0, 1, 2))(as4(q), as4(k), as4(v)))
+    np.testing.assert_allclose(dq[0], 0.0, atol=1e-6)
+    p, ds, lo = row(t - 1)
+    np.testing.assert_allclose(dq[t - 1], ds @ k[lo:], atol=1e-5)
+    np.testing.assert_allclose(dv[t - 1], p[-1] * g[t - 1], atol=1e-5)
+    np.testing.assert_allclose(dk[t - 1], ds[-1] * q[t - 1], atol=1e-5)
+    first = [row(i) for i in range(window)]      # the rows that see key 0
+    np.testing.assert_allclose(
+        dv[0], sum(p[0] * g[i] for i, (p, _, _) in enumerate(first)),
+        atol=1e-5)
+    np.testing.assert_allclose(
+        dk[0], sum(ds[0] * q[i] for i, (_, ds, _) in enumerate(first)),
+        atol=1e-5)
+    # ... and row ``window`` no longer does
+    assert row(window)[2] == 1
+
+
+def test_backward_tiles_are_the_triangle_and_the_band():
+    """``_bwd_tiles``: no tile above the diagonal or outside the band, masks
+    on the tiles an edge crosses and on no other."""
+    kj, qi, flag = fa_mod._bwd_tiles(2048, 2048, 512, 512, 2048, True, None)
+    assert sorted(zip(kj, qi)) == [(j, i) for j in range(4)
+                                   for i in range(j, 4)]
+    assert [bool(f & fa_mod._MASKED) for f in flag] == \
+        [j == i for j, i in zip(kj, qi)]
+    # a band of 1,024 in blocks of 512: key block j is seen by query blocks
+    # j .. j + 2, the diagonal's and the far edge's tiles masked
+    kj, qi, flag = fa_mod._bwd_tiles(4096, 4096, 512, 512, 4096, True, 1024)
+    assert sorted(zip(kj, qi)) == [(j, i) for j in range(8)
+                                   for i in range(j, min(j + 3, 8))]
+    assert [bool(f & fa_mod._MASKED) for f in flag] == \
+        [i != j + 1 for j, i in zip(kj, qi)]
+    # every key block starts and ends once, in order
+    for tiles in (fa_mod._bwd_tiles(4096, 4096, 512, 512, 4096, True, 1024),
+                  fa_mod._bwd_tiles(512, 1536, 512, 512, 1500, True, None),
+                  fa_mod._bwd_tiles(1024, 512, 256, 512, 300, False, None)):
+        kj, qi, flag = tiles
+        assert list(kj) == sorted(kj)
+        for j in set(kj):
+            mine = flag[kj == j]
+            assert mine[0] & fa_mod._FIRST_OF_K
+            assert mine[-1] & fa_mod._LAST_OF_K
+            assert not any(f & fa_mod._FIRST_OF_K for f in mine[1:])
+            assert not any(f & fa_mod._LAST_OF_K for f in mine[:-1])
+    # causal with more keys than queries: the key blocks no query sees
+    # keep one masked tile each, which writes their zeros
+    kj, qi, flag = fa_mod._bwd_tiles(512, 1536, 512, 512, 1500, True, None)
+    assert list(kj) == [0, 1, 2] and all(f & fa_mod._MASKED for f in flag)
+
+
 def test_flash_under_jit_and_mha_layer(rng):
     """use_flash=True path of nn.MultiHeadAttention compiles and runs."""
     import analytics_zoo_tpu.nn as nn

@@ -135,6 +135,30 @@ def test_windowed_flash_backward_compiles_in_the_bands_memory(chip):
         < 0.5 * whole.memory_analysis().temp_size_in_bytes
 
 
+# the backward kernels at both cells' shapes (Qwen3-Next: 2 rows x 16 heads of
+# 256 at 8,192; Trinity-Mini: 32 heads of 128 at 16,384, full and with a
+# window of 2,048), the unaligned pair, and BERT's heads non-causal
+@pytest.mark.parametrize("bh,t,d,causal,window", [
+    (32, 8192, 256, True, None), (32, 16384, 128, True, None),
+    (32, 16384, 128, True, 2048), (32, 200, 128, True, 50),
+    (48, 2048, 64, False, None)])
+def test_flash_backward_kernel_compiles(chip, bh, t, d, causal, window):
+    x = _on(chip, jax.ShapeDtypeStruct((bh, t, d), jnp.bfloat16))
+    lse = _on(chip, jax.ShapeDtypeStruct((bh, t), jnp.float32))
+    compiled = _compile(
+        lambda q, k, v, o, l, g: fa._padded_pallas_bwd(
+            q, k, v, o, l, g, d ** -0.5, causal, interpret=False,
+            window=window), x, x, x, x, lse, x)
+    name = ("flash_attention_bwd" if window is None
+            else "flash_attention_window_bwd")
+    assert re.findall(rf"%({name}[.\d]*) = ", compiled.as_text())
+    if d % 128 == 0 and t % 1024 == 0:
+        # nothing is padded and nothing of a tile reaches HBM: the program's
+        # temporaries are delta and lse as rows, where one [BH, T, 256]
+        # float32 term of the blocked form is 268 MB
+        assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
 # one DeltaNet layer of the Qwen3-Next cell: two rows of 8192 tokens, 16 key
 # and 32 value heads of 128, chunks of 64 in tiles of 128 rows
 @pytest.mark.parametrize("which", ["forward", "forward_for_a_gradient",
@@ -309,11 +333,28 @@ def test_bert_base_one_chip_step_is_the_program_pr26_recorded(topo):
     assert one.memory_analysis().temp_size_in_bytes == 4455141888
 
 
+def _flash_takes_the_chips_branch(monkeypatch):
+    """``default_backend()`` is "cpu" during these compiles: make flash
+    attention's forward and backward take the branch they take on the
+    chip, the compiled kernels."""
+    monkeypatch.setattr(
+        fa, "_flash_fwd_dispatch",
+        lambda q, k, v, causal, bq, bk, window=None: fa._padded_pallas(
+            q, k, v, q.shape[-1] ** -0.5, causal, bq, bk, interpret=False,
+            window=window))
+    monkeypatch.setattr(
+        fa, "_flash_bwd_dispatch",
+        lambda q, k, v, o, lse, g, causal, bk, window=None:
+        fa._padded_pallas_bwd(q, k, v, o, lse, g, q.shape[-1] ** -0.5,
+                              causal, interpret=False, window=window))
+
+
 def test_qwen3next_cell_train_step_compiles_for_one_chip(topo, monkeypatch):
     """``qwen3next_ep16_fit_s8192``'s train step at its real sizes (625.7 M
     parameters with AdamW's moments, two rows of 8192 tokens): the chip's
     compiler takes it — a program over the chip's memory is refused here —
-    with the flash kernel and the two delta-rule kernels under their names,
+    with the flash kernels (forward and backward) and the two delta-rule
+    kernels under their names,
     the grouped matmuls of the expert layer as XLA's ragged-dot kernels,
     and one call of each kernel a layer and step (the blocks'
     recomputation keeps what the kernels' backward passes read: three
@@ -321,12 +362,7 @@ def test_qwen3next_cell_train_step_compiles_for_one_chip(topo, monkeypatch):
     import json
     from analytics_zoo_tpu.orca.learn import Estimator
     from benchmark.families import qwen3_next
-    # default_backend() is "cpu" here: take the branch the chip takes
-    monkeypatch.setattr(
-        fa, "_flash_fwd_dispatch",
-        lambda q, k, v, causal, bq, bk, window=None: fa._padded_pallas(
-            q, k, v, q.shape[-1] ** -0.5, causal, bq, bk, interpret=False,
-            window=window))
+    _flash_takes_the_chips_branch(monkeypatch)
     monkeypatch.setattr(gdr, "dispatch", lambda dk, dv, chunk: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(
@@ -343,6 +379,8 @@ def test_qwen3next_cell_train_step_compiles_for_one_chip(topo, monkeypatch):
     text = compiled.as_text()
     kernels = re.findall(r"%(flash_attention_fwd[.\d]*) = ", text)
     assert len(kernels) == 1, kernels
+    backward = re.findall(r"%(flash_attention_bwd[.\d]*) = ", text)
+    assert len(backward) == 1, backward
     for name in ("gated_delta_rule_fwd", "gated_delta_rule_bwd"):
         calls = re.findall(rf"%({name}[.\d]*) = ", text)
         assert len(calls) == 3, (name, calls)
@@ -357,17 +395,14 @@ def test_trinity_mini_cell_train_step_compiles_for_one_chip(topo,
     """``trinity_mini_ep8_fit_s16384``'s train step at its real sizes
     (705.5 M parameters with AdamW's moments, one row of 16,384 tokens):
     the chip's compiler takes it inside the chip's memory, with the
-    windowed kernel once a sliding layer and the plain one once for the
-    full layer (the blocks' recomputation keeps what the backward passes
-    read), and the expert layers' grouped matmuls as ragged-dot kernels."""
+    windowed kernels (forward, backward) once a sliding layer and the plain
+    ones once for the full layer (the blocks' recomputation keeps what the
+    backward passes read), and the expert layers' grouped matmuls as
+    ragged-dot kernels."""
     import json
     from analytics_zoo_tpu.orca.learn import Estimator
     from benchmark.families import afmoe
-    monkeypatch.setattr(
-        fa, "_flash_fwd_dispatch",
-        lambda q, k, v, causal, bq, bk, window=None: fa._padded_pallas(
-            q, k, v, q.shape[-1] ** -0.5, causal, bq, bk, interpret=False,
-            window=window))
+    _flash_takes_the_chips_branch(monkeypatch)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(
             root, "benchmark/configs/trinity_mini_ep8.json")) as f:
@@ -383,6 +418,9 @@ def test_trinity_mini_cell_train_step_compiles_for_one_chip(topo,
     text = compiled.as_text()
     window = re.findall(r"%(flash_attention_window_fwd[.\d]*) = ", text)
     full = re.findall(r"%(flash_attention_fwd[.\d]*) = ", text)
+    assert (len(window), len(full)) == (4, 1), (window, full)
+    window = re.findall(r"%(flash_attention_window_bwd[.\d]*) = ", text)
+    full = re.findall(r"%(flash_attention_bwd[.\d]*) = ", text)
     assert (len(window), len(full)) == (4, 1), (window, full)
     assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) >= 6 * 4
     held = sum(int(np.prod(l.shape)) for l in
