@@ -4,10 +4,12 @@ Every family from the reference's Scala+Py twin zoo, rebuilt as pure-JAX
 modules over analytics_zoo_tpu.nn: recommendation (NeuralCF, WideAndDeep,
 SessionRecommender), text classification, text matching (KNRM), anomaly
 detection, seq2seq, image classification (ResNet), object detection (SSD),
-plus the BERT family the reference shipped through TFPark, and two
-sparse-expert causal decoders the reference had no analog of: a hybrid
-linear-attention one (Qwen3Next) and a sliding-window / full-attention one
-with a bias-balanced sigmoid router (AFMoE).
+plus the BERT family the reference shipped through TFPark, and three
+causal decoders the reference had no analog of: two sparse-expert ones, a
+hybrid linear-attention one (Qwen3Next) and a sliding-window /
+full-attention one with a bias-balanced sigmoid router (AFMoE), and a dense
+hybrid of Mamba-2 state-space blocks and attention with a tied, scaled head
+(GraniteHybrid).
 """
 
 from .common import ZooModel
@@ -23,6 +25,7 @@ from .objectdetection import ObjectDetector, SSDLite, Visualizer
 from .bert import BERT, BERTClassifier, BERTNER, BERTSQuAD
 from .qwen3_next import Qwen3Next
 from .afmoe import AFMoE
+from .granite_hybrid import GraniteHybrid
 from .graphnet import GraphNet
 from .net import ForeignNet, Net
 
@@ -33,5 +36,5 @@ __all__ = [
     "AnomalyDetector", "unroll", "Seq2seq", "RNNEncoder", "RNNDecoder",
     "ImageClassifier", "ResNet", "ObjectDetector", "SSDLite", "Visualizer",
     "BERT", "BERTClassifier", "BERTNER", "BERTSQuAD", "Qwen3Next",
-    "AFMoE",
+    "AFMoE", "GraniteHybrid",
 ]

@@ -36,6 +36,7 @@ from .functional import Input, Model, SymbolicTensor
 from .linear_attention import GatedDeltaNet
 from .module import Module, Scope, param_count
 from .recurrent import (GRU, LSTM, Bidirectional, SimpleRNN, TimeDistributed)
+from .state_space import Mamba2
 
 # keras-1 naming aliases (reference: zoo keras-1.2 class names) so ported
 # scripts keep their spellings
@@ -63,7 +64,7 @@ __all__ = [
     "GlobalAveragePooling2D", "GlobalMaxPooling2D", "GlobalAveragePooling1D",
     "GlobalMaxPooling1D", "ZeroPadding2D", "BatchNormalization",
     "LayerNormalization", "Concatenate", "Add", "Multiply", "Sequential",
-    "RMSNorm", "SwiGLU", "CausalConv1D", "GatedDeltaNet",
+    "RMSNorm", "SwiGLU", "CausalConv1D", "GatedDeltaNet", "Mamba2",
     "LSTM", "GRU", "SimpleRNN", "Bidirectional", "TimeDistributed",
     "MultiHeadAttention", "TransformerLayer", "dot_product_attention",
     # extended Keras-1.2 zoo (layers_extra)

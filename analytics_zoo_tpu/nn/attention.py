@@ -13,6 +13,7 @@ optional fused-kernel hook — ``analytics_zoo_tpu.ops.flash_attention``
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional, Union
 
 import jax
@@ -61,15 +62,19 @@ def causal_mask(tq: int, tk: Optional[int] = None,
 
 def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                           mask: Optional[jax.Array] = None,
-                          ) -> jax.Array:
+                          scale: Optional[float] = None) -> jax.Array:
     """Plain attention: q,k,v [B, T, H, D] → [B, T, H, D].
 
     mask: broadcastable to [B, H, Tq, Tk]; 1 = attend, 0 = masked.
+    scale: the logits' multiplier, 1/sqrt(D) unless given.
     """
     d = q.shape[-1]
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32)
-    logits = logits / jnp.sqrt(jnp.asarray(d, logits.dtype))
+    if scale is None:
+        logits = logits / jnp.sqrt(jnp.asarray(d, logits.dtype))
+    else:
+        logits = logits * scale
     if mask is not None:
         logits = jnp.where(mask.astype(bool), logits, -1e30)
     weights = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
@@ -109,8 +114,10 @@ class MultiHeadAttention(Module):
     ``sigmoid(gate)`` before ``wo``), ``window`` (sliding-window attention,
     causal only: position i sees the ``window`` keys ``i - window + 1 ..
     i``; the flash path visits the band's blocks alone, the dense path
-    builds the band mask).  All of them go through the one dense / flash /
-    ring dispatch below."""
+    builds the band mask), ``scale`` (the softmax's multiplier where a
+    model publishes one other than ``1/sqrt(head_dim)``; dense and flash
+    paths).  All of them go through the one dense / flash / ring dispatch
+    below."""
 
     def __init__(self, num_heads: int, head_dim: Optional[int] = None,
                  dropout: float = 0.0,
@@ -122,8 +129,13 @@ class MultiHeadAttention(Module):
                  gate: bool = False, norm_epsilon: float = 1e-6,
                  window: Optional[int] = None,
                  qk_norm_zero_centered: bool = True,
+                 scale: Optional[float] = None,
                  name: Optional[str] = None):
         super().__init__(name)
+        if scale is not None and use_ring:
+            raise ValueError("scale is taken by the dense and flash paths; "
+                             "ring attention scales by 1/sqrt(head_dim)")
+        self.scale = scale
         if window is not None and (not causal or use_ring or window < 1):
             raise ValueError("window is sliding-window causal attention on "
                              "the dense or flash path: it needs causal=True, "
@@ -223,7 +235,7 @@ class MultiHeadAttention(Module):
         elif use_flash and mask is None:
             from analytics_zoo_tpu.ops import flash_attention
             ctx = flash_attention(q, k, v, causal=self.causal,
-                                  window=self.window)
+                                  window=self.window, scale=self.scale)
         else:
             # explicit mask: dense path (flash/ring kernels take no mask);
             # causal still applies — combine, never silently drop it
@@ -238,9 +250,8 @@ class MultiHeadAttention(Module):
             if self.causal:
                 cm = causal_mask(x.shape[1], kv.shape[1], self.window)
                 mask = cm if mask is None else (mask.astype(bool) & cm)
-            attn = (jax.checkpoint(dot_product_attention) if self.remat
-                    else dot_product_attention)
-            ctx = attn(q, k, v, mask)
+            attn = functools.partial(dot_product_attention, scale=self.scale)
+            ctx = (jax.checkpoint(attn) if self.remat else attn)(q, k, v, mask)
 
         if gate is not None:
             ctx = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)

@@ -593,15 +593,17 @@ class SwiGLU(Module):
 class CausalConv1D(Module):
     """Depthwise causal convolution over time: ``[B, T, C] -> [B, T, C]``,
     ``y[t, c] = sum_j w[j, c] * x[t - (K-1) + j, c]`` with zeros before the
-    sequence's start, no bias.  Written as K shifted multiply-adds, which
-    XLA fuses into one pass over ``x`` (a grouped convolution with one
-    channel a group has nothing for the MXU to do), summed in float32."""
+    sequence's start, plus a ``[C]`` bias under ``use_bias``, then the
+    activation.  Written as K shifted multiply-adds, which XLA fuses into
+    one pass over ``x`` (a grouped convolution with one channel a group has
+    nothing for the MXU to do), summed in float32."""
 
     def __init__(self, kernel_size: int, activation: Any = None,
-                 kernel_init: Any = "lecun_uniform",
+                 kernel_init: Any = "lecun_uniform", use_bias: bool = False,
                  name: Optional[str] = None):
         super().__init__(name)
         self.kernel_size = kernel_size
+        self.use_bias = use_bias
         self.activation = activations.get(activation)
         self.kernel_init = initializers.get(kernel_init)
 
@@ -611,6 +613,9 @@ class CausalConv1D(Module):
         xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
         # float32 inside the fusion: costs no traffic, saves K roundings
         y = sum(xp[:, j:j + t].astype(jnp.float32) * w[j] for j in range(k))
+        if self.use_bias:
+            y = y + scope.param("bias", initializers.get("zeros"),
+                                (x.shape[-1],))
         return self.activation(y).astype(x.dtype)
 
 

@@ -46,6 +46,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import gated_delta_rule as kernels
+from .initializers import a_log_init, dt_bias_init
 from .layers import CausalConv1D, Dense, RMSNorm
 from .module import Module, Scope
 
@@ -204,24 +205,6 @@ def _chunked_jax(q, k, v, g, beta, s0, chunk):
     return o.astype(dt), s_final
 
 
-def _dt_bias_init(low: float = 1e-3, high: float = 0.1):
-    """``softplus^-1(dt)`` with ``dt`` log-uniform in [low, high]: a head
-    forgets ``exp(A_log) * dt`` a position, from almost nothing to a few
-    tenths (the initialiser of the Gated DeltaNet reference code)."""
-    def init(rng, shape, dtype=jnp.float32):
-        dt = jnp.exp(jax.random.uniform(rng, shape, jnp.float32,
-                                        np.log(low), np.log(high)))
-        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
-    return init
-
-
-def _a_log_init(high: float = 16.0):
-    def init(rng, shape, dtype=jnp.float32):
-        return jnp.log(jax.random.uniform(rng, shape, jnp.float32, 1e-4,
-                                          high)).astype(dtype)
-    return init
-
-
 class GatedDeltaNet(Module):
     """Gated DeltaNet mixer: ``[B, T, D] -> [B, T, D]``, causal.
 
@@ -269,8 +252,8 @@ class GatedDeltaNet(Module):
         k = qkv[..., key_dim:2 * key_dim].reshape(b, t, hk, dk)
         v = qkv[..., 2 * key_dim:].reshape(b, t, hv, dv)
 
-        a_log = scope.param("A_log", _a_log_init(), (hv,))
-        dt_bias = scope.param("dt_bias", _dt_bias_init(), (hv,))
+        a_log = scope.param("A_log", a_log_init(), (hv,))
+        dt_bias = scope.param("dt_bias", dt_bias_init(), (hv,))
         ba = ba.astype(jnp.float32)
         beta = jax.nn.sigmoid(ba[..., :hv])
         g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias)

@@ -630,13 +630,15 @@ def _band_bwd_jax(q, k, v, out, lse, g, scale, window, block):
 # ---------------------------------------------------------------------------
 
 def mha_reference(q, k, v, causal: bool = False,
-                  window: Optional[int] = None) -> jax.Array:
+                  window: Optional[int] = None,
+                  scale: Optional[float] = None) -> jax.Array:
     """Materialized-logits reference ([B, T, H, D]) for differential tests;
-    ``window`` as in :func:`flash_attention`."""
+    ``window`` and ``scale`` as in :func:`flash_attention`."""
     d = q.shape[-1]
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) / jnp.sqrt(
-        jnp.asarray(d, jnp.float32))
+                   preferred_element_type=jnp.float32)
+    s = s / jnp.sqrt(jnp.asarray(d, jnp.float32)) if scale is None \
+        else s * scale
     if causal:
         tq, tk = s.shape[-2:]
         seen = jnp.arange(tq)[:, None] - jnp.arange(tk)[None, :]
@@ -646,18 +648,24 @@ def mha_reference(q, k, v, causal: bool = False,
     return jnp.einsum("bhqk,bkhd->bqhd", w, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q3, k3, v3, causal, block_q, block_k, window=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q3, k3, v3, causal, block_q, block_k, window=None, scale=None):
     out, _ = _flash_fwd_dispatch(q3, k3, v3, causal, block_q, block_k,
-                                 window)
+                                 window, scale)
     return out
 
 
 INTERPRET = False  # tests set True to exercise the Pallas kernel on CPU
 
 
-def _flash_fwd_dispatch(q3, k3, v3, causal, block_q, block_k, window=None):
-    scale = 1.0 / (q3.shape[-1] ** 0.5)
+def _softmax_scale(scale: Optional[float], d: int) -> float:
+    """The logits' multiplier: 1/sqrt(D) unless the caller names one."""
+    return 1.0 / (d ** 0.5) if scale is None else float(scale)
+
+
+def _flash_fwd_dispatch(q3, k3, v3, causal, block_q, block_k, window=None,
+                        scale=None):
+    scale = _softmax_scale(scale, q3.shape[-1])
     if jax.default_backend() == "tpu":
         # the compiled kernel or the compiler's error: no interpret mode,
         # no pure-JAX stand-in on the device the kernel was written for
@@ -689,9 +697,10 @@ def _padded_pallas(q3, k3, v3, scale, causal, block_q, block_k, interpret,
     return out[:, :tq, :d], lse[:, 0, :tq]
 
 
-def _flash_vjp_fwd(q3, k3, v3, causal, block_q, block_k, window=None):
+def _flash_vjp_fwd(q3, k3, v3, causal, block_q, block_k, window=None,
+                   scale=None):
     out, lse = _flash_fwd_dispatch(q3, k3, v3, causal, block_q, block_k,
-                                   window)
+                                   window, scale)
     # named, so that an enclosing jax.checkpoint can be told to keep them
     # (policy save_only_these_names): [BH, T, D] + [BH, T] kept spare the
     # recomputation a second run of the whole kernel
@@ -701,11 +710,11 @@ def _flash_vjp_fwd(q3, k3, v3, causal, block_q, block_k, window=None):
 
 
 def _flash_bwd_dispatch(q3, k3, v3, out, lse, g, causal, block_k,
-                        window=None):
+                        window=None, scale=None):
     """As ``_flash_fwd_dispatch``: on the chip the compiled kernel or the
     compiler's error, elsewhere the blocked ``jax.numpy`` form, or the
     kernel in interpret mode when a test asks for it."""
-    scale = 1.0 / (q3.shape[-1] ** 0.5)
+    scale = _softmax_scale(scale, q3.shape[-1])
     if jax.default_backend() == "tpu" or INTERPRET:
         return _padded_pallas_bwd(q3, k3, v3, out, lse, g, scale, causal,
                                   interpret=jax.default_backend() != "tpu",
@@ -717,8 +726,8 @@ def _flash_bwd_dispatch(q3, k3, v3, out, lse, g, causal, block_k,
                             min(block_k, k3.shape[1]))
 
 
-def _flash_vjp_bwd(causal, block_q, block_k, window, res, g):
-    return _flash_bwd_dispatch(*res, g, causal, block_k, window)
+def _flash_vjp_bwd(causal, block_q, block_k, window, scale, res, g):
+    return _flash_bwd_dispatch(*res, g, causal, block_k, window, scale)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -727,8 +736,12 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False, block_q: int = 256,
                     block_k: int = 256,
-                    window: Optional[int] = None) -> jax.Array:
-    """Flash attention over [B, T, H, D] tensors (softmax scale 1/sqrt(D)).
+                    window: Optional[int] = None,
+                    scale: Optional[float] = None) -> jax.Array:
+    """Flash attention over [B, T, H, D] tensors: ``softmax(scale * q k^T)
+    v``, ``scale`` 1/sqrt(D) unless given (a model that publishes its own
+    multiplier passes it; forward and backward, kernels and ``jax.numpy``
+    forms alike take it as a static number).
 
     Differentiable; O(T·D) memory.  Matches :func:`mha_reference` to fp
     tolerance (see tests/test_ops.py).  ``block_q`` and ``block_k`` are the
@@ -750,5 +763,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     q3 = q.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
     k3 = k.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
     v3 = v.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
-    out = _flash(q3, k3, v3, causal, block_q, block_k, window)
+    out = _flash(q3, k3, v3, causal, block_q, block_k, window, scale)
     return out.reshape(b, h, tq, d).transpose(0, 2, 1, 3)

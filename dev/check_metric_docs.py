@@ -12,11 +12,12 @@ way, so span naming can't drift undocumented either:
   ``core/metrics.py`` registry is found by scanning ``analytics_zoo_tpu``
   sources for ``counter("...")`` / ``gauge("...")`` /
   ``histogram("...")`` / ``inc("...")`` / ``observe("...")`` /
-  ``set_gauge("...")`` string literals, PLUS the four known dynamic
+  ``set_gauge("...")`` string literals, PLUS the five known dynamic
   registration sites (``"client." + key`` over the client's stats dict,
   ``"server." + k`` over the server's counters dict, ``"frontend." +
   key`` over ``_FRONTEND_COUNTERS``, ``"moe." + key`` over the expert
-  layer's ``COUNTER_KEYS`` and ``LEVEL_KEYS``) whose key sets are extracted from the same
+  layer's ``COUNTER_KEYS`` and ``LEVEL_KEYS``, ``"ssm." + key`` over the
+  state-space mixer's) whose key sets are extracted from the same
   files;
 - **spans, code side**: every span name recorded through ``core/trace.py``
   — the second argument of ``trace.record(...)`` / ``trace_lib.record``
@@ -71,6 +72,11 @@ _DYNAMIC = [
      re.compile(r"COUNTER_KEYS = \(([^)]*)\)", re.S)),
     # ... and the levels kept beside them (a bias-balanced router)
     ("parallel/moe.py", "moe.",
+     re.compile(r"LEVEL_KEYS = \(([^)]*)\)", re.S)),
+    # the state-space mixer's counters and levels, the same way
+    ("nn/state_space.py", "ssm.",
+     re.compile(r"COUNTER_KEYS = \(([^)]*)\)", re.S)),
+    ("nn/state_space.py", "ssm.",
      re.compile(r"LEVEL_KEYS = \(([^)]*)\)", re.S)),
 ]
 

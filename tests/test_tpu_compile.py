@@ -339,13 +339,14 @@ def _flash_takes_the_chips_branch(monkeypatch):
     chip, the compiled kernels."""
     monkeypatch.setattr(
         fa, "_flash_fwd_dispatch",
-        lambda q, k, v, causal, bq, bk, window=None: fa._padded_pallas(
-            q, k, v, q.shape[-1] ** -0.5, causal, bq, bk, interpret=False,
-            window=window))
+        lambda q, k, v, causal, bq, bk, window=None, scale=None:
+        fa._padded_pallas(q, k, v, fa._softmax_scale(scale, q.shape[-1]),
+                          causal, bq, bk, interpret=False, window=window))
     monkeypatch.setattr(
         fa, "_flash_bwd_dispatch",
-        lambda q, k, v, o, lse, g, causal, bk, window=None:
-        fa._padded_pallas_bwd(q, k, v, o, lse, g, q.shape[-1] ** -0.5,
+        lambda q, k, v, o, lse, g, causal, bk, window=None, scale=None:
+        fa._padded_pallas_bwd(q, k, v, o, lse, g,
+                              fa._softmax_scale(scale, q.shape[-1]),
                               causal, interpret=False, window=window))
 
 
@@ -388,6 +389,14 @@ def test_qwen3next_cell_train_step_compiles_for_one_chip(topo, monkeypatch):
     held = sum(int(np.prod(l.shape)) for l in
                jax.tree_util.tree_leaves(est._ts["params"]))
     assert held == 625_667_136
+    # the program PR 32 left, to the byte (PR 33 compiled parent and change):
+    # a change to code this cell shares (``MultiHeadAttention``, the flash
+    # kernels' wrapper, ``CausalConv1D``) that means to leave it alone
+    # shows it here
+    cost = compiled.cost_analysis()
+    assert int(cost["flops"]) == 25_031_698_546_688
+    assert int(cost["bytes accessed"]) == 340_933_083_136
+    assert compiled.memory_analysis().temp_size_in_bytes == 5_699_695_616
 
 
 def test_trinity_mini_cell_train_step_compiles_for_one_chip(topo,
@@ -426,3 +435,38 @@ def test_trinity_mini_cell_train_step_compiles_for_one_chip(topo,
     held = sum(int(np.prod(l.shape)) for l in
                jax.tree_util.tree_leaves(est._ts["params"]))
     assert held == 705_473_792
+
+
+@pytest.mark.slow   # two minutes of compiling: a builder's tool, outside tier-1
+def test_granite_cell_train_step_compiles_for_one_chip(topo, monkeypatch):
+    """``granite4h_micro_fit_s8192``'s train step at its real sizes (772.2 M
+    parameters with AdamW's moments, one row of 8,192 tokens): the chip's
+    compiler takes it inside the chip's memory (11.6 GB by its own count),
+    with the flash kernels (forward, backward) once for the one attention
+    layer, at heads of 64 padded to the 128 lanes, and the chunked scan's
+    [32 chunks, 64 heads, 256, 256] terms as XLA's own ops (no kernel is
+    asked for the scan)."""
+    import json
+    from analytics_zoo_tpu.orca.learn import Estimator
+    from benchmark.families import granite_hybrid
+    _flash_takes_the_chips_branch(monkeypatch)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark/configs/granite_4_0_h_micro_pp4.json")) as f:
+        config = json.load(f)
+    est = Estimator.from_keras(
+        granite_hybrid.build(config), loss=config["loss"],
+        optimizer=config["optimizer"]["name"],
+        learning_rate=config["optimizer"]["learning_rate"])
+    ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    compiled = _abstract_train_step(est, mesh, ids, ids).compile()
+    assert _per_chip_bytes(compiled) < HBM_BYTES
+    text = compiled.as_text()
+    assert len(re.findall(r"%(flash_attention_fwd[.\d]*) = ", text)) == 1
+    assert len(re.findall(r"%(flash_attention_bwd[.\d]*) = ", text)) == 1
+    assert re.search(r"bf16\[32,8192,128\]", text)      # 64 padded to 128
+    assert re.search(r"bf16\[32,64,256,256\]", text)    # the chunks' terms
+    held = sum(int(np.prod(l.shape)) for l in
+               jax.tree_util.tree_leaves(est._ts["params"]))
+    assert held == 772_160_448
