@@ -1,34 +1,42 @@
-"""Flash attention: Pallas TPU kernel with online softmax.
+"""Flash attention: Pallas TPU kernels with online softmax.
 
 Reference (SURVEY.md §2.3/§5.7): the reference's attention was the Scala
 Keras-zoo TransformerLayer/BERT self-attention — plain materialized-logits
-attention on CPU (seq<=512).  TPU-native redesign: a blocked kernel that never
-materializes the [Tq, Tk] logits matrix in HBM — running max/sum ("online
-softmax") accumulate per q-block while k/v blocks stream through VMEM, so
-memory is O(T·D) and the two matmuls per block tile onto the MXU.
+attention on CPU (seq<=512).  TPU-native redesign: blocked kernels that never
+materialize the [Tq, Tk] logits matrix in HBM, so memory is O(T·D).
 
-Backward pass: `jax.custom_vjp` whose residuals are just (q, k, v, out, lse).
-One more kernel (``flash_attention_bwd``; ``flash_attention_window_bwd`` on
-a band) recomputes a tile's probabilities from q, k and lse — the standard
-flash-attention-2 trade, extra FLOPs for O(T) memory — and walks the (key
-block, query block) tiles that hold a visible pair, key block outermost:
-the triangle when causal, the band with a ``window``, the tile list read by
-the index maps (``_bwd_tiles``), so a tile outside is neither fetched nor
-computed and only the tiles an edge crosses build a mask.  Scores,
-probabilities, dp and ds of a tile exist only in VMEM; dk and dv of the key
-block and dq of the whole head accumulate in float32 scratch and reach HBM
-once.  Five matmuls a tile, on operands in the inputs' dtype (p and ds cast
-to it, as XLA:TPU's default precision rounds the operands of the
-``jax.numpy`` form's float32 einsums to bf16); s, exp, lse, delta and the
-accumulators are float32.
+Forward (``flash_attention_fwd``; ``flash_attention_window_fwd`` on a band)
+and backward (``flash_attention_bwd``; ``flash_attention_window_bwd``) walk
+a flat list of the tiles that hold a visible pair: every tile when not
+causal, the triangle when causal, the band with a ``window``.  The lists
+(``_fwd_tiles``: query block outermost; ``_bwd_tiles``: key block outermost)
+are read by the index maps from scalar-prefetch tables, so a tile outside is
+neither stepped over nor fetched, and only the tiles an edge crosses (the
+diagonal, the band's far edge, padded keys) build a mask.  Tiles are large
+(a grid step costs what a 256 x 256 tile's products do) and come from the
+shapes (``_fwd_blocks``, ``_bwd_blocks``).  Both hold a tile's scores
+TRANSPOSED, ``[keys, queries]``: what is one number a query (the forward's
+running max and sum, the backward's lse and delta) is then a row along the
+lanes, reduced and broadcast along sublanes.
+
+Forward: running max/sum ("online softmax") accumulate per query block in
+VMEM scratch while its key blocks stream through, the accumulator as
+``out^T`` turned once where the block is written; a tile's keys are walked
+in chunks, two matmuls a chunk.  Backward: `jax.custom_vjp` whose residuals
+are just (q, k, v, out, lse); one kernel recomputes a tile's probabilities
+from q, k and lse — the standard flash-attention-2 trade, extra FLOPs for
+O(T) memory; scores, probabilities, dp and ds of a tile exist only in VMEM;
+dk and dv of the key block and dq of the whole head accumulate in float32
+scratch and reach HBM once; five matmuls a tile.  In both, the matmuls'
+operands are in the inputs' dtype (p and ds cast to it, as XLA:TPU's default
+precision rounds the operands of the ``jax.numpy`` forms' float32 einsums to
+bf16); s, exp, the statistics and the accumulators are float32.
 
 ``window`` (causal only) is sliding-window attention: position i sees the
 ``window`` keys ``i - window + 1 .. i``.  Every path then visits the blocks
 that intersect that band and no other, so the work is ``T * window`` and not
-``T^2``: the forward kernel's k axis covers the band's blocks of a query
-block (its own op name, ``flash_attention_window_fwd``), the backward
-kernel's tile list holds the band's tiles, and the blocked forms slice the
-band out of k (forward) or out of q (backward) block by block.
+``T^2``: the kernels' tile lists hold the band's tiles, and the blocked forms
+slice the band out of k (forward) or out of q (backward) block by block.
 
 On platform ``tpu`` forward and backward are always the compiled kernels (or
 the compiler's error).  On other backends they are the same math blocked in
@@ -60,156 +68,203 @@ def _ceil_to(x: int, m: int) -> int:
 # Pallas forward kernel
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, scale: float, causal: bool,
-                block_q: int, block_k: int, seq_k: int,
-                window: Optional[int] = None):
-    """Grid = (BH, Tq/bq, Tk/bk); k-block is the innermost (sequential) axis,
-    so VMEM scratch carries the online-softmax state across k blocks.  With
-    a ``window`` the innermost axis walks the band's k blocks only (see
-    ``_band_block``): ``ki`` is then the k block this step was given."""
-    qi = pl.program_id(1)
-    step = pl.program_id(2)
-    nk = pl.num_programs(2)
-    ki = step if window is None else _band_block(qi, step, nk, block_q,
-                                                 block_k)
+# what a tile of a walk has to do besides its products; the forward's walk
+# opens and closes a QUERY block, the backward's a KEY block
+_FIRST_OF_Q = _FIRST_OF_K = 1
+_LAST_OF_Q = _LAST_OF_K = 2
+_MASKED = 4
 
-    @pl.when(step == 0)
-    def _init():
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
+
+
+def _fwd_tiles(tq, tk, block_q, block_k, true_tk, causal, window):
+    """The (query block, key block) tiles the forward visits, query block
+    outermost, as three int32 arrays the kernel's index maps read: query
+    block, key block, flags.  Not causal: every key block; causal: those up
+    to the diagonal (the triangle); with a ``window`` the band's alone.
+    ``_MASKED`` is set only where a tile holds a hidden pair too: the
+    diagonal crosses it, the band's far edge does, or it holds padded keys.
+    ``tq`` and ``tk`` are the padded lengths (a padded query row counts as a
+    row: it is computed and sliced off).  Every query block has a tile: key
+    block 0 unless a ``window`` hides it, then the diagonal's."""
+    qis, kjs, flags = [], [], []
+    for i in range(tq // block_q):
+        q0, q1 = i * block_q, i * block_q + block_q - 1
+        seen = []
+        for j in range(tk // block_k):
+            k0, k1 = j * block_k, j * block_k + block_k - 1
+            if causal and k0 > q1:
+                break
+            if window is not None and q0 - k1 >= window:
+                continue
+            masked = (k1 >= true_tk or (causal and k1 > q0)
+                      or (window is not None and q1 - k0 >= window))
+            seen.append((j, _MASKED if masked else 0))
+        for n, (j, flag) in enumerate(seen):
+            qis.append(i)
+            kjs.append(j)
+            flags.append(flag | (_FIRST_OF_Q if n == 0 else 0)
+                         | (_LAST_OF_Q if n == len(seen) - 1 else 0))
+    return tuple(np.asarray(a, np.int32) for a in (qis, kjs, flags))
+
+
+def _fwd_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, o_ref,
+                lse_ref, m_scr, l_scr, acc_scr, *, scale: float,
+                causal: bool, block_q: int, block_k: int, chunk: int,
+                seq_k: Optional[int], window: Optional[int]):
+    """Grid = (BH, tiles): a head's tiles in the order of ``_fwd_tiles``.
+    ``seq_k`` is the unpadded key length where keys were padded, else None.
+    Scores are held transposed, ``[keys, block_q]``, as the backward holds
+    them: the online softmax's running max and sum are then rows (lanes),
+    reduced and broadcast along sublanes, and the accumulator is ``out^T``
+    of ``[d, block_q]``, rescaled by a row and turned once, where the query
+    block is written.  A tile's keys are walked ``chunk`` at a time, each
+    chunk one step of the online softmax: with 128 keys a chunk both
+    products hold one MXU tile of k (of v) and stream the query block past
+    it, and a chunk's ``exp`` runs beside the next one's products."""
+    t = pl.program_id(1)
+    qi, kj, flag = qi_ref[t], kj_ref[t], flag_ref[t]
+
+    @pl.when(flag & _FIRST_OF_Q != 0)
+    def _new_query_block():
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def _body():
-        q = q_ref[0].astype(jnp.float32)          # [bq, d]
-        k = k_ref[0].astype(jnp.float32)          # [bk, d]
-        v = v_ref[0].astype(jnp.float32)          # [bk, d]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        # mask out k positions beyond the (padded) true sequence length
-        kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = kpos < seq_k
-        if causal:
-            qpos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            mask = mask & (qpos >= kpos)
-            if window is not None:
-                mask = mask & (qpos - kpos < window)
-        s = jnp.where(mask, s, _NEG_INF)
+    def tile(masked: bool):
+        q = q_ref[0]                               # [bq, d]
+        for first in range(0, block_k, chunk):
+            k = k_ref[0, first:first + chunk, :]   # [chunk, d]
+            v = v_ref[0, first:first + chunk, :]
+            s = jax.lax.dot_general(
+                k, q, _NT, preferred_element_type=jnp.float32) * scale
+            if masked:
+                key = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                key0 = kj * block_k + first
+                seen = []                  # what a visible pair has to meet
+                if causal:
+                    # how far the query lies ahead of the key: the two
+                    # positions inside the tile and one scalar
+                    ahead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                             - key + (qi * block_q - key0))
+                    seen.append(ahead >= 0)
+                    if window is not None:
+                        seen.append(ahead < window)
+                if seq_k is not None:
+                    seen.append(key < seq_k - key0)
+                if seen:
+                    s = jnp.where(functools.reduce(jnp.logical_and, seen), s,
+                                  _NEG_INF)
+            m_prev = m_scr[...]                    # [1, bq]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)                 # [chunk, bq]
+            l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=0,
+                                                      keepdims=True)
+            acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+                v, p.astype(v.dtype), _TN, preferred_element_type=jnp.float32)
+            m_scr[...] = m_new
 
-        m_prev = m_scr[...]                        # [bq, 1] broadcast lanes
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
-        l_scr[...] = l_new
+    pl.when(flag & _MASKED != 0)(lambda: tile(True))
+    pl.when(flag & _MASKED == 0)(lambda: tile(False))
 
-    if window is not None:
-        # the band's first blocks of the first query blocks lie before the
-        # sequence (their index map clamps to block 0: nothing is fetched),
-        # and a block may end before the band's first key
-        @pl.when((ki >= 0) & (ki * block_k < seq_k)
-                 & (ki * block_k + block_k - 1 > qi * block_q - window))
-        def _run():
-            _body()
-    elif causal:
-        # whole block strictly above the diagonal: nothing to do
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _run():
-            _body()
-    else:
-        _body()
-
-    @pl.when(step == nk - 1)
-    def _finish():
+    @pl.when(flag & _LAST_OF_Q != 0)
+    def _write_query_block():
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_scr[...] + jnp.log(denom))[:, 0]
-
-
-def _band_blocks(window: int, block_q: int, block_k: int) -> int:
-    """k blocks a query block's band can touch: the keys ``first row -
-    window + 1 .. last row``, ``block_q + window - 1`` of them, ending at a
-    block's last row when ``block_k`` divides ``block_q`` and anywhere
-    inside a block otherwise."""
-    span = block_q + window - 1
-    return -(-span // block_k) + (1 if block_q % block_k else 0)
-
-
-def _band_block(qi, step, steps: int, block_q: int, block_k: int):
-    """The k block of step ``step`` of query block ``qi``: the last step is
-    the block that holds the query block's last row (the diagonal), the
-    others the ones before it; negative before the sequence's start."""
-    return (qi * block_q + block_q - 1) // block_k - (steps - 1) + step
+        o_ref[0] = (acc_scr[...] / denom).T.astype(o_ref.dtype)
+        lse_ref[0] = m_scr[...] + jnp.log(denom)
 
 
 @functools.lru_cache(maxsize=64)
-def _fwd_call(bh, tq, tk, d, dtype, scale, causal, block_q, block_k, true_tk,
-              interpret, window):
+def _fwd_call(bh, tq, tk, d, dtype, scale, causal, block_q, block_k, chunk,
+              true_tk, interpret, window):
     """The forward ``pallas_call`` over q, k, v of ``[BH, T, D]`` (D padded
     to 128, T padded to block; ``true_tk`` is the unpadded key length:
-    padded key positions are masked out): built once for its sizes, so that
-    the layers of a model (and its ``init``, ``predict`` and train-step
-    programs) trace the kernel once between them."""
+    padded key positions are masked out), giving out and lse of ``[BH, 1,
+    Tq]``: built once for its sizes, so that the layers of a model (and its
+    ``init``, ``predict`` and train-step programs) trace the kernel once
+    between them.  A chunk's scores and probabilities are float32 terms of
+    ``[chunk, block_q]`` (1 MB each at 128 x 2,048, 4 MB where a caller's
+    key block of 1,024 is walked whole against 1,024 queries), so the limit
+    is raised to what the sizes need."""
+    tiles = _fwd_tiles(tq, tk, block_q, block_k, true_tk, causal, window)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k,
-                               seq_k=true_tk, window=window)
-    if window is None:
-        steps = tk // block_k
+                               block_q=block_q, block_k=block_k, chunk=chunk,
+                               seq_k=true_tk if true_tk < tk else None,
+                               window=window)
 
-        def k_block(b, i, j):
-            return (b, j, 0)
-    else:
-        # the innermost axis is the band's blocks, not the row's: a block
-        # outside the band is neither fetched nor computed
-        steps = min(_band_blocks(window, block_q, block_k), tk // block_k)
+    def q_block(b, t, qi, kj, flag):
+        return (b, qi[t], 0)
 
-        def k_block(b, i, j):
-            return (b, jnp.clip(_band_block(i, j, steps, block_q, block_k),
-                                0, tk // block_k - 1), 0)
-    return pl.pallas_call(
+    def k_block(b, t, qi, kj, flag):
+        return (b, kj[t], 0)
+
+    def q_row(b, t, qi, kj, flag):
+        return (b, 0, qi[t])
+
+    size = jnp.dtype(dtype).itemsize
+    # the streamed blocks and the output's, twice; out^T and its turned
+    # copy; a handful of [chunk, block_q] float32 terms
+    vmem = (4 * (block_q + block_k) * d * size + 2 * block_q * d * 4
+            + 6 * block_q * chunk * 4)
+    call = pl.pallas_call(
         kernel,
-        grid=(bh, tq // block_q, steps),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), k_block),
-            pl.BlockSpec((1, block_k, d), k_block),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            # lse as [BH, 1, T]: block (1, 1, bq) satisfies the TPU (8, 128)
-            # tile rule (sublane dim == full array dim, lane dim % 128 == 0)
-            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(bh, len(tiles[0])),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), q_block),
+                pl.BlockSpec((1, block_k, d), k_block),
+                pl.BlockSpec((1, block_k, d), k_block),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, d), q_block),
+                # lse as [BH, 1, T]: a row, as the kernel holds it
+                pl.BlockSpec((1, 1, block_q), q_row),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((1, block_q), jnp.float32),
+                pltpu.VMEM((1, block_q), jnp.float32),
+                pltpu.VMEM((d, block_q), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((bh, tq, d), dtype),
             jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem + (16 << 20)),
         interpret=interpret,
         # the op's name in HLO and in a profile: a trace tells a windowed
         # layer's kernel from a full one's
         name=("flash_attention_fwd" if window is None
               else "flash_attention_window_fwd"),
     )
+    return functools.partial(call, *tiles)
+
+
+def _fwd_blocks(tq: int, tk: int, window: Optional[int]):
+    """(query block, key block, key chunk) of the forward kernel, from the
+    shapes.  A grid step costs what a small tile's products do, so tiles are
+    large.  Where the triangle or the square is walked, 2,048 queries
+    against 1,024 keys in chunks of 128: 16.5 ms at 32 x 16,384 x 128 causal
+    on a v5e, where 1,024 x 1,024 reads 20.5 chunked or whole (a chunk of
+    128 keys pays only with a query block long enough to stream past it),
+    512 x 512 23.8 and 256 x 256 53.3.  On a band, whose edge tiles compute
+    pairs outside it, 512 x 512 whole: a band of 2,048 reads 7.6 ms (8.9 in
+    chunks; 7.4 at 1,024 x 1,024, 9.0 at 2,048 x 512), and a narrower band
+    wastes less.  Multiples of the 128 lanes; a shorter sequence is one
+    block.  The head's width decides nothing: 128 and 256 order the sizes
+    alike."""
+    bq, bk = (2048, 1024) if window is None else (512, 512)
+    bq, bk = min(bq, _ceil_to(tq, 128)), min(bk, _ceil_to(tk, 128))
+    return bq, bk, 128 if window is None else bk
 
 
 # ---------------------------------------------------------------------------
 # Pallas backward kernel
 # ---------------------------------------------------------------------------
-
-# what a tile of the backward's walk has to do besides its products
-_FIRST_OF_K, _LAST_OF_K, _MASKED = 1, 2, 4
-
 
 def _bwd_tiles(tq, tk, block_q, block_k, true_tk, causal, window):
     """The (key block, query block) tiles the backward visits, key block
@@ -240,10 +295,6 @@ def _bwd_tiles(tq, tk, block_q, block_k, true_tk, causal, window):
             flags.append(flag | (_FIRST_OF_K if n == 0 else 0)
                          | (_LAST_OF_K if n == len(seen) - 1 else 0))
     return tuple(np.asarray(a, np.int32) for a in (kjs, qis, flags))
-
-
-_NT = (((1,), (1,)), ((), ()))   # a @ b.T
-_TN = (((0,), (0,)), ((), ()))   # a.T @ b
 
 
 def _bwd_kernel(kj_ref, qi_ref, flag_ref, q_ref, k_ref, v_ref, g_ref,
@@ -663,37 +714,47 @@ def _softmax_scale(scale: Optional[float], d: int) -> float:
     return 1.0 / (d ** 0.5) if scale is None else float(scale)
 
 
+def _jax_block(block_k: Optional[int], tk: int) -> int:
+    """The k block of the ``jax.numpy`` forms: the caller's, else 256."""
+    return min(block_k or 256, tk)
+
+
 def _flash_fwd_dispatch(q3, k3, v3, causal, block_q, block_k, window=None,
                         scale=None):
     scale = _softmax_scale(scale, q3.shape[-1])
-    if jax.default_backend() == "tpu":
-        # the compiled kernel or the compiler's error: no interpret mode,
-        # no pure-JAX stand-in on the device the kernel was written for
+    if jax.default_backend() == "tpu" or INTERPRET:
+        # on the chip the compiled kernel or the compiler's error: no
+        # interpret mode, no pure-JAX stand-in on the device the kernel was
+        # written for
         return _padded_pallas(q3, k3, v3, scale, causal, block_q, block_k,
-                              interpret=False, window=window)
-    if INTERPRET:
-        return _padded_pallas(q3, k3, v3, scale, causal, block_q, block_k,
-                              interpret=True, window=window)
+                              interpret=jax.default_backend() != "tpu",
+                              window=window)
     if window is not None:
         return _band_fwd_jax(q3, k3, v3, scale, window,
-                             min(block_k, k3.shape[1]))
+                             _jax_block(block_k, k3.shape[1]))
     return _blocked_fwd_jax(q3, k3, v3, scale, causal,
-                            min(block_k, k3.shape[1]))
+                            _jax_block(block_k, k3.shape[1]))
 
 
 def _padded_pallas(q3, k3, v3, scale, causal, block_q, block_k, interpret,
                    window=None):
-    """Pad T to block multiples and D to the 128-lane tile, run the kernel."""
+    """The forward kernel on q, k, v of ``[BH, T, D]``: T padded to the
+    blocks (the caller's, else ``_fwd_blocks``'; padded keys are masked,
+    padded queries sliced off), D to the 128-lane tile."""
     bh, tq, d = q3.shape
     tk = k3.shape[1]
-    bq = min(block_q, _ceil_to(tq, 8))
-    bk = min(block_k, _ceil_to(tk, 8))
+    bq, bk, chunk = _fwd_blocks(tq, tk, window)
+    if block_q is not None:
+        bq = min(block_q, _ceil_to(tq, 8))
+    if block_k is not None:
+        bk = chunk = min(block_k, _ceil_to(tk, 8))
     tq_p, tk_p, d_p = _ceil_to(tq, bq), _ceil_to(tk, bk), _ceil_to(d, 128)
     qp = jnp.pad(q3, ((0, 0), (0, tq_p - tq), (0, d_p - d)))
     kp = jnp.pad(k3, ((0, 0), (0, tk_p - tk), (0, d_p - d)))
     vp = jnp.pad(v3, ((0, 0), (0, tk_p - tk), (0, d_p - d)))
     out, lse = _fwd_call(bh, tq_p, tk_p, d_p, jnp.dtype(q3.dtype), scale,
-                         causal, bq, bk, tk, interpret, window)(qp, kp, vp)
+                         causal, bq, bk, chunk, tk, interpret, window)(
+                             qp, kp, vp)
     return out[:, :tq, :d], lse[:, 0, :tq]
 
 
@@ -721,9 +782,9 @@ def _flash_bwd_dispatch(q3, k3, v3, out, lse, g, causal, block_k,
                                   window=window)
     if window is not None:
         return _band_bwd_jax(q3, k3, v3, out, lse, g, scale, window,
-                             min(block_k, k3.shape[1]))
+                             _jax_block(block_k, k3.shape[1]))
     return _blocked_bwd_jax(q3, k3, v3, out, lse, g, scale, causal,
-                            min(block_k, k3.shape[1]))
+                            _jax_block(block_k, k3.shape[1]))
 
 
 def _flash_vjp_bwd(causal, block_q, block_k, window, scale, res, g):
@@ -734,8 +795,8 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    causal: bool = False, block_q: int = 256,
-                    block_k: int = 256,
+                    causal: bool = False, block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     window: Optional[int] = None,
                     scale: Optional[float] = None) -> jax.Array:
     """Flash attention over [B, T, H, D] tensors: ``softmax(scale * q k^T)
@@ -744,12 +805,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     forms alike take it as a static number).
 
     Differentiable; O(T·D) memory.  Matches :func:`mha_reference` to fp
-    tolerance (see tests/test_ops.py).  ``block_q`` and ``block_k`` are the
-    forward's (and the ``jax.numpy`` backward's k block); the backward
-    kernel takes its blocks from the shapes.  ``window`` (causal
-    self-attention only): position i sees keys ``i - window + 1 .. i``; forward and
-    backward then visit the band's blocks alone.  A window that covers the
-    row is plain causal attention and takes its path.
+    tolerance (see tests/test_ops.py).  ``block_q`` and ``block_k`` are left
+    at None: both kernels take their blocks from the shapes (``_fwd_blocks``,
+    ``_bwd_blocks``).  A value is honoured by the forward kernel (on the
+    chip a multiple of the 128 lanes for ``block_q``, of 16 sublanes for
+    ``block_k``) and, ``block_k``, by the ``jax.numpy`` forms that stand in
+    off the chip (256 when None).  ``window`` (causal self-attention only):
+    position i sees keys ``i - window + 1 .. i``; forward and backward then
+    visit the band's tiles alone.  A window that covers the row is plain
+    causal attention and takes its path.
     """
     b, tq, h, d = q.shape
     tk = k.shape[1]

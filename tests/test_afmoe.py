@@ -114,17 +114,26 @@ def test_a_window_that_covers_the_row_is_plain_causal_attention(window):
                          - mha_reference(q, k, v, causal=True)).max()) == 0.0
 
 
-def test_the_band_is_what_the_kernel_walks():
-    """A query block's band at blocks of 256 and a window of 2048: nine k
-    blocks, against the row's 64 at 16,384 tokens."""
-    assert fa._band_blocks(2048, 256, 256) == 9
-    assert fa._band_blocks(16, 16, 16) == 2
-    assert fa._band_blocks(19, 32, 16) == 4      # 50 keys, ending on a block
-    assert fa._band_blocks(19, 16, 32) == 3      # 34 keys, ending inside one
-    # the last step is the diagonal's block, the first may lie before key 0
-    assert [int(fa._band_block(0, s, 9, 256, 256)) for s in (0, 8)] == [-8, 0]
-    assert [int(fa._band_block(63, s, 9, 256, 256)) for s in (0, 8)] \
-        == [55, 63]
+def test_the_band_is_what_the_forward_walks():
+    """A sliding layer of the cell, 16,384 rows and a window of 2,048 in the
+    blocks the shapes give (512 x 512): a query block walks its own key
+    block and the four before it, 150 tiles a head where the rectangular
+    grid stepped over 32 x 32, and only the diagonal's tile and the far
+    edge's build a mask."""
+    assert fa._fwd_blocks(16384, 16384, 2048) == (512, 512, 512)
+    qi, kj, flag = fa._fwd_tiles(16384, 16384, 512, 512, 16384, True, 2048)
+    assert len(qi) == 150
+    for i in range(32):
+        assert list(kj[qi == i]) == list(range(max(0, i - 4), i + 1))
+    assert [bool(f & fa._MASKED) for f in flag] \
+        == [j in (i, i - 4) for i, j in zip(qi, kj)]
+    # 70 rows, a window of 19, blocks of 32 x 16 (rows padded to 96, keys
+    # to 80): rows 32..63 see keys 14..63, rows 64..95 keys 46..79
+    qi, kj, flag = fa._fwd_tiles(96, 80, 32, 16, 70, True, 19)
+    assert [list(kj[qi == i]) for i in range(3)] \
+        == [[0, 1], [0, 1, 2, 3], [2, 3, 4]]
+    # ... and every one of those tiles is crossed by an edge or by padding
+    assert all(f & fa._MASKED for f in flag)
 
 
 @pytest.mark.parametrize("bad", [dict(causal=False, window=8),

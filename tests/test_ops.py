@@ -79,6 +79,187 @@ def test_pallas_kernel_interpret_causal(rng):
                                atol=2e-5, rtol=2e-5)
 
 
+# -- the forward kernels (flash_attention_fwd / flash_attention_window_fwd) -----
+#
+# Self-attention: {not causal, causal, a window of 50} x {256 rows: a
+# multiple of every block here; 200: T padded to the block, padded keys
+# masked, padded queries sliced off} x heads of {64, 128, 256} x {blocks
+# from the shapes; explicit and unequal}.  Then Tq != Tk (blocks unequal the
+# other way among them), a multiplier of the model's own, and rows long
+# enough for blocks from the shapes to make several tiles (2,048 x 1,024 on
+# the triangle or square, 512 x 512 on a band).
+# name: tq, tk, d, causal, window, (block_q, block_k), scale
+FWD_CASES = {
+    f"{mode}_t{t}_d{d}_{'x'.join(map(str, blocks)) if blocks else 'shapes'}":
+        (t, t, d, mode != "full", 50 if mode == "window" else None, blocks,
+         None)
+    for mode in ("full", "causal", "window") for t in (256, 200)
+    for d in (64, 128, 256) for blocks in (None, (64, 32))}
+FWD_CASES.update({
+    "cross_full_tk_gt_tq": (200, 700, 64, False, None, None, None),
+    "cross_full_tk_gt_tq_64x32": (200, 700, 64, False, None, (64, 32), None),
+    "cross_full_tq_gt_tk": (700, 200, 64, False, None, None, None),
+    "cross_full_tq_gt_tk_32x64": (700, 200, 64, False, None, (32, 64), None),
+    "cross_causal_tk_gt_tq": (200, 700, 64, True, None, None, None),
+    "cross_causal_tk_gt_tq_64x32": (200, 700, 64, True, None, (64, 32), None),
+    "scale_of_the_models_own_d64": (200, 200, 64, True, None, None, 1 / 64),
+    "scale_with_a_window_64x32": (256, 256, 64, True, 50, (64, 32), 0.3),
+    "triangle_of_three_blocks": (2500, 2500, 64, True, None, None, None),
+    "square_of_two_by_three": (1100, 2100, 64, False, None, None, None),
+    "band_of_600_in_blocks_of_512": (2048, 2048, 128, True, 600, None, None),
+    "band_narrower_than_a_block": (1100, 1100, 64, True, 100, None, None),
+})
+
+
+def _lse_reference(q, k, causal, window, scale):
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (
+        q.shape[-1] ** -0.5 if scale is None else scale)
+    if causal:
+        seen = jnp.arange(q.shape[1])[:, None] - jnp.arange(k.shape[1])
+        s = jnp.where((seen >= 0) if window is None
+                      else (seen >= 0) & (seen < window), s, -jnp.inf)
+    lse = jax.scipy.special.logsumexp(s, axis=-1)        # [B, H, Tq]
+    return lse.reshape(-1, q.shape[1])
+
+
+@pytest.mark.parametrize("case", list(FWD_CASES))
+def test_forward_kernel_matches_reference_and_blocked_form(rng, case,
+                                                           monkeypatch):
+    """The Pallas forward (interpret mode), out AND lse (the backward reads
+    it), against ``mha_reference`` and against the ``jax.numpy`` form that
+    stands in for it off the chip."""
+    tq, tk, d, causal, window, blocks, scale = FWD_CASES[case]
+    q, k, v, _ = _bwd_inputs(rng, tq, tk, d, jnp.float32)
+    bq, bk = blocks or (None, None)
+    want = mha_reference(q, k, v, causal=causal, window=window, scale=scale)
+    want_lse = _lse_reference(q, k, causal, window, scale)
+
+    def run(interpret):
+        monkeypatch.setattr(fa_mod, "INTERPRET", interpret)
+        q3, k3, v3 = (a.transpose(0, 2, 1, 3).reshape(-1, a.shape[1], d)
+                      for a in (q, k, v))
+        out, lse = fa_mod._flash_fwd_dispatch(q3, k3, v3, causal, bq, bk,
+                                              window, scale)
+        assert out.shape == (2, tq, d) and lse.shape == (2, tq)
+        assert lse.dtype == jnp.float32
+        return out.reshape(1, 2, tq, d).transpose(0, 2, 1, 3), lse
+
+    for out, lse in (run(True), run(False)):
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   atol=5e-6, rtol=5e-6)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                                   atol=5e-6, rtol=5e-6)
+    # the public entry takes the same blocks (None: from the shapes)
+    got = flash_attention(q, k, v, causal=causal, window=window, scale=scale,
+                          block_q=bq, block_k=bk)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=5e-6, rtol=5e-6)
+
+
+def test_forward_kernel_keeps_bf16_operands_and_float32_statistics(
+        rng, monkeypatch):
+    """bf16 q, k, v go to the MXU as they are (p cast to v's dtype for the
+    second product); out comes back bf16 within its rounding, lse float32
+    and as close as float32 operands leave it."""
+    q, k, v, _ = _bwd_inputs(rng, 1024, 1024, 128, jnp.bfloat16)
+    monkeypatch.setattr(fa_mod, "INTERPRET", True)
+    q3, k3, v3 = (a.transpose(0, 2, 1, 3).reshape(2, 1024, 128)
+                  for a in (q, k, v))
+    out, lse = fa_mod._flash_fwd_dispatch(q3, k3, v3, True, None, None)
+    assert out.dtype == jnp.bfloat16 and lse.dtype == jnp.float32
+    f32 = [a.astype(jnp.float32) for a in (q, k, v)]
+    want = mha_reference(*f32, causal=True).transpose(0, 2, 1, 3)[0]
+    assert float(jnp.abs(out.astype(jnp.float32) - want).max()) \
+        <= 2 ** -8 * float(jnp.abs(want).max()) + 2e-3
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(_lse_reference(*f32[:2], True, None,
+                                                   None)), atol=1e-5)
+
+
+# (tq, tk, block_q, block_k, true_tk, causal, window): small enough to count
+# every pair; rows and windows that are no multiple of a block, unequal
+# blocks both ways, Tq != Tk both ways, padded keys
+TILE_CASES = [
+    (64, 64, 16, 16, 64, False, None), (64, 64, 16, 16, 64, True, None),
+    (64, 64, 16, 16, 64, True, 16), (64, 64, 16, 16, 64, True, 19),
+    (96, 96, 32, 16, 90, True, 19), (96, 96, 16, 32, 90, True, 19),
+    (96, 96, 32, 16, 90, True, 1), (64, 160, 16, 32, 150, True, None),
+    (64, 160, 16, 32, 150, False, None), (160, 64, 32, 16, 50, False, None),
+    (128, 128, 64, 16, 128, True, 40), (128, 128, 16, 64, 128, True, 40),
+]
+
+
+@pytest.mark.parametrize("tq,tk,bq,bk,true_tk,causal,window", TILE_CASES)
+def test_forward_tiles_by_hand(tq, tk, bq, bk, true_tk, causal, window):
+    """``_fwd_tiles`` against every pair counted in numpy: each visible pair
+    lies in exactly one tile, no tile is without one, ``_MASKED`` is set
+    where a tile holds a hidden pair too and nowhere else, and each query
+    block opens and closes once, its tiles together and in key order."""
+    qpos, kpos = np.arange(tq)[:, None], np.arange(tk)[None, :]
+    visible = np.broadcast_to(kpos < true_tk, (tq, tk)).copy()
+    if causal:
+        visible &= kpos <= qpos
+    if window is not None:
+        visible &= qpos - kpos < window
+    qi, kj, flag = fa_mod._fwd_tiles(tq, tk, bq, bk, true_tk, causal, window)
+    assert qi.dtype == kj.dtype == flag.dtype == np.int32
+    covered = np.zeros((tq, tk), int)
+    for i, j, f in zip(qi, kj, flag):
+        tile = visible[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk]
+        assert tile.any(), (i, j)
+        assert bool(f & fa_mod._MASKED) == (not tile.all()), (i, j)
+        covered[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk] += 1
+    assert (covered[visible] == 1).all() and covered.max() == 1
+    assert list(qi) == sorted(qi) and set(qi) == set(range(tq // bq))
+    for i in set(qi):
+        mine, keys = flag[qi == i], kj[qi == i]
+        assert list(keys) == sorted(keys)
+        assert [bool(f & fa_mod._FIRST_OF_Q) for f in mine] \
+            == [n == 0 for n in range(len(mine))]
+        assert [bool(f & fa_mod._LAST_OF_Q) for f in mine] \
+            == [n == len(mine) - 1 for n in range(len(mine))]
+
+
+# What the forward walks at the cells' shapes, blocks from the shapes: tiles a
+# head, how many of them build a mask, pairs computed, pairs visible.  The
+# parent's rectangular grid stepped over 4,096 / 576 / 1,024 blocks of 256 a
+# head and fetched k and v for each.
+CELL_WALKS = {
+    "trinity_full_16384_d128": (16384, None, (2048, 1024, 128),
+                                72, 16, 150_994_944, 134_225_920),
+    "trinity_band_2048_of_16384": (16384, 2048, (512, 512, 512),
+                                   150, 60, 39_321_600, 31_458_304),
+    "qwen_and_granite_8192": (8192, None, (2048, 1024, 128),
+                              20, 8, 41_943_040, 33_558_528),
+}
+
+
+@pytest.mark.parametrize("cell", list(CELL_WALKS))
+def test_forward_walk_at_the_cells_shapes(cell):
+    t, window, blocks, tiles, masked, computed, visible = CELL_WALKS[cell]
+    assert fa_mod._fwd_blocks(t, t, window) == blocks
+    bq, bk, _ = blocks
+    qi, kj, flag = fa_mod._fwd_tiles(t, t, bq, bk, t, True, window)
+    assert len(qi) == tiles
+    assert int(np.count_nonzero(flag & fa_mod._MASKED)) == masked
+    assert tiles * bq * bk == computed
+    assert sum(min(i + 1, window or t) for i in range(t)) == visible
+    # nothing above the diagonal, nothing before the band
+    assert (kj * bk <= qi * bq + bq - 1).all()
+    if window is not None:
+        assert (qi * bq - (kj * bk + bk - 1) < window).all()
+
+
+@pytest.mark.parametrize("tq,tk,window,want", [
+    (200, 200, None, (256, 256, 128)), (200, 700, None, (256, 768, 128)),
+    (1024, 1024, None, (1024, 1024, 128)), (5000, 300, None, (2048, 384, 128)),
+    (200, 200, 50, (256, 256, 256)), (4096, 4096, 1024, (512, 512, 512))])
+def test_forward_blocks_come_from_the_shapes(tq, tk, window, want):
+    """Multiples of the 128 lanes, a short sequence one block, the key
+    chunk a divisor of the key block (the block itself on a band)."""
+    assert fa_mod._fwd_blocks(tq, tk, window) == want
+
+
 # -- the backward kernels (flash_attention_bwd / flash_attention_window_bwd) ---
 #
 # tq, tk, d, causal, window, dtype: causal and not, a band of 2,048 in 4,096
