@@ -14,6 +14,7 @@ Gloo, gRPC, plasma, py4j) collapse into this single compiled plane.
 from __future__ import annotations
 
 import atexit
+import contextlib
 import json
 import logging
 import os
@@ -337,8 +338,28 @@ def stop_orca_context() -> None:
         _HEARTBEAT = None
 
 
+_held = threading.local()  # mesh_scope's mesh, on the thread that holds it
+
+
+@contextlib.contextmanager
+def mesh_scope(mesh: jax.sharding.Mesh):
+    """For the length of the block ``get_mesh()`` answers ``mesh`` on this
+    thread and starts no context: for tracing a program again for the mesh
+    it was built for, after the context it ran under was stopped (the
+    Estimator's ``trace.register_program`` callable)."""
+    before = getattr(_held, "mesh", None)
+    _held.mesh = mesh
+    try:
+        yield mesh
+    finally:
+        _held.mesh = before
+
+
 def get_mesh() -> jax.sharding.Mesh:
     """The global mesh, initializing a local default context if needed."""
+    held = getattr(_held, "mesh", None)
+    if held is not None:
+        return held
     if not OrcaContext.initialized:
         init_orca_context("local")
     return OrcaContext.mesh

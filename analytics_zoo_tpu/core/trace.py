@@ -43,10 +43,12 @@ from __future__ import annotations
 
 import collections
 import logging
+import re
 import threading
 import time
 import uuid
-from typing import Dict, List, Optional
+from typing import (Callable, Dict, FrozenSet, Iterable, List, Optional, Set,
+                    Tuple)
 
 logger = logging.getLogger("analytics_zoo_tpu")
 
@@ -263,6 +265,154 @@ def phase(name: str):
     nothing else in this module needs it."""
     import jax
     return jax.profiler.TraceAnnotation("zoo:" + name)
+
+
+# -- a compiled program's device ops, by the scope that made them -------------
+#
+# ``Scope.child`` and the train step put a module's path on every op they
+# trace (``jax.named_scope``).  The compiled executable's HLO text keeps that
+# path in each instruction's ``op_name``, under the instruction name a device
+# trace prints at the head of the op's event: the text is the symbol table of
+# a profile slice.
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*->.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s")
+_HLO_OPERANDS = re.compile(r"=\s+(?:\(.*?\)|\S+)\s+[\w\-]+\(([^()]*)\)")
+_HLO_NAME = re.compile(r"%([\w.\-]+)")
+_HLO_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+#: JAX's transforms wrap the scope they met: ``transpose(jvp(bert))``
+_SCOPE_TRANSFORM = re.compile(r"^[\w.\-]+\((.*)\)$")
+#: path components that are JAX's own and no module's: a nested ``jit``, the
+#: parts of a loop, a conditional or a checkpoint, an einsum's subscripts
+_SCOPE_WRAPPER = re.compile(
+    r"^(p?jit\(.*\)|pjit|while|body|cond|branch_\d+_fun|closed_call|"
+    r"core_call|checkpoint|rematted_computation|remat2?|"
+    r"custom_(jvp|vjp)_call\w*|.*->.*)$")
+
+OpScopes = Dict[str, Tuple[Optional[str], FrozenSet[str]]]
+
+
+def scope_of_op_name(op_name: str) -> Optional[str]:
+    """The module path in an HLO ``op_name``: JAX's transforms unwrapped,
+    its wrappers and the primitive (the last component) dropped, so that a
+    module's forward, recomputation and backward read alike —
+    ``jit(train_step)/grad_accum/while/body/closed_call/transpose(jvp(bert))/layer_3/mha/dot_general``
+    -> ``grad_accum/bert/layer_3/mha``, ``jit(train_step)/optimizer/add`` ->
+    ``optimizer``, ``jit(train_step)/div`` -> ``""`` (the step's own
+    arithmetic, outside every module).  Of names XLA joined with ``;`` the
+    first counts.  Under ``jax.checkpoint`` the backward's path repeats the
+    forward's (``transpose(jvp(remat_0))/jvp(remat_0)/checkpoint/
+    rematted_computation/moe/...``): a path that repeats is kept once.
+    None for a name that is no path of JAX's (no ``/``): an argument's
+    (``ts['params']['w']``) or one the compiler gave (``ragged-dot-none``)."""
+    first = op_name.split(";")[0]
+    if "/" not in first:
+        return None
+    path: List[str] = []
+    for part in first.split("/")[:-1]:
+        while True:
+            inner = _SCOPE_TRANSFORM.match(part)
+            if inner is None or part.startswith(("jit(", "pjit(")):
+                break
+            part = inner.group(1)
+        if part and not _SCOPE_WRAPPER.match(part):
+            path.append(part)
+    # a run of components that follows itself (layer_0/moe/layer_0/moe/...)
+    n = 1
+    while n <= len(path) // 2:
+        i = next((i for i in range(len(path) - 2 * n + 1)
+                  if path[i:i + n] == path[i + n:i + 2 * n]), None)
+        if i is None:
+            n += 1
+        else:
+            del path[i + n:i + 2 * n]
+    return "/".join(path)
+
+
+def _commonest(scopes: Iterable[Optional[str]]) -> Optional[str]:
+    count = collections.Counter(s for s in scopes if s is not None)
+    return count.most_common(1)[0][0] if count else None
+
+
+def scopes_of_hlo(text: str) -> OpScopes:
+    """``{instruction name: (scope, also)}`` for every instruction of every
+    computation of a compiled program's HLO text (``compiled.as_text()``;
+    names are unique in a module).  ``scope`` is the instruction's own
+    (:func:`scope_of_op_name`).  Where it has none, the compiler made it:
+    a fusion then takes the commonest scope among the instructions of the
+    computation it calls; failing that, any instruction takes the commonest
+    scope among the instructions that feed it (a copy or a convert, or a
+    ``ragged-dot`` kernel, whose ``op_name`` XLA:TPU replaces with its own:
+    it is fed the rows its module gathered); else None.  ``also`` holds the
+    OTHER scopes found in the computation a fusion calls: a weight
+    gradient's matmul that XLA fused with the optimizer's update has one
+    side of the ``optimizer`` boundary in ``scope`` and the other in
+    ``also``.  A pure parser: no JAX."""
+    table: OpScopes = {}
+    inside: Dict[str, List[str]] = {}   # computation -> its instructions
+    current: List[str] = []
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            comp = _HLO_COMPUTATION.match(line)
+            if comp:
+                current = inside.setdefault(comp.group(1), [])
+            continue
+        instr = _HLO_INSTRUCTION.match(line)
+        if instr is None:
+            continue
+        own = _HLO_OP_NAME.search(line)
+        scope = scope_of_op_name(own.group(1)) if own else None
+        also: Set[str] = set()
+        called = _HLO_CALLS.search(line)
+        if called:  # callees are printed before their callers
+            fused = [table[name] for name in
+                     inside.get(called.group(1), ())]
+            also.update(s for s, _ in fused)
+            also.update(*(a for _, a in fused))
+            if scope is None:
+                scope = _commonest(s for s, _ in fused)
+        if scope is None:  # operands are printed before their users
+            fed = _HLO_OPERANDS.search(line)
+            scope = _commonest(
+                table[name][0] for name in
+                _HLO_NAME.findall(fed.group(1) if fed else "")
+                if name in table)
+        also -= {scope, None}
+        table[instr.group(1)] = (scope, frozenset(also))
+        current.append(instr.group(1))
+    return table
+
+
+_programs: Dict[str, Callable[[], str]] = {}
+_program_scopes: Dict[str, OpScopes] = {}
+
+
+def register_program(name: str, hlo_text: Callable[[], str]) -> None:
+    """Name a compiled program of this process by a ZERO-ARGUMENT callable
+    that returns its HLO text.  Nothing is called here: registering costs
+    the closure, and whoever never asks :func:`op_scopes` pays no more.
+    ``Estimator.fit`` registers ``"train_step"``."""
+    with _lock:
+        _programs[name] = hlo_text
+        _program_scopes.pop(name, None)
+
+
+def op_scopes(name: str) -> Optional[OpScopes]:
+    """The table of :func:`scopes_of_hlo` for the program registered as
+    ``name``, worked out on the first call (the callable's lowering and
+    compile, then the parse) and kept; None when no such program was
+    registered."""
+    with _lock:
+        hlo_text = _programs.get(name)
+        table = _program_scopes.get(name)
+    if hlo_text is None or table is not None:
+        return table
+    table = scopes_of_hlo(hlo_text())
+    with _lock:
+        if _programs.get(name) is hlo_text:
+            _program_scopes[name] = table
+    return table
 
 
 def find(trace_id: str) -> List[TraceRecord]:

@@ -143,6 +143,7 @@ class GraniteHybrid(ZooModel):
             # the embedding's own leaf, read a second time: autodiff adds
             # the head's gradient to the gather's
             table = scope.params["embed"]["embeddings"]
-            return jnp.einsum("btd,vd->btv", x, table.astype(x.dtype))
+            with jax.named_scope("head"):  # as the untied head's child is
+                return jnp.einsum("btd,vd->btv", x, table.astype(x.dtype))
         return scope.child(nn.Dense(self.vocab_size, use_bias=False), x,
                            name="head")

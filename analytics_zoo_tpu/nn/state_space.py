@@ -214,11 +214,12 @@ class Mamba2(Module):
         dt = jax.nn.softplus(zxbcdt[..., 2 * inner + 2 * g * n:]
                              .astype(jnp.float32) + dt_bias)
 
-        y, _, stats = _ssd(
-            xbc[..., :inner].reshape(bsz, t, h, p), dt, a_log,
-            xbc[..., inner:inner + g * n].reshape(bsz, t, g, n),
-            xbc[..., inner + g * n:].reshape(bsz, t, g, n), d_skip, None,
-            self.chunk)
+        with jax.named_scope("ssd"):  # the scan, for a profile's readers
+            y, _, stats = _ssd(
+                xbc[..., :inner].reshape(bsz, t, h, p), dt, a_log,
+                xbc[..., inner:inner + g * n].reshape(bsz, t, g, n),
+                xbc[..., inner + g * n:].reshape(bsz, t, g, n), d_skip,
+                None, self.chunk)
         y = y.reshape(bsz, t, inner)
         y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
         y = scope.child(RMSNorm(self.epsilon), y, name="norm")

@@ -41,7 +41,7 @@ from analytics_zoo_tpu.core.config import ZooConfig
 from analytics_zoo_tpu.core import faults as faults_lib
 from analytics_zoo_tpu.core import metrics as telemetry
 from analytics_zoo_tpu.core import trace as trace_lib
-from analytics_zoo_tpu.core.context import heartbeat
+from analytics_zoo_tpu.core.context import heartbeat, mesh_scope
 from analytics_zoo_tpu.core.summary import SummaryWriter
 from analytics_zoo_tpu.data import (EpochEnd, PrefetchIterator, as_feed,
                                     batch_sharding, make_placer,
@@ -56,6 +56,28 @@ logger = logging.getLogger("analytics_zoo_tpu")
 
 #: Valid values for ``ZooEstimator(nan_policy=...)``.
 NAN_POLICIES = ("warn", "skip_step", "rollback", "raise")
+
+
+def _hlo_text_of(step: Any, mesh: Any, *args: Any) -> Callable[[], str]:
+    """A zero-argument callable that returns the compiled HLO text of the
+    jitted ``step`` for ``args`` (``trace_lib.register_program``).  It keeps
+    the function, the mesh the step was built for and the arguments' SHAPES
+    with their shardings, taken here, before the call that donates them —
+    never the arrays.  Called, it lowers and compiles as ``fit()``'s own
+    dispatch did, with that mesh as ``get_mesh()``'s answer (the context
+    may be stopped by then, and none is started); with the persistent
+    compile cache on, the compile is a hit on the executable that ran."""
+    def abstract(leaf: Any) -> jax.ShapeDtypeStruct:
+        placed = isinstance(leaf, jax.Array)   # else a host batch's array
+        return jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, weak_type=placed and leaf.weak_type,
+            sharding=leaf.sharding if placed and leaf.committed else None)
+    shapes = jax.tree_util.tree_map(abstract, args)
+
+    def hlo_text() -> str:
+        with mesh_scope(mesh):
+            return step.lower(*shapes).compile().as_text()
+    return hlo_text
 
 
 def _jit_cache_size(fn: Any) -> int:
@@ -693,9 +715,11 @@ class ZooEstimator:
                 out, new_state = model.apply(
                     {"params": params, "state": state}, xb,
                     training=True, rng=rng)
-                loss = loss_fn(out, yb)
-                # auxiliary losses recorded in state (e.g. MoE load-balance)
-                loss = loss + aux_w * _collect_aux_losses(new_state)
+                with jax.named_scope("loss"):
+                    loss = loss_fn(out, yb)
+                    # auxiliary losses recorded in state (e.g. MoE
+                    # load-balance)
+                    loss = loss + aux_w * _collect_aux_losses(new_state)
                 return loss, new_state
 
             if accum > 1:
@@ -1075,6 +1099,13 @@ class ZooEstimator:
                                 # a counted event
                                 cache_prev = _jit_cache_size(
                                     self._train_step)
+                            # the step's own table of its device ops, for
+                            # whoever asks (trace_lib.op_scopes): a
+                            # closure here, nothing lowered or compiled
+                            trace_lib.register_program(
+                                "train_step", _hlo_text_of(
+                                    self._train_step, mesh, self._ts,
+                                    batch))
                         # liveness beat for the zoo-launch gang
                         # supervisor (no-op unless a heartbeat file is
                         # configured); the payload makes the heartbeat

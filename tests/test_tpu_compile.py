@@ -286,6 +286,14 @@ def _bert_base_step(mesh, global_batch, accum):
     return _abstract_train_step(est, mesh, ids, ids).compile()
 
 
+@pytest.fixture(scope="module")
+def bert_one_chip(topo):
+    """``bert_base_fit_s512``'s train step (batch 32 = micro 16 x accum 2)
+    compiled for one described chip, once for the tests that read it."""
+    return _bert_base_step(Mesh(np.asarray(topo.devices[:1]), ("data",)),
+                           32, 2)
+
+
 def _megabytes(result_type):
     """Bytes of an HLO result type such as ``(f32[768,768]{...}, f32[])``,
     in MB."""
@@ -298,7 +306,8 @@ def _megabytes(result_type):
     return total / 1e6
 
 
-def test_bert_base_dp4_accum_step_computes_a_chips_rows_once(topo):
+def test_bert_base_dp4_accum_step_computes_a_chips_rows_once(topo,
+                                                             bert_one_chip):
     """The ``bert_base_fit_dp4`` cell's train step (BERT-base, seq 512,
     global batch 128 = 4 chips x micro 16 x accum 2, mesh {data: 4}) for
     the described 2x2: it fits a chip in the one-chip cell's memory, the
@@ -315,8 +324,7 @@ def test_bert_base_dp4_accum_step_computes_a_chips_rows_once(topo):
     section 7)."""
     from hlo_loops import collectives
     four = _bert_base_step(Mesh(np.asarray(topo.devices), ("data",)), 128, 2)
-    one = _bert_base_step(Mesh(np.asarray(topo.devices[:1]), ("data",)),
-                          32, 2)
+    one = bert_one_chip
     assert _per_chip_bytes(four) < HBM_BYTES
     assert _per_chip_bytes(four) <= 1.05 * _per_chip_bytes(one)
     ratio = four.cost_analysis()["flops"] / one.cost_analysis()["flops"]
@@ -333,17 +341,46 @@ def test_bert_base_dp4_accum_step_computes_a_chips_rows_once(topo):
     assert collectives(one.as_text()) == ({}, {})
 
 
-def test_bert_base_one_chip_step_is_the_program_pr26_recorded(topo):
+def test_bert_base_one_chip_step_is_the_program_pr26_recorded(bert_one_chip):
     """``bert_base_fit_s512``'s train step, compiled for one described chip:
     the FLOPs and bytes PERF.md records for PR 26, to the byte.  A change to
     code the BERT cells share (``MultiHeadAttention``, ``Dense``, the loss,
     the train step) that means to leave them alone shows it here."""
-    one = _bert_base_step(Mesh(np.asarray(topo.devices[:1]), ("data",)),
-                          32, 2)
-    cost = one.cost_analysis()
+    cost = bert_one_chip.cost_analysis()
     assert int(cost["flops"]) == 5922331557888
     assert int(cost["bytes accessed"]) == 61243039744
-    assert one.memory_analysis().temp_size_in_bytes == 4455141888
+    assert bert_one_chip.memory_analysis().temp_size_in_bytes == 4455141888
+
+
+def test_bert_base_step_names_its_matmuls_by_module(bert_one_chip):
+    """The same executable's text as the program's table of its device ops
+    (``core/trace.py scopes_of_hlo``, PR 35), on real TPU HLO: every
+    ``convolution`` outside a fusion and every fusion that carries one has
+    a scope under a BERT layer, the head, the loss or the optimizer; the
+    optimizer's update and the loss are there under their names."""
+    from analytics_zoo_tpu.core.trace import scopes_of_hlo
+    text = bert_one_chip.as_text()
+    table = scopes_of_hlo(text)
+    carries, matmuls, computation = set(), [], None
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            computation = line.split(" ")[1 if line.startswith("ENTRY")
+                                          else 0].lstrip("%")
+            continue
+        name = re.match(r"\s+(?:ROOT )?%?([\w.\-]+) = ", line)
+        called = re.search(r"calls=%?([\w.\-]+)", line)
+        if name and (" convolution(" in line
+                     or (called and called.group(1) in carries)):
+            carries.add(computation)
+            matmuls.append(name.group(1))
+    assert len(matmuls) > 300, len(matmuls)
+    known = re.compile(r"(^|/)(bert/layer_\d+|mlm_head|loss|optimizer)(/|$)")
+    unnamed = [m for m in matmuls
+               if not known.search(table[m][0] or "")]
+    assert not unnamed, [(m, table[m]) for m in unnamed[:5]]
+    scopes = {s for s, _ in table.values() if s is not None}
+    assert {"optimizer", "grad_accum/loss", "grad_accum/mlm_head"} <= scopes
+    assert {f"grad_accum/bert/layer_{i}/mha" for i in range(12)} <= scopes
 
 
 def _flash_takes_the_chips_branch(monkeypatch):
