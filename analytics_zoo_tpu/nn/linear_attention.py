@@ -46,8 +46,9 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import gated_delta_rule as kernels
-from .initializers import a_log_init, dt_bias_init
-from .layers import CausalConv1D, Dense, RMSNorm
+from ..ops.gdn_qkv_conv import qkv_conv
+from . import initializers
+from .layers import Dense, RMSNorm
 from .module import Module, Scope
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -205,6 +206,27 @@ def _chunked_jax(q, k, v, g, beta, s0, chunk):
     return o.astype(dt), s_final
 
 
+class _QKVConv(Module):
+    """The mixer's child ``conv``: it holds the depthwise conv's ``kernel``
+    ``[K, 2 key_dim + value_dim]`` (the leaf a ``CausalConv1D`` under that
+    name held, drawn the same way) and is everything between
+    ``in_proj_qkvz``'s output and the delta rule's q, k, v:
+    ``ops/gdn_qkv_conv.py``, one Pallas kernel each way on a TPU."""
+
+    def __init__(self, num_k_heads: int, num_v_heads: int, k_head_dim: int,
+                 v_head_dim: int, kernel_size: int, epsilon: float,
+                 name: Optional[str] = None):
+        super().__init__(name)
+        self.heads = (num_k_heads, num_v_heads, k_head_dim, v_head_dim)
+        self.kernel_size, self.epsilon = kernel_size, epsilon
+
+    def forward(self, scope: Scope, qkvz: jax.Array):
+        hk, hv, dk, dv = self.heads
+        w = scope.param("kernel", initializers.get("lecun_uniform"),
+                        (self.kernel_size, 2 * hk * dk + hv * dv))
+        return qkv_conv(qkvz, w, *self.heads, self.epsilon)
+
+
 class GatedDeltaNet(Module):
     """Gated DeltaNet mixer: ``[B, T, D] -> [B, T, D]``, causal.
 
@@ -214,7 +236,9 @@ class GatedDeltaNet(Module):
     softplus(a + dt_bias)`` a value head (float32).  q, k, v pass a
     depthwise causal convolution of ``conv_kernel`` positions and a SiLU;
     q and k are L2-normalised over the head, q scaled by
-    ``1/sqrt(k_head_dim)``; each key head serves ``num_v_heads /
+    ``1/sqrt(k_head_dim)`` (all of that is the child ``conv``:
+    ``ops/gdn_qkv_conv.py``, one Pallas kernel each way on a TPU, the
+    ``jax.numpy`` lines elsewhere); each key head serves ``num_v_heads /
     num_k_heads`` value heads.  The recurrence is :func:`gated_delta_rule`;
     its output is RMS-normalised a head, gated by ``silu(z)`` and projected
     back.  No biases.  Holds no cache: a sequence starts from a zero state.
@@ -245,29 +269,22 @@ class GatedDeltaNet(Module):
         qkvz = scope.child(dense(2 * key_dim + 2 * value_dim), x,
                            name="in_proj_qkvz")
         ba = scope.child(dense(2 * hv), x, name="in_proj_ba")
-        qkv = scope.child(CausalConv1D(self.conv_kernel, activation="silu"),
-                          qkvz[..., :2 * key_dim + value_dim], name="conv")
-        z = qkvz[..., 2 * key_dim + value_dim:].reshape(b, t, hv, dv)
-        q = qkv[..., :key_dim].reshape(b, t, hk, dk)
-        k = qkv[..., key_dim:2 * key_dim].reshape(b, t, hk, dk)
-        v = qkv[..., 2 * key_dim:].reshape(b, t, hv, dv)
+        # conv, SiLU, the split into heads, q's and k's l2norm and q's scale
+        q, k, v, z = scope.child(
+            _QKVConv(hk, hv, dk, dv, self.conv_kernel, self.epsilon), qkvz,
+            name="conv")
 
-        a_log = scope.param("A_log", a_log_init(), (hv,))
-        dt_bias = scope.param("dt_bias", dt_bias_init(), (hv,))
+        a_log = scope.param("A_log", initializers.a_log_init(), (hv,))
+        dt_bias = scope.param("dt_bias", initializers.dt_bias_init(),
+                              (hv,))
         ba = ba.astype(jnp.float32)
         beta = jax.nn.sigmoid(ba[..., :hv])
         g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias)
-
-        def l2norm(a):
-            af = a.astype(jnp.float32)
-            return af * jax.lax.rsqrt(
-                jnp.square(af).sum(-1, keepdims=True) + self.epsilon)
-        q = (l2norm(q) * dk ** -0.5).astype(x.dtype)
-        k = l2norm(k).astype(x.dtype)
         # value head i reads key head i // (hv / hk)
         o, _ = gated_delta_rule(q, k, v, g, beta, chunk=self.chunk)
 
         o = scope.child(RMSNorm(self.epsilon), o, name="norm")
-        o = o * jax.nn.silu(z.astype(jnp.float32)).astype(o.dtype)
-        return scope.child(dense(d), o.reshape(b, t, value_dim),
-                           name="out_proj")
+        # z lies as qkvz's last columns: gated there, no head axis to make
+        o = (o.reshape(b, t, value_dim)
+             * jax.nn.silu(z.astype(jnp.float32)).astype(o.dtype))
+        return scope.child(dense(d), o, name="out_proj")

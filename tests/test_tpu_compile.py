@@ -31,6 +31,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # the module, not the function ops/__init__ re-exports under its name
 fa = importlib.import_module("analytics_zoo_tpu.ops.flash_attention")
 gdr = importlib.import_module("analytics_zoo_tpu.ops.gated_delta_rule")
+qkv = importlib.import_module("analytics_zoo_tpu.ops.gdn_qkv_conv")
 
 HBM_BYTES = 16 * 2 ** 30  # one v5e chip
 
@@ -192,6 +193,32 @@ def test_delta_rule_kernels_compile(chip, which):
             lambda *a: gdr._fwd_call(*a, 64, which != "forward", False),
             qk, qk, v, gate, gate, state)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ... and the way into them (PR 36): conv, SiLU, split and l2norm of the same
+# layer as one kernel each way, over ``qkvz`` [2, 8192, 12288] in place
+@pytest.mark.parametrize("which", ["forward", "backward"])
+def test_gdn_qkv_conv_kernels_compile(chip, which):
+    b, t, hk, hv, d, taps = 2, 8192, 16, 32, 128, 4
+    on = lambda shape, dtype: _on(chip, jax.ShapeDtypeStruct(shape, dtype))
+    qkvz, w = on((b, t, 12288), jnp.bfloat16), on((taps, 8192), jnp.float32)
+    sizes = (b, t, hk, hv, d, d, taps, 1e-6, jnp.dtype(jnp.bfloat16), False)
+    if which == "forward":
+        compiled = _compile(lambda x, w: qkv._forward(*sizes)(x, w), qkvz, w)
+        name = "gdn_qkv_conv_fwd"
+    else:
+        dqk, dvz = on((b, t, 2048), jnp.bfloat16), on((b, t, 4096),
+                                                      jnp.bfloat16)
+        compiled = _compile(lambda *a: qkv._backward(*sizes)(*a), dqk, dqk,
+                            dvz, dvz, qkvz, qkvz, w)
+        name = "gdn_qkv_conv_bwd"
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    asked, used = _kernel_vmem(text, name)
+    assert used <= asked < VMEM_BYTES, (asked, used)
+    # one pass: nothing of the program but the kernel's operands and results
+    # reaches HBM (the slice that fed the conv was a 268 MB copy)
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 def test_bert_base_layer_fwd_bwd_compiles(chip):
@@ -404,17 +431,19 @@ def test_qwen3next_cell_train_step_compiles_for_one_chip(topo, monkeypatch):
     """``qwen3next_ep16_fit_s8192``'s train step at its real sizes (625.7 M
     parameters with AdamW's moments, two rows of 8192 tokens): the chip's
     compiler takes it — a program over the chip's memory is refused here —
-    with the flash kernels (forward and backward) and the two delta-rule
-    kernels under their names,
+    with the flash kernels (forward and backward), the two delta-rule
+    kernels and the two kernels of the way into them under their names,
     the grouped matmuls of the expert layer as XLA's ragged-dot kernels,
     and one call of each kernel a layer and step (the blocks'
     recomputation keeps what the kernels' backward passes read: three
-    DeltaNet layers, one attention layer)."""
+    DeltaNet layers, one attention layer), but for ``gdn_qkv_conv_fwd``,
+    which the recomputation runs again."""
     import json
     from analytics_zoo_tpu.orca.learn import Estimator
     from benchmark.families import qwen3_next
     _flash_takes_the_chips_branch(monkeypatch)
     monkeypatch.setattr(gdr, "dispatch", lambda dk, dv, chunk: False)
+    monkeypatch.setattr(qkv, "dispatch", lambda *sizes: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(
             root, "benchmark/configs/qwen3_next_80b_a3b_ep16.json")) as f:
@@ -435,20 +464,28 @@ def test_qwen3next_cell_train_step_compiles_for_one_chip(topo, monkeypatch):
     for name in ("gated_delta_rule_fwd", "gated_delta_rule_bwd"):
         calls = re.findall(rf"%({name}[.\d]*) = ", text)
         assert len(calls) == 3, (name, calls)
+    # the way into them (PR 36) engages once a layer and pass: forward and
+    # recomputed forward, and one backward (its inputs are not kept)
+    for name, count in (("gdn_qkv_conv_fwd", 6), ("gdn_qkv_conv_bwd", 3)):
+        calls = re.findall(rf"%({name}[.\d]*) = ", text)
+        assert len(calls) == count, (name, calls)
     assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) >= 6 * 4
     held = sum(int(np.prod(l.shape)) for l in
                jax.tree_util.tree_leaves(est._ts["params"]))
     assert held == 625_667_136
-    # the program PR 32 left, to the byte (PR 33 compiled parent and change):
-    # a change to code this cell shares (``MultiHeadAttention``, the flash
-    # kernels' wrapper, ``CausalConv1D``) that means to leave it alone
-    # shows it here
+    # the program PR 36 left, to the byte: a change to code this cell shares
+    # (``MultiHeadAttention``, the flash kernels' wrapper, ``DroplessMoE``)
+    # that means to leave it alone shows it here.  PR 32 - 35 left
+    # 25,031,698,546,688 FLOPs, 340,933,083,136 bytes accessed and
+    # 5,699,792,384 B of temporaries (PR 34: + 96,768 B for the forward
+    # kernel's tile lists); PR 36's two kernels a DeltaNet layer took
+    # 52.2 GB of passes out (the issue asked for 30 at least: the slices,
+    # the l2norm's float32 arrays and the conv's three backward fusions)
     cost = compiled.cost_analysis()
-    assert int(cost["flops"]) == 25_031_698_546_688
-    assert int(cost["bytes accessed"]) == 340_933_083_136
-    # PR 34: + 96,768 B of temporaries beside the forward kernel (its tile
-    # lists ride in as operands); FLOPs and bytes as PR 32 left them
-    assert compiled.memory_analysis().temp_size_in_bytes == 5_699_792_384
+    assert int(cost["flops"]) == 24_989_306_716_160
+    assert int(cost["bytes accessed"]) == 288_747_782_144
+    assert 340_933_083_136 - int(cost["bytes accessed"]) > 30e9
+    assert compiled.memory_analysis().temp_size_in_bytes == 4_838_883_328
 
 
 def test_trinity_mini_cell_train_step_compiles_for_one_chip(topo,
