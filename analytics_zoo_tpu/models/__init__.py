@@ -4,11 +4,12 @@ Every family from the reference's Scala+Py twin zoo, rebuilt as pure-JAX
 modules over analytics_zoo_tpu.nn: recommendation (NeuralCF, WideAndDeep,
 SessionRecommender), text classification, text matching (KNRM), anomaly
 detection, seq2seq, image classification (ResNet), object detection (SSD),
-plus the BERT family the reference shipped through TFPark, and three
-causal decoders the reference had no analog of: two sparse-expert ones, a
-hybrid linear-attention one (Qwen3Next) and a sliding-window /
-full-attention one with a bias-balanced sigmoid router (AFMoE), and a dense
-hybrid of Mamba-2 state-space blocks and attention with a tied, scaled head
+plus the BERT family the reference shipped through TFPark, and four
+causal decoders the reference had no analog of: three sparse-expert ones, a
+hybrid linear-attention one (Qwen3Next), a sliding-window / full-attention
+one with a bias-balanced sigmoid router (AFMoE) and a latent-attention one
+with a multi-token prediction module (GlmMoeLite), and a dense hybrid of
+Mamba-2 state-space blocks and attention with a tied, scaled head
 (GraniteHybrid).
 """
 
@@ -26,6 +27,7 @@ from .bert import BERT, BERTClassifier, BERTNER, BERTSQuAD
 from .qwen3_next import Qwen3Next
 from .afmoe import AFMoE
 from .granite_hybrid import GraniteHybrid
+from .glm_moe_lite import GlmMoeLite
 from .graphnet import GraphNet
 from .net import ForeignNet, Net
 
@@ -36,5 +38,5 @@ __all__ = [
     "AnomalyDetector", "unroll", "Seq2seq", "RNNEncoder", "RNNDecoder",
     "ImageClassifier", "ResNet", "ObjectDetector", "SSDLite", "Visualizer",
     "BERT", "BERTClassifier", "BERTNER", "BERTSQuAD", "Qwen3Next",
-    "AFMoE", "GraniteHybrid",
+    "AFMoE", "GraniteHybrid", "GlmMoeLite",
 ]

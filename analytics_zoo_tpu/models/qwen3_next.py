@@ -15,10 +15,14 @@ RMSNorm, a final RMSNorm and an untied vocabulary head: logits at every
 position, trained with ``sparse_categorical_crossentropy`` against the ids
 shifted by one.
 
-Not built: the multi-token-prediction module the model card describes, a
-cache or a decode path (``Estimator.predict`` recomputes the sequence),
-packed documents with state resets, and the expert exchange across chips
-(a share computes its own experts' part and nothing else).
+Not used here: the multi-token-prediction module the model card describes
+(the release's config has no key for it; the module exists as
+``models.glm_moe_lite.MultiTokenPredictor`` with its loss
+``nn.losses.multi_token_crossentropy``, and the Qwen configuration does
+not build it).  Not built: a cache or a decode path (``Estimator.predict``
+recomputes the sequence), packed documents with state resets, and the
+expert exchange across chips (a share computes its own experts' part and
+nothing else).
 """
 
 from __future__ import annotations

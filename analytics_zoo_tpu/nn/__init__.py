@@ -1,8 +1,8 @@
 """Keras-style NN layer API on a minimal JAX module system (reference L5)."""
 
 from . import activations, initializers, losses, metrics
-from .attention import (MultiHeadAttention, TransformerLayer,
-                        dot_product_attention)
+from .attention import (LatentAttention, MultiHeadAttention,
+                        TransformerLayer, dot_product_attention)
 from .layers import (Activation, Add, AveragePooling2D, BatchNormalization,
                      CausalConv1D, Concatenate, Conv1D, Conv2D, Dense,
                      Dropout, Embedding,
@@ -66,7 +66,8 @@ __all__ = [
     "LayerNormalization", "Concatenate", "Add", "Multiply", "Sequential",
     "RMSNorm", "SwiGLU", "CausalConv1D", "GatedDeltaNet", "Mamba2",
     "LSTM", "GRU", "SimpleRNN", "Bidirectional", "TimeDistributed",
-    "MultiHeadAttention", "TransformerLayer", "dot_product_attention",
+    "MultiHeadAttention", "LatentAttention", "TransformerLayer",
+    "dot_product_attention",
     # extended Keras-1.2 zoo (layers_extra)
     "Conv3D", "Conv2DTranspose", "DepthwiseConv2D", "SeparableConv2D",
     "LocallyConnected1D", "MaxPooling1D", "AveragePooling1D",

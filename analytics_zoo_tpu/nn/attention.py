@@ -1,14 +1,24 @@
-"""Attention layers: MultiHeadAttention + Transformer encoder block.
+"""Attention layers.
 
-Reference (SURVEY.md §2.3, §5.7): the Scala Keras zoo's TransformerLayer/BERT
-self-attention layers (zoo/.../pipeline/api/keras/layers/ self-attention
-area), replicated per-worker with seq≤512 on CPU.
+``MultiHeadAttention``: the plain layer of the BERT family (reference,
+SURVEY.md §2.3, §5.7: the Scala Keras zoo's TransformerLayer / BERT
+self-attention, replicated per worker at seq <= 512 on the CPU) and, by its
+options, the attention of today's decoder blocks: grouped-query heads, a
+q/k RMSNorm, rotary embedding on a part of each head, an output gate, a
+sliding window, a published softmax scale.  ``LatentAttention``: multi-head
+latent attention (DeepSeek-V2/V3's block): queries through a low-rank
+bottleneck, keys and values rebuilt from one low-rank latent, the rotary
+part of the key one head shared by all.  ``TransformerLayer``: the
+reference's pre/post-LN encoder block.
 
-TPU-native: batched einsum attention that XLA fuses onto the MXU, with an
-optional fused-kernel hook — ``analytics_zoo_tpu.ops.flash_attention``
-(Pallas) is used when available for long sequences, and ring attention over a
-``seq`` mesh axis lives in ``analytics_zoo_tpu.parallel.ring_attention``
-(capability the reference lacked; SURVEY.md §5.7 'post-parity stretch').
+Both attention classes hand q, k, v ``[B, T, H, D]`` to ``attention_core``,
+the one dense / flash / ring dispatch: batched einsum attention that XLA
+puts on the MXU (under ``jax.checkpoint`` with ``remat``), the Pallas flash
+kernels of ``analytics_zoo_tpu.ops.flash_attention`` (forward and backward;
+a window visits its band's tiles alone), or ring attention over a ``seq``
+mesh axis (``analytics_zoo_tpu.parallel.ring_attention``).
+``rotary_embedding``, ``causal_mask`` and ``dot_product_attention`` are the
+pieces, usable alone.
 """
 
 from __future__ import annotations
@@ -100,6 +110,53 @@ def rotary_embedding(x: jax.Array, rotary_dim: int,
     return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
 
 
+def attention_core(q: jax.Array, k: jax.Array, v: jax.Array,
+                   causal: bool = False, window: Optional[int] = None,
+                   scale: Optional[float] = None,
+                   mask: Optional[jax.Array] = None,
+                   use_flash: Union[bool, str] = False,
+                   use_ring: bool = False, remat: bool = False) -> jax.Array:
+    """The dense / flash / ring dispatch of every attention layer: q
+    ``[B, Tq, H, D]``, k ``[B, Tk, H, D]``, v ``[B, Tk, H, Dv]`` (the heads
+    already repeated to H) -> ``[B, Tq, H, Dv]``.  ``use_flash="auto"``
+    takes the flash kernels from ``FLASH_AUTO_MIN_SEQ`` keys on; an explicit
+    ``mask`` takes the dense path, where ``causal`` and ``window`` still
+    apply; ``remat`` recomputes the dense core in the backward pass.  The
+    flash kernels want value heads as wide as the key's (``Dv == D``); the
+    dense path takes any."""
+    tq, tk = q.shape[1], k.shape[1]
+    if use_flash == "auto":
+        use_flash = (mask is None
+                     and tk >= FLASH_AUTO_MIN_SEQ)
+    if use_ring and mask is None:
+        from analytics_zoo_tpu.parallel import ring_self_attention
+        return ring_self_attention(q, k, v, causal=causal)
+    if use_flash and mask is None:
+        if v.shape[-1] != q.shape[-1]:
+            raise ValueError(
+                f"the flash kernels take value heads as wide as the key's; "
+                f"got {v.shape[-1]} against {q.shape[-1]} at {tk} keys "
+                "(use_flash=False is the dense path, which takes any)")
+        from analytics_zoo_tpu.ops import flash_attention
+        return flash_attention(q, k, v, causal=causal,
+                               window=window, scale=scale)
+    # explicit mask: dense path (flash/ring kernels take no mask);
+    # causal still applies — combine, never silently drop it
+    if window is not None and mask is not None \
+            and tk >= FLASH_AUTO_MIN_SEQ:
+        # the band exists to spare the [T, T] maps: a mask that
+        # forces them at such a length is refused, not obeyed
+        raise ValueError(
+            f"a window with an explicit mask takes the dense path; "
+            f"at {tk} keys (>= {FLASH_AUTO_MIN_SEQ}) that "
+            "is [T, T] maps the window was meant to spare")
+    if causal:
+        cm = causal_mask(tq, tk, window)
+        mask = cm if mask is None else (mask.astype(bool) & cm)
+    attn = functools.partial(dot_product_attention, scale=scale)
+    return (jax.checkpoint(attn) if remat else attn)(q, k, v, mask)
+
+
 class MultiHeadAttention(Module):
     """Multi-head attention, ``[B, T, D] -> [B, T, D]``.  The defaults are
     the plain layer of the BERT family.  A decoder block of today's kind
@@ -117,7 +174,7 @@ class MultiHeadAttention(Module):
     builds the band mask), ``scale`` (the softmax's multiplier where a
     model publishes one other than ``1/sqrt(head_dim)``; dense and flash
     paths).  All of them go through the one dense / flash / ring dispatch
-    below."""
+    above (``attention_core``)."""
 
     def __init__(self, num_heads: int, head_dim: Optional[int] = None,
                  dropout: float = 0.0,
@@ -225,33 +282,10 @@ class MultiHeadAttention(Module):
             k = jnp.repeat(k, h // kv_h, axis=2)
             v = jnp.repeat(v, h // kv_h, axis=2)
 
-        use_flash = self.use_flash
-        if use_flash == "auto":
-            use_flash = (mask is None
-                         and kv.shape[1] >= FLASH_AUTO_MIN_SEQ)
-        if self.use_ring and mask is None:
-            from analytics_zoo_tpu.parallel import ring_self_attention
-            ctx = ring_self_attention(q, k, v, causal=self.causal)
-        elif use_flash and mask is None:
-            from analytics_zoo_tpu.ops import flash_attention
-            ctx = flash_attention(q, k, v, causal=self.causal,
-                                  window=self.window, scale=self.scale)
-        else:
-            # explicit mask: dense path (flash/ring kernels take no mask);
-            # causal still applies — combine, never silently drop it
-            if self.window is not None and mask is not None \
-                    and kv.shape[1] >= FLASH_AUTO_MIN_SEQ:
-                # the band exists to spare the [T, T] maps: a mask that
-                # forces them at such a length is refused, not obeyed
-                raise ValueError(
-                    f"a window with an explicit mask takes the dense path; "
-                    f"at {kv.shape[1]} keys (>= {FLASH_AUTO_MIN_SEQ}) that "
-                    "is [T, T] maps the window was meant to spare")
-            if self.causal:
-                cm = causal_mask(x.shape[1], kv.shape[1], self.window)
-                mask = cm if mask is None else (mask.astype(bool) & cm)
-            attn = functools.partial(dot_product_attention, scale=self.scale)
-            ctx = (jax.checkpoint(attn) if self.remat else attn)(q, k, v, mask)
+        ctx = attention_core(q, k, v, causal=self.causal, window=self.window,
+                             scale=self.scale, mask=mask,
+                             use_flash=self.use_flash,
+                             use_ring=self.use_ring, remat=self.remat)
 
         if gate is not None:
             ctx = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)
@@ -260,6 +294,93 @@ class MultiHeadAttention(Module):
         out = jnp.dot(ctx.reshape(x.shape[:-1] + (h * d_head,)),
                       wo.astype(x.dtype))
         return scope.child(Dropout(self.dropout), out, name="drop")
+
+
+#: level (a float32 leaf beside the counters; the Estimator observes it as
+#: it stands, in the histogram ``mla.<key>``, docs/observability.md) of
+#: LatentAttention: the largest |c_kv| after its norm in the layer's last
+#: step, what a latent cache in a lower precision would have to hold
+LATENT_LEVEL_KEYS = ("kv_latent_abs_max",)
+
+
+class LatentAttention(Module):
+    """Multi-head latent attention (DeepSeek-V2/V3, arXiv:2412.19437 section
+    2.1.1), self-attention from position 0, ``[B, T, D] -> [B, T, D]``, in
+    its training form: keys and values are rebuilt from the latent at every
+    position (the absorbed form that decodes from a cache of latents is not
+    built).
+
+    ``c_q = RMSNorm(x wq_a)`` (``q_rank`` wide) and ``c_q wq_b`` gives each
+    head ``rope_dim + nope_dim`` query dims.  ``x wkv_a`` gives ``[c_kv |
+    k_r]``: the latent (``kv_rank`` wide, then RMS-normalised) and ONE key
+    head of ``rope_dim`` that every query head shares; ``c_kv wkv_b`` gives
+    each head ``[k_n | v]``, ``nope_dim`` key dims and ``v_dim`` value dims.
+    The rotary embedding turns the first ``rope_dim`` dims of a query head
+    and all of ``k_r`` (:func:`rotary_embedding`: half-split pairs); a key
+    head is ``[k_r | k_n]``.  Scores are scaled by ``1 / sqrt(rope_dim +
+    nope_dim)``, and the ``num_heads * v_dim`` values go through ``wo``.  No
+    bias anywhere; matmul operands in the input's dtype, norms and rotation
+    in float32.
+
+    The core goes through ``attention_core``; the flash kernels take it
+    when ``rope_dim + nope_dim == v_dim`` and refuse it otherwise.
+
+    State: ``counters`` — the level ``mla.kv_latent_abs_max``
+    (``LATENT_LEVEL_KEYS``), read by the Estimator once an epoch.
+    """
+
+    def __init__(self, num_heads: int, q_rank: int, kv_rank: int,
+                 nope_dim: int, rope_dim: int, v_dim: int,
+                 rope_theta: float = 10000.0, norm_epsilon: float = 1e-6,
+                 causal: bool = True, use_flash: Union[bool, str] = False,
+                 name: Optional[str] = None):
+        super().__init__(name)
+        if rope_dim % 2:
+            raise ValueError(f"rope_dim must be even; got {rope_dim}")
+        if use_flash not in (True, False, "auto"):
+            raise ValueError(
+                f"use_flash must be True, False, or 'auto'; got "
+                f"{use_flash!r}")
+        self.num_heads = num_heads
+        self.q_rank, self.kv_rank = q_rank, kv_rank
+        self.nope_dim, self.rope_dim, self.v_dim = nope_dim, rope_dim, v_dim
+        self.rope_theta = rope_theta
+        self.norm_epsilon = norm_epsilon
+        self.causal = causal
+        self.use_flash = use_flash
+
+    def forward(self, scope: Scope, x: jax.Array) -> jax.Array:
+        b, t, d_model = x.shape
+        h, rope, nope = self.num_heads, self.rope_dim, self.nope_dim
+        init = initializers.get("glorot_uniform")
+
+        def proj(name: str, src: jax.Array, width: int) -> jax.Array:
+            w = scope.param(name, init, (src.shape[-1], width))
+            with jax.named_scope(name):  # a profile tells the five apart
+                return jnp.dot(src, w.astype(src.dtype))  # same-dtype dot
+
+        norm = RMSNorm(self.norm_epsilon)
+        c_q = scope.child(norm, proj("wq_a", x, self.q_rank), name="q_norm")
+        q = proj("wq_b", c_q, h * (rope + nope)).reshape(b, t, h, rope + nope)
+        kv_a = proj("wkv_a", x, self.kv_rank + rope)
+        c_kv = scope.child(norm, kv_a[..., :self.kv_rank], name="kv_norm")
+        k_r = kv_a[..., None, self.kv_rank:]                 # one head
+        kv = proj("wkv_b", c_kv, h * (nope + self.v_dim)).reshape(
+            b, t, h, nope + self.v_dim)
+        q = rotary_embedding(q, rope, self.rope_theta)
+        k_r = rotary_embedding(k_r, rope, self.rope_theta)
+        k = jnp.concatenate([jnp.broadcast_to(k_r, (b, t, h, rope)),
+                             kv[..., :nope]], axis=-1)
+        ctx = attention_core(q, k, kv[..., nope:], causal=self.causal,
+                             use_flash=self.use_flash)
+
+        if scope.init_mode:  # a level: nothing of the last step is read
+            scope.variable("counters", lambda: {
+                "mla." + LATENT_LEVEL_KEYS[0]: jnp.zeros((), jnp.float32)})
+        scope.put_variable("counters", {
+            "mla." + LATENT_LEVEL_KEYS[0]: jax.lax.stop_gradient(
+                jnp.abs(c_kv).max().astype(jnp.float32))})
+        return proj("wo", ctx.reshape(b, t, h * self.v_dim), d_model)
 
 
 class TransformerLayer(Module):

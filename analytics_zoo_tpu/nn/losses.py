@@ -32,6 +32,32 @@ def sparse_categorical_crossentropy(y_pred: jax.Array, y_true: jax.Array,
     return nll.mean()
 
 
+def multi_token_crossentropy(y_pred: jax.Array, y_true: jax.Array,
+                             depth_weight: float = 0.3) -> jax.Array:
+    """Cross-entropy of a model that predicts several tokens ahead
+    (``models.GlmMoeLite``): ``y_pred`` ``[B, K, T, V]`` holds one row of
+    logits a prediction depth, ``y_true`` ``[B, T]`` the next-token ids.
+    Depth k's logits at position i are scored against ``y_true[i + k]``; the
+    k positions whose target lies past the row are left out of that depth's
+    mean.  Depth 0 counts once, every deeper one ``depth_weight`` times
+    (DeepSeek-V3's lambda, arXiv:2412.19437 section 2.2).  The same
+    logsumexp-minus-target form as ``sparse_categorical_crossentropy``, in
+    float32."""
+    y_true = y_true.astype(jnp.int32)
+    depths, t = y_pred.shape[1], y_pred.shape[2]
+    logits = y_pred.astype(jnp.float32)
+    # depth k's targets, the k past the row's end pointed at id 0 and masked
+    target = jnp.stack([jnp.pad(y_true[:, k:], ((0, 0), (0, k)))
+                        for k in range(depths)], axis=1)         # [B, K, T]
+    scored = jnp.arange(t) < t - jnp.arange(depths)[:, None]     # [K, T]
+    nll = jax.scipy.special.logsumexp(logits, axis=-1) \
+        - jnp.take_along_axis(logits, target[..., None], axis=-1)[..., 0]
+    per_depth = jnp.where(scored, nll, 0.0).sum(axis=(0, 2)) \
+        / (y_true.shape[0] * scored.sum(axis=-1))
+    weight = jnp.where(jnp.arange(depths) == 0, 1.0, depth_weight)
+    return (per_depth * weight).sum()
+
+
 def categorical_crossentropy(y_pred: jax.Array, y_true: jax.Array,
                              from_logits: bool = True) -> jax.Array:
     if from_logits:
@@ -107,6 +133,7 @@ def cosine_proximity(y_pred: jax.Array, y_true: jax.Array) -> jax.Array:
 
 LOSSES = {
     "sparse_categorical_crossentropy": sparse_categorical_crossentropy,
+    "multi_token_crossentropy": multi_token_crossentropy,
     "categorical_crossentropy": categorical_crossentropy,
     "binary_crossentropy": binary_crossentropy,
     "mse": mean_squared_error,

@@ -12,13 +12,14 @@ way, so span naming can't drift undocumented either:
   ``core/metrics.py`` registry is found by scanning ``analytics_zoo_tpu``
   sources for ``counter("...")`` / ``gauge("...")`` /
   ``histogram("...")`` / ``inc("...")`` / ``observe("...")`` /
-  ``set_gauge("...")`` string literals, PLUS the five known dynamic
+  ``set_gauge("...")`` string literals, PLUS the known dynamic
   registration sites (``"client." + key`` over the client's stats dict,
   ``"server." + k`` over the server's counters dict, ``"frontend." +
   key`` over ``_FRONTEND_COUNTERS``, ``"moe." + key`` over the expert
   layer's ``COUNTER_KEYS`` and ``LEVEL_KEYS``, ``"ssm." + key`` over the
-  state-space mixer's) whose key sets are extracted from the same
-  files;
+  state-space mixer's, ``"mla." + key`` over latent attention's level and
+  ``"mtp." + key`` over the multi-token prediction module's) whose key
+  sets are extracted from the same files;
 - **spans, code side**: every span name recorded through ``core/trace.py``
   — the second argument of ``trace.record(...)`` / ``trace_lib.record``
   call sites and the first argument of ``trace.span("...")`` /
@@ -78,6 +79,13 @@ _DYNAMIC = [
      re.compile(r"COUNTER_KEYS = \(([^)]*)\)", re.S)),
     ("nn/state_space.py", "ssm.",
      re.compile(r"LEVEL_KEYS = \(([^)]*)\)", re.S)),
+    # latent attention's level and the prediction module's counters
+    ("nn/attention.py", "mla.",
+     re.compile(r"LATENT_LEVEL_KEYS = \(([^)]*)\)", re.S)),
+    ("models/glm_moe_lite.py", "mtp.",
+     re.compile(r"MTP_COUNTER_KEYS = \(([^)]*)\)", re.S)),
+    ("models/glm_moe_lite.py", "mtp.",
+     re.compile(r"MTP_LEVEL_KEYS = \(([^)]*)\)", re.S)),
 ]
 
 _KEY = re.compile(r'"([a-z0-9_]+)"')

@@ -559,3 +559,42 @@ def test_granite_cell_train_step_compiles_for_one_chip(topo, monkeypatch):
     held = sum(int(np.prod(l.shape)) for l in
                jax.tree_util.tree_leaves(est._ts["params"]))
     assert held == 772_160_448
+
+
+@pytest.mark.slow   # a minute or two of compiling: a builder's tool
+def test_glm_cell_train_step_compiles_for_one_chip(topo, monkeypatch):
+    """``glm47_flash_ep8_fit_s8192``'s train step at its real sizes (706.5 M
+    parameters with AdamW's moments, one row of 8,192 tokens, both
+    prediction depths' logits): the chip's compiler takes it inside the
+    chip's memory, with the flash kernels (forward, backward) once for each
+    of the six latent-attention layers at ``[20, 8192, 256]`` (the blocks'
+    recomputation keeps what the backward passes read), the five expert
+    layers' grouped matmuls as ragged-dot kernels, and ONE head matmul
+    over both depths' 16,384 rows."""
+    import json
+    from analytics_zoo_tpu.orca.learn import Estimator
+    from benchmark.families import glm_moe_lite
+    _flash_takes_the_chips_branch(monkeypatch)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark/configs/glm_4_7_flash_ep8.json")) as f:
+        config = json.load(f)
+    est = Estimator.from_keras(
+        glm_moe_lite.build(config), loss=config["loss"],
+        optimizer=config["optimizer"]["name"],
+        learning_rate=config["optimizer"]["learning_rate"])
+    ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    compiled = _abstract_train_step(est, mesh, ids, ids).compile()
+    print("per-chip bytes", _per_chip_bytes(compiled) / 1e9, "GB;",
+          compiled.memory_analysis())
+    assert _per_chip_bytes(compiled) < HBM_BYTES
+    text = compiled.as_text()
+    assert len(re.findall(r"%(flash_attention_fwd[.\d]*) = ", text)) == 6
+    assert len(re.findall(r"%(flash_attention_bwd[.\d]*) = ", text)) == 6
+    assert re.search(r"bf16\[20,8192,256\]", text)
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) >= 6 * 5
+    assert re.search(r"bf16\[1,2,8192,19360\]", text)   # one head pass
+    held = sum(int(np.prod(l.shape)) for l in
+               jax.tree_util.tree_leaves(est._ts["params"]))
+    assert held == 706_518_528
