@@ -25,12 +25,27 @@ float32 sums taken BEFORE the ``exp`` (never ``exp(c_t) / exp(c_s)``: at a
 strong decay ``exp(c_s)`` underflows inside one chunk of 256); matmul
 operands keep the inputs' dtype (bf16 on the MXU), sums are float32.
 
-This is the ``jax.numpy`` form, on every backend; autodiff gives its
-backward pass (``lax.scan``'s transpose walks the chunks in reverse).  The
-``[chunks, H, Q, Q]`` float32 terms are recomputed in the backward pass
-(``jax.checkpoint``) and not kept.  ``ssd`` tags its output and the
-chunk-start states (``mamba2_ssd_out``, ``mamba2_ssd_states``) for an
-enclosing ``nn.Remat(save_names=)``.
+One algorithm, two implementations, chosen from what a call can see
+(``ops.mamba2_ssd.dispatch``: the backend and the shapes; no option and no
+model's name).  On backend ``tpu``, for heads, a state width and a chunk
+that are whole 128-lane tiles, the two Pallas kernels of
+``ops/mamba2_ssd.py`` (``mamba2_ssd_fwd``, ``mamba2_ssd_bwd``) keep a
+chunk's ``[Q, Q]`` terms and the carried state in VMEM; the running sums
+``c_t`` stay ``jax.numpy`` around them (``[B, T, H]`` float32), so autodiff
+takes ``dt`` and ``A_log`` through the cumulative sum as before.  Everywhere
+else (every other backend, a float32 model at chunk 24, a row shorter than
+128) the ``jax.numpy`` form below, which is also the oracle of the kernels'
+tests (they set ``ops.mamba2_ssd.INTERPRET``).  Which of the two a trace of
+``ssd`` took is counted at trace time: ``ssm.scan_traces{path="kernel" |
+"jnp"}`` (docs/observability.md).
+
+What is kept for the backward pass: by the kernels, their inputs and the
+float32 state at every chunk's start (the ``[Q, Q]`` terms are rebuilt in
+VMEM); by the ``jax.numpy`` form what autodiff keeps (``lax.scan``'s
+transpose walks the chunks in reverse), less the ``[chunks, H, Q, Q]``
+float32 terms, which are recomputed (``jax.checkpoint``).  Both tag the
+output and the chunk-start states (``mamba2_ssd_out``,
+``mamba2_ssd_states``) for an enclosing ``nn.Remat(save_names=)``.
 """
 
 from __future__ import annotations
@@ -41,6 +56,8 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ..core.metrics import get_registry
+from ..ops import mamba2_ssd as kernels
 from . import initializers
 from .layers import CausalConv1D, Dense, RMSNorm
 from .module import Module, Scope
@@ -87,7 +104,6 @@ def _ssd(x, dt, a_log, b, c, d_skip, s0, chunk) -> Tuple[
     g, n = b.shape[2:]
     if h % g:
         raise ValueError(f"{h} heads are no multiple of {g} groups")
-    r = h // g
     q = min(chunk, t)
     pad = -t % q
     if pad:
@@ -95,6 +111,37 @@ def _ssd(x, dt, a_log, b, c, d_skip, s0, chunk) -> Tuple[
                    for v in (x, b, c))
         dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
     nc = (t + pad) // q
+    dt = dt.astype(jnp.float32)
+    # c_t: the log decay from the chunk's start to position t, inclusive
+    cs = jnp.cumsum((dt * -jnp.exp(a_log.astype(jnp.float32))).reshape(
+        bsz, nc, q, h), axis=2)                              # [B,nc,Q,H]
+    s0 = (jnp.zeros((bsz, h, p, n), jnp.float32) if s0 is None
+          else s0.astype(jnp.float32))
+    interpret = kernels.dispatch(h, g, p, n, q)
+    get_registry().inc("ssm.scan_traces",
+                       path="jnp" if interpret is None else "kernel")
+    if interpret is None:
+        y, s_final, s_abs_max = _chunked_jax(x, dt, cs, b, c, d_skip, s0)
+    else:
+        y, s_final, s_abs_max = kernels.chunk_kernels(
+            x, dt, cs.reshape(dt.shape), b, c, d_skip, s0, q, interpret)
+    stats = jax.lax.stop_gradient({
+        "tokens_padded": jnp.asarray(bsz * pad, jnp.int32),
+        "chunk_decay_exponent_max": (-cs[:, :, -1]).max(),
+        "state_abs_max": s_abs_max.max()})
+    return y[:, :t], s_final, stats
+
+
+def _chunked_jax(x, dt, cs, b, c, d_skip, s0):
+    """The chunked form in ``jax.numpy``: x ``[B, T, H, P]``, dt ``[B, T,
+    H]`` float32, cs (the running sums ``c_t``) ``[B, chunks, Q, H]``, b and
+    c ``[B, T, G, N]``, s0 ``[B, H, P, N]`` float32, T a whole number of
+    chunks.  Returns y, the final state and the largest |S| at a chunk's
+    boundary.  Autodiff gives its backward pass."""
+    bsz, t, h, p = x.shape
+    g, n = b.shape[2:]
+    nc, q = cs.shape[1:3]
+    r = h // g
     dtype = x.dtype
     f32 = dict(preferred_element_type=jnp.float32)
 
@@ -103,10 +150,8 @@ def _ssd(x, dt, a_log, b, c, d_skip, s0, chunk) -> Tuple[
 
     xc = chunks(x, g, r, p)
     bc, cc = chunks(b, g, n), chunks(c, g, n)
-    dtc = chunks(dt.astype(jnp.float32), g, r)
-    # c_t: the log decay from the chunk's start to position t, inclusive
-    cs = jnp.cumsum(dtc * -jnp.exp(a_log.astype(jnp.float32)).reshape(g, r),
-                    axis=2)                                  # [B,nc,Q,G,R]
+    dtc = chunks(dt, g, r)
+    cs = cs.reshape(bsz, nc, q, g, r)
     x_dt = (xc * dtc[..., None]).astype(dtype)
 
     @jax.checkpoint
@@ -134,15 +179,13 @@ def _ssd(x, dt, a_log, b, c, d_skip, s0, chunk) -> Tuple[
     wrote = jnp.einsum("bcsgrp,bcsgn->bcgrpn", x_end, bc, **f32)
     kept = jnp.exp(cs[:, :, -1])                             # [B,nc,G,R]
 
-    s0 = (jnp.zeros((bsz, g, r, p, n), jnp.float32) if s0 is None
-          else s0.astype(jnp.float32).reshape(bsz, g, r, p, n))
-
     def carry(s, step):
         wrote_i, kept_i = step
         return s * kept_i[..., None, None] + wrote_i, s
 
     s_final, s_in = jax.lax.scan(
-        carry, s0, (jnp.moveaxis(wrote, 1, 0), jnp.moveaxis(kept, 1, 0)))
+        carry, s0.reshape(bsz, g, r, p, n),
+        (jnp.moveaxis(wrote, 1, 0), jnp.moveaxis(kept, 1, 0)))
     s_in = checkpoint_name(jnp.moveaxis(s_in, 0, 1), "mamba2_ssd_states")
 
     # what the incoming state answers, decayed to each position
@@ -150,14 +193,10 @@ def _ssd(x, dt, a_log, b, c, d_skip, s0, chunk) -> Tuple[
                        **f32) * jnp.exp(cs)[..., None]
     y = y + xc.astype(jnp.float32) * d_skip.astype(jnp.float32).reshape(
         g, r, 1)
-    y = y.astype(dtype).reshape(bsz, t + pad, h, p)[:, :t]
-    y = checkpoint_name(y, "mamba2_ssd_out")
-    stats = jax.lax.stop_gradient({
-        "tokens_padded": jnp.asarray(bsz * pad, jnp.int32),
-        "chunk_decay_exponent_max": (-cs[:, :, -1]).max(),
-        "state_abs_max": jnp.maximum(jnp.abs(s_in).max(),
-                                     jnp.abs(s_final).max())})
-    return y, s_final.reshape(bsz, h, p, n), stats
+    y = checkpoint_name(y.astype(dtype).reshape(bsz, t, h, p),
+                        "mamba2_ssd_out")
+    s_abs_max = jnp.maximum(jnp.abs(s_in).max(), jnp.abs(s_final).max())
+    return y, s_final.reshape(bsz, h, p, n), s_abs_max
 
 
 class Mamba2(Module):

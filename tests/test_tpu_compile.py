@@ -32,6 +32,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 fa = importlib.import_module("analytics_zoo_tpu.ops.flash_attention")
 gdr = importlib.import_module("analytics_zoo_tpu.ops.gated_delta_rule")
 qkv = importlib.import_module("analytics_zoo_tpu.ops.gdn_qkv_conv")
+ssd = importlib.import_module("analytics_zoo_tpu.ops.mamba2_ssd")
 
 HBM_BYTES = 16 * 2 ** 30  # one v5e chip
 
@@ -530,15 +531,17 @@ def test_trinity_mini_cell_train_step_compiles_for_one_chip(topo,
 def test_granite_cell_train_step_compiles_for_one_chip(topo, monkeypatch):
     """``granite4h_micro_fit_s8192``'s train step at its real sizes (772.2 M
     parameters with AdamW's moments, one row of 8,192 tokens): the chip's
-    compiler takes it inside the chip's memory (11.6 GB by its own count),
-    with the flash kernels (forward, backward) once for the one attention
-    layer, at heads of 64 padded to the 128 lanes, and the chunked scan's
-    [32 chunks, 64 heads, 256, 256] terms as XLA's own ops (no kernel is
-    asked for the scan)."""
+    compiler takes it inside the chip's memory, with the flash kernels
+    (forward, backward) once for the one attention layer, at heads of 64
+    padded to the 128 lanes, and the scan as its two kernels (PR 38): the
+    forward twice a Mamba layer (the forward pass and the block's
+    recomputation), the backward once, and no chunk's [.., 256, 256] term
+    left in HBM."""
     import json
     from analytics_zoo_tpu.orca.learn import Estimator
     from benchmark.families import granite_hybrid
     _flash_takes_the_chips_branch(monkeypatch)
+    monkeypatch.setattr(ssd, "dispatch", lambda *sizes: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(
             root, "benchmark/configs/granite_4_0_h_micro_pp4.json")) as f:
@@ -555,10 +558,19 @@ def test_granite_cell_train_step_compiles_for_one_chip(topo, monkeypatch):
     assert len(re.findall(r"%(flash_attention_fwd[.\d]*) = ", text)) == 1
     assert len(re.findall(r"%(flash_attention_bwd[.\d]*) = ", text)) == 1
     assert re.search(r"bf16\[32,8192,128\]", text)      # 64 padded to 128
-    assert re.search(r"bf16\[32,64,256,256\]", text)    # the chunks' terms
+    for name, count in (("mamba2_ssd_fwd", 18), ("mamba2_ssd_bwd", 9)):
+        calls = re.findall(rf"%({name}[.\d]*) = ", text)
+        assert len(calls) == count, (name, calls)
+    assert not re.search(r"\[[\d,]*,256,256\]", text)  # the chunks' terms
     held = sum(int(np.prod(l.shape)) for l in
                jax.tree_util.tree_leaves(est._ts["params"]))
     assert held == 772_160_448
+    # the jax.numpy scan's passes are gone: PR 37's step accessed
+    # 226,534,309,888 bytes (1,161 mentions of a [.., 256, 256] array in
+    # its text), PR 38's 146,739,462,144, with 2.36 GB of temporaries both
+    cost = compiled.cost_analysis()
+    assert 226_534_309_888 - int(cost["bytes accessed"]) > 60e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.6e9
 
 
 @pytest.mark.slow   # a minute or two of compiling: a builder's tool
