@@ -19,9 +19,11 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from analytics_zoo_tpu.core.metrics import get_registry
 from analytics_zoo_tpu.nn import activations, initializers
 from analytics_zoo_tpu.nn.layers import Dense, SwiGLU
 from analytics_zoo_tpu.nn.module import Module, Scope
+from analytics_zoo_tpu.ops import moe_rows
 
 
 class MoE(Module):
@@ -130,23 +132,36 @@ LEVEL_KEYS = ("expert_bias_abs_max",)
 FAST_BUFFER = 2.0
 
 
+def _sizes(start, load, n: int):
+    """The held experts' rows inside places ``start .. start + n`` of the
+    sorted order (the grouped matmuls' group sizes), and where each
+    expert's rows end."""
+    ends = jnp.cumsum(load)
+    return (jnp.clip(ends, start, start + n)
+            - jnp.clip(ends - load, start, start + n)), ends
+
+
+def _experts(x_rows, w_in, w_out, sizes):
+    """The held experts' SwiGLU over a window's rows, grouped by expert."""
+    m = w_out.shape[1]
+    h = jax.lax.ragged_dot(x_rows, w_in, sizes)
+    h = jax.nn.silu(h[:, :m]) * h[:, m:]
+    return jax.lax.ragged_dot(h, w_out, sizes)
+
+
 def _window(start, xs, w_in, w_out, pair_w, order, load, n: int, k: int):
     """Rows ``start .. start + n`` of the sorted (token, pick) pairs through
     the held experts: their weighted outputs added up by token ([S, D]
     float32), and how many of the rows are held pairs.  The others belong to
     no group: the grouped matmuls leave them undefined, forward and
     backward, so they are masked on the way in and on the way out."""
-    s, m = xs.shape[0], w_out.shape[1]
+    s = xs.shape[0]
     pairs = jax.lax.dynamic_slice(order, (start,), (n,))
     token = pairs // k
-    ends = jnp.cumsum(load)
-    sizes = jnp.clip(ends, start, start + n) \
-        - jnp.clip(ends - load, start, start + n)
+    sizes, ends = _sizes(start, load, n)
     live = (start + jnp.arange(n) < ends[-1])[:, None]
     x_rows = jnp.where(live, xs[token], 0)                       # [n, D]
-    h = jax.lax.ragged_dot(x_rows, w_in, sizes)
-    h = jax.nn.silu(h[:, :m]) * h[:, m:]
-    y = jax.lax.ragged_dot(h, w_out, sizes)
+    y = _experts(x_rows, w_in, w_out, sizes)
     y = jnp.where(live, y, 0).astype(jnp.float32) * pair_w[pairs][:, None]
     out = jnp.zeros((s, xs.shape[1]), jnp.float32).at[token].add(y)
     return out, live.sum(dtype=jnp.int32)
@@ -190,6 +205,127 @@ def _routed_bwd(n, k, res, g):
 _routed.defvjp(_routed_fwd, _routed_bwd)
 
 
+def _route(i, order, pair_w, place, load, n: int, k: int):
+    """Window ``i``'s :class:`ops.moe_rows.Route`, its group sizes and its
+    rows' weights (a gather of ``n`` scalars)."""
+    start = i * n
+    sizes, ends = _sizes(start, load, n)
+    pairs = jax.lax.dynamic_slice(order, (start,), (n,))
+    win = jnp.stack([start, jnp.clip(ends[-1] - start, 0, n)])
+    return (moe_rows.Route(pairs // k, place, win.astype(jnp.int32)), sizes,
+            pair_w[pairs])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def _routed_rows(xs, w_in, w_out, pick_w, order, pair_w, place, load,
+                 n: int, k: int, interpret: bool):
+    """:func:`_routed` with the rows moved by the kernels of
+    ``ops/moe_rows.py``: no row is gathered, masked, weighted or
+    scatter-added by XLA.  ``place`` ([S * kk]) and ``pick_w`` ([S, kk])
+    are each token's held picks, first ones first: their places in the
+    sorted order and their weights; ``pair_w`` holds the same weights by
+    pair, for the kernels to read by row (no gradient flows through it:
+    the backward returns the picks' gradient as ``pick_w``'s).  The first
+    window is taken before the loop, so that the balanced step's one
+    window folds no carry in and adds no gradient to zeros."""
+    return _routed_rows_fwd(xs, w_in, w_out, pick_w, order, pair_w, place,
+                            load, n, k, interpret)[0]
+
+
+def _routed_rows_fwd(xs, w_in, w_out, pick_w, order, pair_w, place, load,
+                     n, k, interpret):
+    s = xs.shape[0]
+
+    def window(i, carry):
+        route, sizes, w = _route(i, order, pair_w, place, load, n, k)
+        y = _experts(moe_rows.gather_call(xs, route, interpret=interpret),
+                     w_in, w_out, sizes)
+        out = moe_rows.combine_call(
+            y, route, s, weight=w, carry=None if carry is None else carry[0],
+            interpret=interpret)
+        return out, route.win[1] + (0 if carry is None else carry[1])
+    return (jax.lax.fori_loop(1, -(-load.sum() // n), window, window(0, None)),
+            (xs, w_in, w_out, order, pair_w, place, load))
+
+
+def _routed_rows_bwd(n, k, interpret, res, g):
+    xs, w_in, w_out, order, pair_w, place, load = res
+    s = xs.shape[0]
+
+    def window(i, grads):
+        route, sizes, w = _route(i, order, pair_w, place, load, n, k)
+        x_rows = moe_rows.gather_call(xs, route, interpret=interpret)
+        y, vjp = jax.vjp(lambda *a: _experts(*a, sizes), x_rows, w_in, w_out)
+        dy, dw = moe_rows.gather_call(g[0], route, w, dot=y,
+                                      out_dtype=y.dtype, interpret=interpret)
+        dx_rows, d_in, d_out = vjp(dy)
+        dx, at_picks = moe_rows.combine_call(
+            dx_rows, route, s, carry=None if grads is None else grads[0],
+            pick_vals=dw, interpret=interpret)
+        start, live = route.win[0], route.win[1]
+        d_pick = jnp.where((place >= start) & (place < start + live),
+                           at_picks, 0.0)
+        if grads is None:
+            return dx, d_in, d_out, d_pick
+        return dx, grads[1] + d_in, grads[2] + d_out, grads[3] + d_pick
+    dx, d_in, d_out, d_pick = jax.lax.fori_loop(
+        1, -(-load.sum() // n), window, window(0, None))
+    return (dx.astype(xs.dtype), d_in, d_out, d_pick.reshape(s, -1),
+            None, None, None, None)
+
+
+_routed_rows.defvjp(_routed_rows_fwd, _routed_rows_bwd)
+
+
+def _held_picks(expert, local, top_w, load, kk: int):
+    """Each token's held picks, first ones first: their places in the order
+    that sorts the pairs by held expert ([S * kk] int32, -1 past the
+    token's count) and their weights ([S, kk]).  A pick's place is its
+    expert's first row plus the tokens before it that picked that expert
+    (a token picks an expert once): cumulative counts, no second sort and
+    no scatter."""
+    hit = (expert[..., None] == jnp.arange(load.shape[0])) & local[..., None]
+    picked = hit.any(1).astype(jnp.int32)                        # [S, held]
+    before = jnp.cumsum(picked, axis=0) - picked
+    place = jnp.sum(jnp.where(hit, (jnp.cumsum(load) - load) + before[:, None],
+                              0), axis=-1)                       # [S, K]
+    slot = ((jnp.cumsum(local, axis=1) - 1)[..., None] == jnp.arange(kk)) \
+        & local[..., None]                                       # [S, K, kk]
+    held_place = jnp.where(slot.any(1), jnp.sum(
+        jnp.where(slot, place[..., None], 0), axis=1), -1)
+    return (held_place.reshape(-1).astype(jnp.int32),
+            jnp.sum(jnp.where(slot, top_w[..., None], 0.0), axis=1))
+
+
+def held_experts(xs, w_in, w_out, top_w, expert, load, num_experts: int):
+    """What the held experts add to each token (``[S, D]`` float32) and how
+    many held pairs the windows placed.  ``expert`` (``[S, K]``) is each
+    pick's expert less the first held one, ``load`` the rows of each held
+    expert.  The pairs are sorted by expert, held experts first (absent ones
+    share the key ``held``), and walked in windows of ``FAST_BUFFER`` times
+    the balanced share of rows; the rows are moved by the kernels of
+    ``ops/moe_rows.py`` where its ``dispatch`` says so (counted in the
+    registry as ``moe.row_path{path="kernel"}``), else by ``jax.numpy``
+    (``path="jnp"``)."""
+    (s, k), held = expert.shape, load.shape[0]
+    local = (expert >= 0) & (expert < held)
+    key = jnp.where(local, expert, held).reshape(-1)
+    kk = min(k, held)
+    n = min(s * kk, -(-int(FAST_BUFFER * s * k * held / num_experts) // 8)
+            * 8)
+    pad = (0, max(0, -(-s * kk // n) * n - s * k))
+    interpret = moe_rows.dispatch(s, n, xs.shape[1], kk)
+    get_registry().inc("moe.row_path",
+                       path="jnp" if interpret is None else "kernel")
+    order = jnp.pad(jnp.argsort(key, stable=True).astype(jnp.int32), pad)
+    if interpret is None:
+        return _routed(xs, w_in, w_out, top_w.reshape(-1), order, load, n, k)
+    place, pick_w = _held_picks(expert, local, top_w, load, kk)
+    return _routed_rows(xs, w_in, w_out, pick_w, order,
+                        jax.lax.stop_gradient(top_w).reshape(-1), place, load,
+                        n, k, interpret)
+
+
 def _top_k(x: jax.Array, k: int):
     """``jax.lax.top_k`` over the last axis for small k: k passes of argmax
     and mask (ties to the lower index, as there).  On a TPU ``top_k`` of a
@@ -203,7 +339,15 @@ def _top_k(x: jax.Array, k: int):
         rest = jnp.where(jnp.arange(x.shape[-1]) == i[..., None], -jnp.inf,
                          rest)
     idx = jnp.stack(picked, axis=-1).astype(jnp.int32)
-    return jnp.take_along_axis(x, idx, axis=-1), idx
+    return _pick(x, idx), idx
+
+
+def _pick(x: jax.Array, idx: jax.Array) -> jax.Array:
+    """``take_along_axis(x, idx, axis=-1)`` as a select over the last axis:
+    on a TPU the gather is a walk over the indices, and its transpose a
+    scatter-add of as many."""
+    hit = idx[..., None] == jnp.arange(x.shape[-1])
+    return jnp.sum(jnp.where(hit, x[..., None, :], 0), axis=-1)
 
 
 class _Router(Module):
@@ -280,7 +424,12 @@ class DroplessMoE(Module):
     is one window; all ``tokens * min(top_k, experts_held)`` rows landing
     here is every window), no shape depends on the data, and the memory is
     one window's.  Expert matmuls in the input's dtype; router, top-k and
-    the weighted sum in float32.
+    the weighted sum in float32.  On a TPU the rows move through two Pallas
+    kernels (``ops/moe_rows.py``), each the other's transpose: a window's
+    rows gathered from their tokens by DMA, live rows only, and summed back
+    to each token from its held picks in float32, written once — no
+    scatter-add, no mask over rows; other backends and shapes run the same
+    walk in ``jax.numpy`` (``moe.row_path`` counts which).
 
     State: ``aux_loss`` — the load-balancing loss ``num_experts * sum_e f_e
     P_e`` over ALL experts (f_e: picks of expert e per token, P_e: mean
@@ -333,7 +482,7 @@ class DroplessMoE(Module):
             bias = scope.variable("expert_bias",
                                   lambda: jnp.zeros((e,), jnp.float32))
             _, top_e = _top_k(probs + bias, k)
-            top_w = jnp.take_along_axis(probs, top_e, axis=-1)
+            top_w = _pick(probs, top_e)
         if self.norm_topk_prob:
             total = top_w.sum(axis=-1, keepdims=True)
             top_w = top_w / (total + self.norm_epsilon
@@ -341,12 +490,7 @@ class DroplessMoE(Module):
         if self.route_scale != 1.0:
             top_w = top_w * self.route_scale
 
-        # sort the pairs by expert, held experts first (absent ones share
-        # the key ``held``): the first n_local rows of that order are ours
         first = self.first_expert
-        local = (top_e >= first) & (top_e < first + held)
-        key = jnp.where(local, top_e - first, held).reshape(-1)
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)  # [S*K]
         picks = jnp.sum(top_e.reshape(-1, 1) == jnp.arange(e), axis=0,
                         dtype=jnp.int32)                         # [E]
         load = picks[first:first + held]
@@ -354,12 +498,8 @@ class DroplessMoE(Module):
         w_in = scope.param("w_gate_up", init,
                            (held, d, 2 * m)).astype(x.dtype)
         w_out = scope.param("w_down", init, (held, m, d)).astype(x.dtype)
-
-        n_most = s * min(k, held)
-        n = min(n_most, -(-int(FAST_BUFFER * s * k * held / e) // 8) * 8)
-        order = jnp.pad(order, (0, max(0, -(-n_most // n) * n - s * k)))
-        out, placed = _routed(xs, w_in, w_out, top_w.reshape(-1), order,
-                              load, n, k)
+        out, placed = held_experts(xs, w_in, w_out, top_w, top_e - first,
+                                   load, e)
 
         if self.shared_units:
             shared = scope.child(SwiGLU(self.shared_units,

@@ -33,6 +33,7 @@ fa = importlib.import_module("analytics_zoo_tpu.ops.flash_attention")
 gdr = importlib.import_module("analytics_zoo_tpu.ops.gated_delta_rule")
 qkv = importlib.import_module("analytics_zoo_tpu.ops.gdn_qkv_conv")
 ssd = importlib.import_module("analytics_zoo_tpu.ops.mamba2_ssd")
+rows = importlib.import_module("analytics_zoo_tpu.ops.moe_rows")
 
 HBM_BYTES = 16 * 2 ** 30  # one v5e chip
 
@@ -87,17 +88,23 @@ def _compile(fn, *args):
 VMEM_BYTES = 128 * 2 ** 20  # one v5e TensorCore
 
 
-def _kernel_vmem(text, name):
-    """(requested, used) bytes of VMEM of the Pallas kernel ``name`` in a
-    compiled program's text: the limit its ``pallas_call`` asked for and
-    what Mosaic allocated under it."""
-    (line,) = [l for l in text.splitlines() if re.search(
-        rf"%{name}[.\d]* = .*custom_call_target=\"tpu_custom_call\"", l)]
-    asked, used = (int(re.search(
-        key + r'":\[\{"memory_space":"1","offset":"0","size":"(\d+)"',
+def _kernels_vmem(text, name):
+    """(requested, used) bytes of VMEM of each call of the Pallas kernel
+    ``name`` in a compiled program's text: the limit its ``pallas_call``
+    asked for and what Mosaic allocated under it."""
+    return [tuple(int(re.search(
+        key + r'":\[\{"memory_space":"1","offset":"\d+","size":"(\d+)"',
         line).group(1)) for key in ('"scoped_memory_configs',
                                     '"used_scoped_memory_configs'))
-    return asked, used
+        for line in text.splitlines() if re.search(
+            rf"%{name}[.\d]* = .*custom_call_target=\"tpu_custom_call\"",
+            line)]
+
+
+def _kernel_vmem(text, name):
+    """The same for a kernel the program calls once."""
+    (one,) = _kernels_vmem(text, name)
+    return one
 
 
 # The forward kernel, blocks from the shapes.  BERT-base's heads (B*H = 4*12,
@@ -220,6 +227,43 @@ def test_gdn_qkv_conv_kernels_compile(chip, which):
     # one pass: nothing of the program but the kernel's operands and results
     # reaches HBM (the slice that fed the conv was a 268 MB copy)
     assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+# one expert layer, forward and backward, at each cell's shapes: tokens,
+# top-k, experts held of all, expert width (Qwen3-Next: two rows of 8,192,
+# top-10 of 512 holding 32 of width 512; Trinity-Mini: one row of 16,384,
+# top-8 of 128 holding 16 of width 1,024, sigmoid router balanced by a
+# bias; GLM-4.7-Flash: one row of 8,192, top-4 of 64 holding 8 of 1,536)
+@pytest.mark.parametrize("rows_, experts, top_k, held, units, balance", [
+    ((2, 8192), 512, 10, 32, 512, None), ((1, 16384), 128, 8, 16, 1024, 1e-3),
+    ((1, 8192), 64, 4, 8, 1536, 1e-3)])
+def test_expert_layer_moves_its_rows_in_its_kernels(
+        chip, monkeypatch, rows_, experts, top_k, held, units, balance):
+    """The rows reach the grouped matmuls and return to their tokens in
+    ``moe_gather_rows`` and ``moe_combine_rows``, each inside the fast
+    memory it asked for, and the compiled layer holds no scatter at all:
+    no row scatter-add forward or backward, no top-k's transposed gather."""
+    from analytics_zoo_tpu.parallel import DroplessMoE
+    monkeypatch.setattr(rows, "dispatch", lambda *sizes: False)
+    layer = DroplessMoE(experts, top_k, units, experts_held=held,
+                        score_func="softmax" if balance is None
+                        else "sigmoid", balance_coeff=balance)
+    x = jax.ShapeDtypeStruct(rows_ + (2048,), jnp.bfloat16)
+    variables = jax.eval_shape(
+        lambda a: layer.init(jax.random.PRNGKey(0), a), x)
+
+    def loss(params, state, a):
+        out, _ = layer.apply({"params": params, "state": state}, a,
+                             training=True)
+        return jnp.sum(out.astype(jnp.float32))
+    compiled = _compile(jax.grad(loss, argnums=(0, 2)), *_on(
+        chip, (variables["params"], variables["state"], x)))
+    text = compiled.as_text()
+    for name in ("moe_gather_rows", "moe_combine_rows"):
+        calls = _kernels_vmem(text, name)
+        assert calls and all(used <= asked < VMEM_BYTES
+                             for asked, used in calls), (name, calls)
+    assert not re.search(r"\bscatter(-add)?[.\d]* = |\bscatter\(", text)
 
 
 def test_bert_base_layer_fwd_bwd_compiles(chip):
@@ -445,6 +489,7 @@ def test_qwen3next_cell_train_step_compiles_for_one_chip(topo, monkeypatch):
     _flash_takes_the_chips_branch(monkeypatch)
     monkeypatch.setattr(gdr, "dispatch", lambda dk, dv, chunk: False)
     monkeypatch.setattr(qkv, "dispatch", lambda *sizes: False)
+    monkeypatch.setattr(rows, "dispatch", lambda *sizes: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(
             root, "benchmark/configs/qwen3_next_80b_a3b_ep16.json")) as f:
@@ -474,19 +519,28 @@ def test_qwen3next_cell_train_step_compiles_for_one_chip(topo, monkeypatch):
     held = sum(int(np.prod(l.shape)) for l in
                jax.tree_util.tree_leaves(est._ts["params"]))
     assert held == 625_667_136
-    # the program PR 36 left, to the byte: a change to code this cell shares
+    # the expert layers' rows move through their two kernels (one gather
+    # forward, two backward; one combine each way; the first window taken
+    # before the windows' loop, whose copy the program holds too)
+    for name, count in (("moe_gather_rows", 6 * 4), ("moe_combine_rows",
+                                                      4 * 4)):
+        calls = re.findall(rf"%({name}[.\d]*) = ", text)
+        assert len(calls) == count, (name, calls)
+    # the program PR 40 left, to the byte: a change to code this cell shares
     # (``MultiHeadAttention``, the flash kernels' wrapper, ``DroplessMoE``)
     # that means to leave it alone shows it here.  PR 32 - 35 left
     # 25,031,698,546,688 FLOPs, 340,933,083,136 bytes accessed and
     # 5,699,792,384 B of temporaries (PR 34: + 96,768 B for the forward
     # kernel's tile lists); PR 36's two kernels a DeltaNet layer took
-    # 52.2 GB of passes out (the issue asked for 30 at least: the slices,
-    # the l2norm's float32 arrays and the conv's three backward fusions)
+    # 52.2 GB of passes out (288,747,782,144 B, 4,838,883,328 B of
+    # temporaries); PR 40's row kernels took the expert layers' gathers,
+    # masks and scatter-adds and the top-k's gather and its transpose out
+    # (the FLOPs count the grouped matmuls of the peeled first window twice)
     cost = compiled.cost_analysis()
-    assert int(cost["flops"]) == 24_989_306_716_160
-    assert int(cost["bytes accessed"]) == 288_747_782_144
-    assert 340_933_083_136 - int(cost["bytes accessed"]) > 30e9
-    assert compiled.memory_analysis().temp_size_in_bytes == 4_838_883_328
+    assert int(cost["flops"]) == 27_054_875_607_040
+    assert int(cost["bytes accessed"]) == 150_427_795_456
+    assert 288_747_782_144 - int(cost["bytes accessed"]) > 100e9
+    assert compiled.memory_analysis().temp_size_in_bytes == 4_929_047_552
 
 
 def test_trinity_mini_cell_train_step_compiles_for_one_chip(topo,
@@ -502,6 +556,7 @@ def test_trinity_mini_cell_train_step_compiles_for_one_chip(topo,
     from analytics_zoo_tpu.orca.learn import Estimator
     from benchmark.families import afmoe
     _flash_takes_the_chips_branch(monkeypatch)
+    monkeypatch.setattr(rows, "dispatch", lambda *sizes: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(
             root, "benchmark/configs/trinity_mini_ep8.json")) as f:
@@ -522,6 +577,7 @@ def test_trinity_mini_cell_train_step_compiles_for_one_chip(topo,
     full = re.findall(r"%(flash_attention_bwd[.\d]*) = ", text)
     assert (len(window), len(full)) == (4, 1), (window, full)
     assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) >= 6 * 4
+    assert len(re.findall(r"%moe_combine_rows[.\d]* = ", text)) >= 4 * 4
     held = sum(int(np.prod(l.shape)) for l in
                jax.tree_util.tree_leaves(est._ts["params"]))
     assert held == 705_473_792
@@ -587,6 +643,7 @@ def test_glm_cell_train_step_compiles_for_one_chip(topo, monkeypatch):
     from analytics_zoo_tpu.orca.learn import Estimator
     from benchmark.families import glm_moe_lite
     _flash_takes_the_chips_branch(monkeypatch)
+    monkeypatch.setattr(rows, "dispatch", lambda *sizes: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(
             root, "benchmark/configs/glm_4_7_flash_ep8.json")) as f:
