@@ -45,10 +45,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from ..core.metrics import get_registry
 from ..ops import gated_delta_rule as kernels
+from ..ops import gdn_gated_norm
 from ..ops.gdn_qkv_conv import qkv_conv
 from . import initializers
-from .layers import Dense, RMSNorm
+from .layers import Dense, _rms_norm
 from .module import Module, Scope
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -227,6 +229,44 @@ class _QKVConv(Module):
         return qkv_conv(qkvz, w, *self.heads, self.epsilon)
 
 
+def _gated_norm_jax(o, z, w, epsilon):
+    """The mixer's way out in ``jax.numpy`` (autodiff and ``_rms_norm``'s
+    rule give its backward pass): o ``[B, T, H, d_v]`` RMS-normalised a head
+    and scaled by ``w``, then gated by ``silu(z)`` (``[B, T, H d_v]``)."""
+    b, t, h, dv = o.shape
+    o = _rms_norm(o, w, epsilon)
+    # z lies as qkvz's last columns: gated there, no head axis to make
+    return (o.reshape(b, t, h * dv)
+            * jax.nn.silu(z.astype(jnp.float32)).astype(o.dtype))
+
+
+class _GatedNorm(Module):
+    """The mixer's child ``norm``: it holds the RMSNorm's ``weight``
+    ``[d_v]`` (initialised to ones, the leaf an ``RMSNorm`` under that name
+    held) and is everything between the delta rule's output and
+    ``out_proj``: each head RMS-normalised, then gated by ``silu(z)``.
+    ``ops/gdn_gated_norm.py``, one Pallas kernel each way, where its
+    ``dispatch`` says so (z read in place from ``source``, the ``qkvz`` it
+    was sliced from), else :func:`_gated_norm_jax`; which of the two a trace
+    took is counted as ``gdn.norm_path{path="kernel" | "jnp"}``."""
+
+    def __init__(self, epsilon: float, name: Optional[str] = None):
+        super().__init__(name)
+        self.epsilon = epsilon
+
+    def forward(self, scope: Scope, o: jax.Array, z: jax.Array,
+                source: jax.Array):
+        t, dv = o.shape[1], o.shape[3]
+        w = scope.param("weight", initializers.get("ones"), (dv,))
+        interpret = gdn_gated_norm.dispatch(t, dv)
+        get_registry().inc("gdn.norm_path",
+                           path="jnp" if interpret is None else "kernel")
+        if interpret is None:
+            return _gated_norm_jax(o, z, w, self.epsilon)
+        return gdn_gated_norm.gated_norm(o, z, w, self.epsilon, interpret,
+                                         source)
+
+
 class GatedDeltaNet(Module):
     """Gated DeltaNet mixer: ``[B, T, D] -> [B, T, D]``, causal.
 
@@ -240,8 +280,10 @@ class GatedDeltaNet(Module):
     ``ops/gdn_qkv_conv.py``, one Pallas kernel each way on a TPU, the
     ``jax.numpy`` lines elsewhere); each key head serves ``num_v_heads /
     num_k_heads`` value heads.  The recurrence is :func:`gated_delta_rule`;
-    its output is RMS-normalised a head, gated by ``silu(z)`` and projected
-    back.  No biases.  Holds no cache: a sequence starts from a zero state.
+    its output is RMS-normalised a head and gated by ``silu(z)`` (the child
+    ``norm``: ``ops/gdn_gated_norm.py``, one Pallas kernel each way on a TPU,
+    the ``jax.numpy`` lines elsewhere) and projected back.  No biases.
+    Holds no cache: a sequence starts from a zero state.
     """
 
     def __init__(self, num_k_heads: int, num_v_heads: int, k_head_dim: int,
@@ -283,8 +325,5 @@ class GatedDeltaNet(Module):
         # value head i reads key head i // (hv / hk)
         o, _ = gated_delta_rule(q, k, v, g, beta, chunk=self.chunk)
 
-        o = scope.child(RMSNorm(self.epsilon), o, name="norm")
-        # z lies as qkvz's last columns: gated there, no head axis to make
-        o = (o.reshape(b, t, value_dim)
-             * jax.nn.silu(z.astype(jnp.float32)).astype(o.dtype))
+        o = scope.child(_GatedNorm(self.epsilon), o, z, qkvz, name="norm")
         return scope.child(dense(d), o, name="out_proj")

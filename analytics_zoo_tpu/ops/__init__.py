@@ -7,8 +7,9 @@ this package for the few ops where a hand schedule beats XLA — today that is
 flash attention (O(T) memory softmax-attention, MXU-tiled), the chunked
 gated delta rule (``gated_delta_rule``: the linear-attention recurrence with
 a chunk's terms and the state in VMEM, forward and backward; called through
-``nn.linear_attention.gated_delta_rule``), the way into it
-(``gdn_qkv_conv``) and the chunked Mamba-2 scan (``mamba2_ssd``: a chunk's
+``nn.linear_attention.gated_delta_rule``), the ways into it
+(``gdn_qkv_conv``) and out of it (``gdn_gated_norm``) and the chunked
+Mamba-2 scan (``mamba2_ssd``: a chunk's
 ``[Q, Q]`` terms and the carried state in VMEM, forward and backward;
 called through ``nn.state_space.ssd``).
 """

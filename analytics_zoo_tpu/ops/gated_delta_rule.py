@@ -532,8 +532,12 @@ def _vjp_fwd(q, k, v, g, beta, s0, chunk, interpret):
                                        interpret)
     # named, so that an enclosing jax.checkpoint can be told to keep them
     # (nn.Remat(save_names=...)): with all three kept, the recomputation of
-    # a block runs no kernel
-    o = checkpoint_name(o, "gated_delta_rule_out")
+    # a block runs no kernel.  o is named as the kernel wrote it, [B, T,
+    # H d_v]: kept as [B, T, H, d_v] it is a relayout copy on a TPU to keep
+    # and another one to read back as the rows its consumers take
+    b, t = o.shape[:2]
+    o = checkpoint_name(o.reshape(b, t, -1),
+                        "gated_delta_rule_out").reshape(o.shape)
     states = checkpoint_name(states, "gated_delta_rule_states")
     inverse = checkpoint_name(inverse, "gated_delta_rule_inverse")
     return (o, sn), (q, k, v, g, beta, states, inverse)
