@@ -4,15 +4,15 @@ Every family from the reference's Scala+Py twin zoo, rebuilt as pure-JAX
 modules over analytics_zoo_tpu.nn: recommendation (NeuralCF, WideAndDeep,
 SessionRecommender), text classification, text matching (KNRM), anomaly
 detection, seq2seq, image classification (ResNet), object detection (SSD),
-plus the BERT family the reference shipped through TFPark, and four
-causal decoders the reference had no analog of: three sparse-expert ones, a
-hybrid linear-attention one (Qwen3Next), a sliding-window / full-attention
-one with a bias-balanced sigmoid router (AFMoE) and a latent-attention one
-with a multi-token prediction module (GlmMoeLite), a dense hybrid of
-Mamba-2 state-space blocks and attention with a tied, scaled head
-(GraniteHybrid), and a looped one that runs one stack of blocks several
-times with the same weights and weighs every pass's head by a learned exit
-gate (Ouro).
+plus the BERT family the reference shipped through TFPark, and five
+causal decoders the reference had no analog of, all on one block
+(``models.decoder``): three sparse-expert ones, a hybrid linear-attention
+one (Qwen3Next), a sliding-window / full-attention one with a
+bias-balanced sigmoid router (AFMoE) and a latent-attention one with a
+multi-token prediction module (GlmMoeLite), a dense hybrid of Mamba-2
+state-space blocks and attention with a tied, scaled head (GraniteHybrid),
+and a looped one that runs one stack of blocks several times with the same
+weights and weighs every pass's head by a learned exit gate (Ouro).
 """
 
 from .common import ZooModel

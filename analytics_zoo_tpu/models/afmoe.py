@@ -14,7 +14,8 @@ top-k expert layer with an ungated shared expert
 (``parallel.DroplessMoE``): a sigmoid router that is balanced by a bias on
 its selection, moved every training step from the step's loads, and by no
 auxiliary loss; it may hold a share of the experts only.  Four RMSNorms a
-block, two of them on the sublayers' outputs; the embedding scaled by
+block, two of them on the sublayers' outputs (the sandwich form of
+``models.decoder.DecoderBlock``); the embedding scaled by
 ``sqrt(hidden_size)``; a final RMSNorm and an untied vocabulary head:
 logits at every position, trained with ``sparse_categorical_crossentropy``
 against the ids shifted by one.
@@ -32,30 +33,12 @@ import jax
 import jax.numpy as jnp
 
 import analytics_zoo_tpu.nn as nn
-from analytics_zoo_tpu.nn.module import Module, Scope
+from analytics_zoo_tpu.nn.module import Scope
 from analytics_zoo_tpu.parallel.moe import DroplessMoE
 from .common import ZooModel
+from .decoder import DecoderBlock, stack
 
 SLIDING, FULL = "sliding_attention", "full_attention"
-
-
-class AFMoEBlock(Module):
-    """``x += norm(attn(norm(x))); x += norm(ff(norm(x)))``: ``ff`` is the
-    child ``mlp`` (dense) or ``moe`` (experts)."""
-
-    def __init__(self, attn: Module, ff: Module, ff_name: str,
-                 epsilon: float, name: Optional[str] = None):
-        super().__init__(name)
-        self.attn, self.ff, self.ff_name = attn, ff, ff_name
-        self.epsilon = epsilon
-
-    def forward(self, scope: Scope, x: jax.Array) -> jax.Array:
-        def norm(name: str, h: jax.Array) -> jax.Array:
-            return scope.child(nn.RMSNorm(self.epsilon), h, name=name)
-        h = scope.child(self.attn, norm("input_norm", x), name="attn")
-        x = x + norm("post_attn_norm", h)
-        h = scope.child(self.ff, norm("pre_ff_norm", x), name=self.ff_name)
-        return x + norm("post_ff_norm", h)
 
 
 class AFMoE(ZooModel):
@@ -98,8 +81,10 @@ class AFMoE(ZooModel):
                         if k not in ("self", "__class__")}
         self.__dict__.update(self._config)
         self.dtype = jnp.dtype(dtype)
+        self._norm = nn.RMSNorm(rms_eps)
+        self._blocks = stack([self._block(i) for i in range(n_layers)], remat)
 
-    def _block(self, i: int) -> AFMoEBlock:
+    def _block(self, i: int) -> DecoderBlock:
         sliding = self.layer_types[i] == SLIDING
         # a full layer carries no position signal of its own
         attn = nn.MultiHeadAttention(
@@ -108,34 +93,27 @@ class AFMoE(ZooModel):
             qk_norm=True, qk_norm_zero_centered=False, gate=True,
             norm_epsilon=self.rms_eps, rope_theta=self.rope_theta,
             rotary_dim=self.head_dim if sliding else 0,
-            window=self.window if sliding else None)
+            window=self.window if sliding else None, name="attn")
         if i < self.num_dense_layers:
-            ff_name, ff = "mlp", nn.SwiGLU(self.dense_units)
+            ff = nn.SwiGLU(self.dense_units, name="mlp")
         else:
-            ff_name, ff = "moe", DroplessMoE(
+            ff = DroplessMoE(
                 self.num_experts, self.top_k, self.moe_units,
                 experts_held=self.experts_held,
                 first_expert=self.first_expert,
                 shared_units=self.shared_units, shared_gate=False,
                 score_func="sigmoid", route_scale=self.route_scale,
                 norm_epsilon=1e-20, balance_coeff=self.balance_coeff)
-        return AFMoEBlock(attn, ff, ff_name, self.rms_eps, name=f"layer_{i}")
+        return DecoderBlock(attn, ff, ("input_norm", "post_attn_norm",
+                                       "pre_ff_norm", "post_ff_norm"),
+                            self._norm, name=f"layer_{i}")
 
     def forward(self, scope: Scope, ids: jax.Array) -> jax.Array:
         x = scope.child(nn.Embedding(self.vocab_size, self.hidden_size),
                         ids, name="embed")
         x = (x * self.hidden_size ** 0.5).astype(self.dtype)
-        for i in range(self.n_layers):
-            block = self._block(i)
-            if self.remat:
-                # what the flash backward reads is kept, so a block's
-                # recomputation runs no attention kernel again
-                x = scope.child(
-                    nn.Remat(block, save_names=(
-                        "flash_attention_out", "flash_attention_lse")),
-                    x, name=f"remat_{i}")
-            else:
-                x = scope.child(block, x, name=f"layer_{i}")
-        x = scope.child(nn.RMSNorm(self.rms_eps), x, name="final_norm")
+        for block in self._blocks:
+            x = scope.child(block, x, name=block.name)
+        x = scope.child(self._norm, x, name="final_norm")
         return scope.child(nn.Dense(self.vocab_size, use_bias=False), x,
                            name="head")

@@ -13,7 +13,8 @@ first ``num_dense_layers`` blocks feed forward through a dense SwiGLU, the
 others through a dropless top-k expert layer with an ungated shared expert
 (``parallel.DroplessMoE``): a sigmoid router balanced by a bias on its
 selection and by no auxiliary loss; it may hold a share of the experts
-only.  Two RMSNorms a block, a final RMSNorm and an untied vocabulary head.
+only.  Two RMSNorms a block (the pre-norm ``models.decoder.DecoderBlock``),
+a final RMSNorm and an untied vocabulary head.
 
 ``mtp_layers=1`` adds the multi-token prediction module: from the last
 block's output ``h_i`` (before the final norm) and the embedding of the
@@ -46,6 +47,7 @@ import analytics_zoo_tpu.nn as nn
 from analytics_zoo_tpu.nn.module import Module, Scope
 from analytics_zoo_tpu.parallel.moe import DroplessMoE
 from .common import ZooModel
+from .decoder import DecoderBlock, stack
 
 #: device-side counters of the prediction module, kept in the model's state
 #: under ``counters`` and published by the Estimator once an epoch as the
@@ -61,27 +63,6 @@ MTP_COUNTER_KEYS = ("positions", "top1_hits")
 #: positions, unweighted, in the last step
 MTP_LEVEL_KEYS = ("loss",)
 
-#: what a block keeps across its recomputation: what the flash backward reads
-_REMAT_SAVE = ("flash_attention_out", "flash_attention_lse")
-
-
-class GlmMoeLiteBlock(Module):
-    """``x += attn(norm(x)); x += ff(norm(x))``: ``ff`` is the child ``mlp``
-    (dense) or ``moe`` (experts)."""
-
-    def __init__(self, attn: Module, ff: Module, ff_name: str,
-                 epsilon: float, name: Optional[str] = None):
-        super().__init__(name)
-        self.attn, self.ff, self.ff_name = attn, ff, ff_name
-        self.epsilon = epsilon
-
-    def forward(self, scope: Scope, x: jax.Array) -> jax.Array:
-        def norm(name: str, h: jax.Array) -> jax.Array:
-            return scope.child(nn.RMSNorm(self.epsilon), h, name=name)
-        x = x + scope.child(self.attn, norm("input_norm", x), name="attn")
-        return x + scope.child(self.ff, norm("post_attn_norm", x),
-                               name=self.ff_name)
-
 
 class MultiTokenPredictor(Module):
     """One prediction depth (DeepSeek-V3 section 2.2): the hidden states
@@ -89,22 +70,21 @@ class MultiTokenPredictor(Module):
     further on, both ``[B, T, D]`` -> the normalised hidden states the
     shared head reads."""
 
-    def __init__(self, block: Module, epsilon: float, remat: bool,
+    def __init__(self, block: DecoderBlock, epsilon: float, remat: bool,
                  name: Optional[str] = None):
         super().__init__(name)
-        self.block, self.epsilon, self.remat = block, epsilon, remat
+        self.block = (nn.Remat(block, name="remat",
+                               save_names=block.remat_keep)
+                      if remat else block)
+        self.norm = nn.RMSNorm(epsilon)
 
     def forward(self, scope: Scope, h: jax.Array, e: jax.Array) -> jax.Array:
         def norm(name: str, a: jax.Array) -> jax.Array:
-            return scope.child(nn.RMSNorm(self.epsilon), a, name=name)
+            return scope.child(self.norm, a, name=name)
         g = jnp.concatenate([norm("enorm", e), norm("hnorm", h)], axis=-1)
         g = scope.child(nn.Dense(h.shape[-1], use_bias=False), g,
                         name="eh_proj")
-        if self.remat:
-            g = scope.child(nn.Remat(self.block, save_names=_REMAT_SAVE), g,
-                            name="remat")
-        else:
-            g = scope.child(self.block, g, name=self.block.name)
+        g = scope.child(self.block, g, name=self.block.name)
         return norm("head_norm", g)
 
 
@@ -140,23 +120,31 @@ class GlmMoeLite(ZooModel):
                         if k not in ("self", "__class__")}
         self.__dict__.update(self._config)
         self.dtype = jnp.dtype(dtype)
+        self._norm = nn.RMSNorm(rms_eps)
+        self._blocks = stack([self._block(i < num_dense_layers, f"layer_{i}")
+                              for i in range(n_layers)], remat)
+        self._mtp = (MultiTokenPredictor(self._block(False, "block"), rms_eps,
+                                         remat, name="mtp")
+                     if mtp_layers else None)
 
-    def _block(self, dense: bool, name: str) -> GlmMoeLiteBlock:
+    def _block(self, dense: bool, name: str) -> DecoderBlock:
         attn = nn.LatentAttention(
             self.num_heads, self.q_rank, self.kv_rank, self.nope_dim,
             self.rope_dim, self.v_dim, rope_theta=self.rope_theta,
-            norm_epsilon=self.rms_eps, causal=True, use_flash=self.use_flash)
+            norm_epsilon=self.rms_eps, causal=True, use_flash=self.use_flash,
+            name="attn")
         if dense:
-            ff_name, ff = "mlp", nn.SwiGLU(self.dense_units)
+            ff = nn.SwiGLU(self.dense_units, name="mlp")
         else:
-            ff_name, ff = "moe", DroplessMoE(
+            ff = DroplessMoE(
                 self.num_experts, self.top_k, self.moe_units,
                 experts_held=self.experts_held,
                 first_expert=self.first_expert,
                 shared_units=self.shared_units, shared_gate=False,
                 score_func="sigmoid", route_scale=self.route_scale,
                 norm_epsilon=1e-20, balance_coeff=self.balance_coeff)
-        return GlmMoeLiteBlock(attn, ff, ff_name, self.rms_eps, name=name)
+        return DecoderBlock(attn, ff, ("input_norm", "post_attn_norm"),
+                            self._norm, name=name)
 
     def forward(self, scope: Scope, ids: jax.Array) -> jax.Array:
         depths = [ids]
@@ -169,19 +157,11 @@ class GlmMoeLite(ZooModel):
                         jnp.stack(depths, axis=1),
                         name="embed").astype(self.dtype)
         x = e[:, 0]
-        for i in range(self.n_layers):
-            block = self._block(i < self.num_dense_layers, f"layer_{i}")
-            if self.remat:
-                x = scope.child(nn.Remat(block, save_names=_REMAT_SAVE), x,
-                                name=f"remat_{i}")
-            else:
-                x = scope.child(block, x, name=f"layer_{i}")
-        hidden = [scope.child(nn.RMSNorm(self.rms_eps), x,
-                              name="final_norm")]
+        for block in self._blocks:
+            x = scope.child(block, x, name=block.name)
+        hidden = [scope.child(self._norm, x, name="final_norm")]
         if self.mtp_layers:
-            mtp = MultiTokenPredictor(self._block(False, "block"),
-                                      self.rms_eps, self.remat)
-            hidden.append(scope.child(mtp, x, e[:, 1], name="mtp"))
+            hidden.append(scope.child(self._mtp, x, e[:, 1], name="mtp"))
         # the one head over every depth's rows at once
         logits = scope.child(nn.Dense(self.vocab_size, use_bias=False),
                              jnp.stack(hidden, axis=1), name="head")

@@ -9,11 +9,12 @@ says which blocks mix positions through a Mamba-2 state-space recurrence
 grouped-query softmax attention with no positional encoding at all
 (``nn.MultiHeadAttention`` through its dense / flash dispatch, at the
 published softmax multiplier and not ``1/sqrt(head_dim)``).  Every block
-feeds forward through one dense SwiGLU.  Four multipliers shape the
-residual stream: the embedding is scaled up, both sublayers' outputs are
-scaled down before they join it, and the logits are divided.  The head is
-TIED: it reads the embedding's table, one parameter leaf whose gradient is
-the sum of both uses.  Logits at every position, trained with
+feeds forward through one dense SwiGLU; it is the pre-norm form of
+``models.decoder.DecoderBlock``.  Four multipliers shape the residual
+stream: the embedding is scaled up, both sublayers' outputs are scaled down
+before they join it, and the logits are divided.  The head is TIED: it
+reads the embedding's table, one parameter leaf whose gradient is the sum
+of both uses.  Logits at every position, trained with
 ``sparse_categorical_crossentropy`` against the ids shifted by one.
 
 Not built: a cache or a decode path with the convolution's and the
@@ -30,33 +31,11 @@ import jax
 import jax.numpy as jnp
 
 import analytics_zoo_tpu.nn as nn
-from analytics_zoo_tpu.nn.module import Module, Scope
+from analytics_zoo_tpu.nn.module import Scope
 from .common import ZooModel
+from .decoder import DecoderBlock, stack
 
 MAMBA, ATTENTION = "mamba", "attention"
-
-
-class GraniteHybridBlock(Module):
-    """``x += m * mixer(norm(x)); x += m * ff(norm(x))`` with the residual
-    multiplier ``m``: the mixer is the child ``mamba`` or ``attn``."""
-
-    def __init__(self, mixer: Module, mixer_name: str, ff: Module,
-                 residual_multiplier: float, epsilon: float,
-                 name: Optional[str] = None):
-        super().__init__(name)
-        self.mixer, self.mixer_name, self.ff = mixer, mixer_name, ff
-        self.residual_multiplier = residual_multiplier
-        self.epsilon = epsilon
-
-    def forward(self, scope: Scope, x: jax.Array) -> jax.Array:
-        def norm(name: str, h: jax.Array) -> jax.Array:
-            return scope.child(nn.RMSNorm(self.epsilon), h, name=name)
-        m = self.residual_multiplier
-        h = scope.child(self.mixer, norm("input_norm", x),
-                        name=self.mixer_name)
-        x = x + h * m
-        h = scope.child(self.ff, norm("post_mixer_norm", x), name="mlp")
-        return x + h * m
 
 
 class GraniteHybrid(ZooModel):
@@ -106,37 +85,36 @@ class GraniteHybrid(ZooModel):
                         if k not in ("self", "__class__")}
         self.__dict__.update(self._config)
         self.dtype = jnp.dtype(dtype)
+        self._norm = nn.RMSNorm(rms_eps)
+        self._blocks = stack([self._block(i) for i in range(n_layers)], remat,
+                             keep=remat_save)
 
-    def _block(self, i: int) -> GraniteHybridBlock:
+    def _block(self, i: int) -> DecoderBlock:
         if self.layer_types[i] == ATTENTION:
             # no rotary embedding, no q/k norm, no gate: position reaches
             # this layer through the state-space blocks before it
-            mixer_name, mixer = "attn", nn.MultiHeadAttention(
+            mixer = nn.MultiHeadAttention(
                 self.num_heads, head_dim=self.head_dim, causal=True,
                 use_flash=self.use_flash, num_kv_heads=self.num_kv_heads,
-                scale=self.attention_multiplier)
+                scale=self.attention_multiplier, name="attn")
         else:
-            mixer_name, mixer = "mamba", nn.Mamba2(
+            mixer = nn.Mamba2(
                 self.mamba_heads, self.mamba_head_dim, self.mamba_state,
                 n_groups=self.mamba_groups,
                 conv_kernel=self.mamba_conv_kernel, chunk=self.chunk,
-                conv_bias=self.mamba_conv_bias, epsilon=self.rms_eps)
-        return GraniteHybridBlock(mixer, mixer_name, nn.SwiGLU(self.ff_units),
-                                  self.residual_multiplier, self.rms_eps,
-                                  name=f"layer_{i}")
+                conv_bias=self.mamba_conv_bias, epsilon=self.rms_eps,
+                name="mamba")
+        return DecoderBlock(mixer, nn.SwiGLU(self.ff_units, name="mlp"),
+                            ("input_norm", "post_mixer_norm"), self._norm,
+                            self.residual_multiplier, name=f"layer_{i}")
 
     def forward(self, scope: Scope, ids: jax.Array) -> jax.Array:
         x = scope.child(nn.Embedding(self.vocab_size, self.hidden_size),
                         ids, name="embed")
         x = (x * self.embedding_multiplier).astype(self.dtype)
-        for i in range(self.n_layers):
-            block = self._block(i)
-            if self.remat:
-                x = scope.child(nn.Remat(block, save_names=self.remat_save),
-                                x, name=f"remat_{i}")
-            else:
-                x = scope.child(block, x, name=f"layer_{i}")
-        x = scope.child(nn.RMSNorm(self.rms_eps), x, name="final_norm")
+        for block in self._blocks:
+            x = scope.child(block, x, name=block.name)
+        x = scope.child(self._norm, x, name="final_norm")
         # the logits' divisor, applied to [T, hidden] and not [T, vocab]
         x = x / self.logits_scaling
         if self.tie_embeddings:

@@ -7,13 +7,14 @@ the release's ``modeling_ouro.py``).
 
 Absent from the reference, whose language models end at BERT (tfpark).
 Built from layers the zoo shares with its other models.  A block is
-``models.afmoe.AFMoEBlock`` with a dense SwiGLU: ``h += N(attn(N(h))); h +=
-N(ff(N(h)))``, four RMSNorms, causal ``nn.MultiHeadAttention`` with rotary
-embedding on the whole head and its dense / flash dispatch.  ``h = E[ids]``;
-then ``total_ut_steps`` passes, each through every block (the SAME module
-objects every pass, so the ``Scope`` hands each the one set of weights and
-their gradient sums the passes) and then the final RMSNorm, whose output is
-the state the next pass starts from.  After pass ``t`` the untied head
+``models.decoder.DecoderBlock`` in its sandwich form with a dense SwiGLU:
+``h += N(attn(N(h))); h += N(ff(N(h)))``, four RMSNorms, causal
+``nn.MultiHeadAttention`` with rotary embedding on the whole head and its
+dense / flash dispatch.  ``h = E[ids]``; then ``total_ut_steps`` passes,
+each through every block (the SAME module objects every pass, so the
+``Scope`` hands each the one set of weights and their gradient sums the
+passes) and then the final RMSNorm, whose output is the state the next pass
+starts from.  After pass ``t`` the untied head
 gives ``logits_t`` and the exit gate ``lambda_t = sigmoid(h_t w_g + b_g)``;
 the exit distribution a position is ``p_t = lambda_t prod_{j<t} (1 -
 lambda_j)`` and, on the last pass, ``p_T = prod_{j<T} (1 - lambda_j)``.
@@ -47,11 +48,8 @@ import analytics_zoo_tpu.nn as nn
 from analytics_zoo_tpu.nn import initializers
 from analytics_zoo_tpu.nn.module import Module, Scope
 from analytics_zoo_tpu.ops.fused_xent import fused_softmax_xent_tokens
-from .afmoe import AFMoEBlock
 from .common import ZooModel
-
-#: what a block keeps across its recomputation: what the flash backward reads
-_REMAT_SAVE = ("flash_attention_out", "flash_attention_lse")
+from .decoder import DecoderBlock, stack
 
 #: tokens a chunk of the heads' cross-entropy: a chunk's float32 logits at
 #: the published vocabulary are 201 MB, and its product 206 GFLOP
@@ -123,21 +121,20 @@ class Ouro(ZooModel):
         self.__dict__.update(self._config)
         self.dtype = jnp.dtype(dtype)
         # built once: every pass runs these same objects
-        self._blocks = [self._block(i) for i in range(n_layers)]
-        self._final_norm = nn.RMSNorm(rms_eps)
+        self._norm = nn.RMSNorm(rms_eps)
+        self._blocks = stack([self._block(i) for i in range(n_layers)], remat)
         self._head = ExitHead(vocab_size, HEAD_CHUNK)
         self._gate = nn.Dense(1, dtype=jnp.float32)
 
-    def _block(self, i: int) -> Module:
+    def _block(self, i: int) -> DecoderBlock:
         attn = nn.MultiHeadAttention(
             self.num_heads, head_dim=self.head_dim, causal=True,
             use_flash=self.use_flash, num_kv_heads=self.num_kv_heads,
-            rotary_dim=self.head_dim, rope_theta=self.rope_theta)
-        block = AFMoEBlock(attn, nn.SwiGLU(self.ffn_units), "mlp",
-                           self.rms_eps, name=f"layer_{i}")
-        if self.remat:
-            return nn.Remat(block, name=f"remat_{i}", save_names=_REMAT_SAVE)
-        return block
+            rotary_dim=self.head_dim, rope_theta=self.rope_theta, name="attn")
+        return DecoderBlock(attn, nn.SwiGLU(self.ffn_units, name="mlp"),
+                            ("input_norm", "post_attn_norm", "pre_ff_norm",
+                             "post_ff_norm"), self._norm,
+                            name=f"layer_{i}")
 
     def forward(self, scope: Scope, ids: jax.Array,
                 labels: Optional[jax.Array] = None) -> jax.Array:
@@ -153,7 +150,7 @@ class Ouro(ZooModel):
                     x = scope.child(block, x, name=block.name)
             with jax.named_scope("exit"):
                 # the normed state is the one the next pass starts from
-                x = scope.child(self._final_norm, x, name="final_norm")
+                x = scope.child(self._norm, x, name="final_norm")
             states.append(x)
         h = jnp.stack(states, axis=1)                       # [B, P, T, D]
         with jax.named_scope("exit"):

@@ -176,6 +176,9 @@ class MultiHeadAttention(Module):
     paths).  All of them go through the one dense / flash / ring dispatch
     above (``attention_core``)."""
 
+    # what the flash backward reads: small beside running the kernel again
+    remat_keep = ("flash_attention_out", "flash_attention_lse")
+
     def __init__(self, num_heads: int, head_dim: Optional[int] = None,
                  dropout: float = 0.0,
                  use_flash: Union[bool, str] = False,
@@ -223,11 +226,8 @@ class MultiHeadAttention(Module):
         # remat: rematerialize the attention core (logits/softmax) in the
         # backward pass instead of saving residuals — trades ~2*T^2*d
         # recompute FLOPs per head for the T x T probability maps' HBM
-        # round-trip.  Measured on BERT-base seq 512 / micro-batch 8:
-        # 110.0 -> 99.9 ms/step, 53.5% -> 58.9% MFU — without the fixed
-        # overhead that made the Pallas flash kernel a net LOSS there
-        # (124.6 ms); XLA was materializing per-layer probability maps
-        # for the backward.  Exact: same math, recomputed.
+        # round-trip, which XLA would otherwise keep for every layer's
+        # backward.  Exact: same math, recomputed.
         self.remat = remat
         # use_flash: True | False | "auto" — "auto" picks the flash
         # kernel when the kv length reaches FLASH_AUTO_MIN_SEQ (the
@@ -328,6 +328,8 @@ class LatentAttention(Module):
     State: ``counters`` — the level ``mla.kv_latent_abs_max``
     (``LATENT_LEVEL_KEYS``), read by the Estimator once an epoch.
     """
+
+    remat_keep = MultiHeadAttention.remat_keep
 
     def __init__(self, num_heads: int, q_rank: int, kv_rank: int,
                  nope_dim: int, rope_dim: int, v_dim: int,

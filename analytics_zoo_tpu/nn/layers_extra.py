@@ -665,7 +665,9 @@ class Remat(Module):
     to its inputs + outputs at ~1.3x compute.  ``save_names``: values the
     inner module tagged with ``jax.ad_checkpoint.checkpoint_name`` that are
     kept all the same (``ops.flash_attention`` tags its output and
-    log-sum-exp: small beside what recomputing the kernel costs)."""
+    log-sum-exp: small beside what recomputing the kernel costs).  A
+    decoder's blocks keep what their mixers declare they tag
+    (``Module.remat_keep``, asked by ``models.decoder.stack``)."""
 
     def __init__(self, inner: Module, name: Optional[str] = None,
                  save_names: Sequence[str] = ()):

@@ -31,9 +31,8 @@ outside the carry is computed for all chunks at once and the backward pass
 is autodiff through the same program (``lax.scan``'s transpose walks the
 chunks in reverse); it is also the oracle of the kernels' tests.  Wrap the
 layer in ``nn.Remat`` to keep a block's activations out of the saved set;
-``save_names=("gated_delta_rule_out", "gated_delta_rule_states",
-"gated_delta_rule_inverse")`` keeps what the backward kernel reads, so the
-recomputation runs no kernel.
+``save_names=GatedDeltaNet.remat_keep`` keeps what the backward kernel
+reads, so the recomputation runs no kernel.
 """
 
 from __future__ import annotations
@@ -285,6 +284,11 @@ class GatedDeltaNet(Module):
     the ``jax.numpy`` lines elsewhere) and projected back.  No biases.
     Holds no cache: a sequence starts from a zero state.
     """
+
+    # what the delta rule's backward kernel reads: kept, a recomputation of
+    # the block runs neither kernel again
+    remat_keep = ("gated_delta_rule_out", "gated_delta_rule_states",
+                  "gated_delta_rule_inverse")
 
     def __init__(self, num_k_heads: int, num_v_heads: int, k_head_dim: int,
                  v_head_dim: int, conv_kernel: int = 4, chunk: int = 64,

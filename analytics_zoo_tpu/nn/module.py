@@ -142,6 +142,11 @@ class Scope:
 class Module:
     """Base class for all layers.  Subclasses implement ``forward(scope, ...)``."""
 
+    #: values this module's kernels tag (``jax.ad_checkpoint.checkpoint_name``)
+    #: for their own backward, which an enclosing ``nn.Remat`` should keep
+    #: rather than recompute (``models.decoder.stack`` asks it)
+    remat_keep: Tuple[str, ...] = ()
+
     def __init__(self, name: Optional[str] = None):
         self.name = name
 

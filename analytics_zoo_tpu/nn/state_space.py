@@ -45,7 +45,8 @@ VMEM); by the ``jax.numpy`` form what autodiff keeps (``lax.scan``'s
 transpose walks the chunks in reverse), less the ``[chunks, H, Q, Q]``
 float32 terms, which are recomputed (``jax.checkpoint``).  Both tag the
 output and the chunk-start states (``mamba2_ssd_out``,
-``mamba2_ssd_states``) for an enclosing ``nn.Remat(save_names=)``.
+``mamba2_ssd_states``) for an enclosing ``nn.Remat(save_names=)``;
+``Mamba2.remat_keep`` leaves them out.
 """
 
 from __future__ import annotations
@@ -216,6 +217,10 @@ class Mamba2(Module):
     State: ``counters`` — device-side counters and levels the Estimator
     reads once an epoch (``COUNTER_KEYS``, ``LEVEL_KEYS``; series ``ssm.*``).
     """
+
+    # nothing: the step measured no faster with the scan's output and states
+    # kept (PERF.md section 6), so a recomputation runs the scan again
+    remat_keep = ()
 
     def __init__(self, num_heads: int, head_dim: int, state_size: int,
                  n_groups: int = 1, conv_kernel: int = 4, chunk: int = 256,

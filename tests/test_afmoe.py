@@ -379,8 +379,8 @@ def test_whole_model_logits_loss_and_gradients_match_the_reference(remat):
 
 def test_the_embedding_is_scaled_and_the_full_layer_has_no_positions():
     model = AFMoE(**dict(TINY, remat=False))
-    kinds = [(b.attn.window, b.attn.rotary_dim, b.ff_name)
-             for b in map(model._block, range(5))]
+    kinds = [(b.mixer.window, b.mixer.rotary_dim, b.ff.name)
+             for b in model._blocks]
     assert kinds == [(16, 16, "mlp")] + [(16, 16, "moe")] * 3 \
         + [(None, 0, "moe")]
     assert AFMoE()._config["layer_types"] == ([SLIDING] * 3 + [FULL]) * 8
