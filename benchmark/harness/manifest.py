@@ -27,6 +27,12 @@ SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 TRAFFIC_SUFFIXES = (".json", ".jsonl", ".toml", ".txt", ".csv")
 TOP_KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
             "end_to_end", "per_layer"}
+#: laid over every traffic's own ``rehearse`` overlay: a rehearsal's
+#: profiler slice opens with its window.  A training window is sized from
+#: one warm epoch, which at a rehearsal's few tiny steps is mostly feed
+#: start-up, and on a CPU that a test suite loads the window can close
+#: before a slice started at a share of that estimate would open.
+REHEARSE_TRAFFIC = {"trace_after_share": 0.0}
 
 
 def _read(path: str) -> Dict[str, Any]:
@@ -123,7 +129,8 @@ def cell(manifest: Dict[str, Any], name: str, rehearse: bool = False,
                                  entry["traffic"] + ".json"))
     if rehearse:
         config = merged(config, config.get("rehearse", {}))
-        traffic = merged(traffic, traffic.get("rehearse", {}))
+        traffic = merged(merged(traffic, traffic.get("rehearse", {})),
+                         REHEARSE_TRAFFIC)
     e2e_entries, layer_entries = manifest["end_to_end"], manifest["per_layer"]
     if not listed:
         e2e_entries = _every_metric(e2e_entries, "end_to_end", root)

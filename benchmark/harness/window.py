@@ -120,7 +120,11 @@ class TraceSlice:
     off: the slice holds device ops, JAX's own host events and the
     benchmark's annotations.  Even so one second of BERT-base training on a
     v5e is 460,000 device events and 48 MB, and ``stop_trace`` takes about a
-    second a megabyte to write it (PR 22): keep slices near one second."""
+    second a megabyte to write it (PR 22): keep slices near one second.
+
+    A slice with no ``after`` (a rehearsal's) is open when ``start()``
+    returns, so the window that follows begins inside it however long the
+    window turns out to be."""
 
     def __init__(self, after: float, seconds: float):
         self.after, self.seconds = after, seconds
@@ -131,17 +135,24 @@ class TraceSlice:
         self.error: Optional[BaseException] = None
 
     def start(self) -> "TraceSlice":
+        if self.after <= 0:
+            self._open()
         self._thread.start()
         return self
 
+    def _open(self) -> None:
+        import jax
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(self.dir, profiler_options=options)
+
     def _run(self) -> None:
         import jax
-        if self._stop.wait(self.after):
-            return
         try:
-            options = jax.profiler.ProfileOptions()
-            options.python_tracer_level = 0
-            jax.profiler.start_trace(self.dir, profiler_options=options)
+            if self.after > 0:
+                if self._stop.wait(self.after):
+                    return
+                self._open()
             try:
                 self._stop.wait(self.seconds)
             finally:

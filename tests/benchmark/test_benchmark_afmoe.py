@@ -99,7 +99,7 @@ def test_the_new_metrics_are_reported_in_the_new_cell_and_in_no_other():
     names = {m.name for m in manifest.cell(SPEC, CELL).per_layer}
     assert not names & {"moe_local_pair_share", "moe_dropped_pairs_per_step",
                         "flash_fwd_roofline_pct", "ragged_dot_roofline_pct",
-                        "pallas_ms_per_step", "gdn_kernel_roofline_pct"}
+                        "gdn_scope_ms_per_step", "gdn_kernel_roofline_pct"}
 
 
 def test_the_manifest_gained_one_configuration_and_one_cell(cell):

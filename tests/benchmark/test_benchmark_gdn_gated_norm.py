@@ -83,17 +83,18 @@ def test_the_kernels_are_bytes_bound_under_the_chips_peaks():
 
 
 def test_the_file_proposes_a_linear_attention_metric_of_the_cell():
-    """The metric waits in its file (``proposed``) for a benchmark change
-    to admit it: appended to ``BENCHMARK.json``'s ``per_layer`` it would
-    add a fifth metric to the layer whose four names
+    """The metric waited in its file (``proposed``) for a benchmark change
+    to admit it, and ``BENCHMARK.json``'s entry is what the file proposed,
+    for the cell alone: the fifth metric of the layer whose names
     ``test_benchmark_gdn_qkv_conv.py`` lists.  A cell tried by its files'
-    names (``<config>+<traffic>``) reads it meanwhile."""
+    names (``<config>+<traffic>``) reads it too."""
     want = {"unit": "%", "better": "higher", "source": "device_trace",
             "layer": "linear attention",
             "moves": "train_samples_per_s_chip"}
     assert _file()["proposed"] == want
-    entry = next((e for e in SPEC["per_layer"] if e["name"] == METRIC), None)
-    assert entry in (None, dict(want, name=METRIC, workloads=[CELL]))
+    entry = next(e for e in SPEC["per_layer"] if e["name"] == METRIC)
+    assert entry == dict(want, name=METRIC, workloads=[CELL])
+    assert METRIC in {m.name for m in manifest.cell(SPEC, CELL).per_layer}
     tried_as = "qwen3_next_80b_a3b_ep16+fit_lm_tokens_s8192_b2"
     cell = manifest.cell(SPEC, tried_as)
     tried = next(m for m in cell.per_layer if m.name == METRIC)

@@ -87,7 +87,8 @@ def test_the_entry_is_a_linear_attention_metric_of_the_cell():
     names = [e["name"] for e in SPEC["per_layer"]
              if e["layer"] == "linear attention"]
     assert names == ["gdn_kernel_roofline_pct", "gdn_scope_ms_per_step",
-                     "gdn_outside_kernels_ms_per_step", METRIC]
+                     "gdn_outside_kernels_ms_per_step", METRIC,
+                     "gdn_gated_norm_roofline_pct"]
 
 
 def test_the_pattern_names_the_kernels_the_program_calls():

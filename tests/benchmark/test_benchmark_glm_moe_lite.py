@@ -114,7 +114,8 @@ def test_the_new_metrics_are_reported_in_the_new_cell_and_in_no_other():
     names = {m.name for m in manifest.cell(SPEC, CELL).per_layer}
     assert not names & {"moe_local_pair_share", "flash_fwd_roofline_pct",
                         "moe_scope_ms_per_step", "afmoe_local_pair_share",
-                        "granite_flash_fwd_roofline_pct", "pallas_ms_per_step"}
+                        "granite_flash_fwd_roofline_pct",
+                        "gdn_scope_ms_per_step"}
     # every metric without a list is read here as in every cell
     unlisted = {e["name"] for e in SPEC["per_layer"] if "workloads" not in e}
     assert unlisted <= names and {"step_device_ms", "train_mfu_pct",

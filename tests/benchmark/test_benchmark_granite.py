@@ -31,8 +31,8 @@ ROOFLINES = {"ssd_roofline_pct": "ssd_work",
              "granite_flash_bwd_roofline_pct": "flash_bwd_work"}
 COUNTERS = {"ssm_padded_tokens_per_step", "ssm_chunk_decay_exponent_max",
             "ssm_state_abs_max"}
-NEW_METRICS = set(ROOFLINES) | COUNTERS | {"ssd_ms_per_step"}
-STATE_SPACE = COUNTERS | {"ssd_ms_per_step", "ssd_roofline_pct"}
+NEW_METRICS = set(ROOFLINES) | COUNTERS | {"ssd_scope_ms_per_step"}
+STATE_SPACE = COUNTERS | {"ssd_scope_ms_per_step", "ssd_roofline_pct"}
 T, H, P, N, HEADS, KV, D = 8192, 64, 64, 128, 32, 8, 64
 PAIRS = T * (T + 1) // 2
 
@@ -87,7 +87,10 @@ def test_the_traced_rehearsal_reports_the_scans_state(rehearsal):
     # dt up to softplus(.) of a few tenths, A up to 16, 24 positions
     assert 0.0 < got["ssm_chunk_decay_exponent_max"]["value"] < 16 * 24
     assert 0.0 < got["ssm_state_abs_max"]["value"] < 10.0
-    assert NEW_METRICS & set(got) == COUNTERS
+    # the device-trace metrics' readers find nothing on the CPU, a
+    # module's time excepted: the scan's scope reads here too
+    assert NEW_METRICS & set(got) == COUNTERS | {"ssd_scope_ms_per_step"}
+    assert got["ssd_scope_ms_per_step"]["value"] > 0
     assert "first_step_s" in got and "epoch_gap_ms" in got
 
 
@@ -99,7 +102,7 @@ def test_the_new_metrics_are_reported_in_the_new_cell_and_in_no_other():
     # ... and the other decoders' kernels' and counters' entries stay theirs
     names = {m.name for m in manifest.cell(SPEC, CELL).per_layer}
     assert not names & {"moe_local_pair_share", "flash_fwd_roofline_pct",
-                        "flash_bwd_roofline_pct", "pallas_ms_per_step",
+                        "flash_bwd_roofline_pct", "gdn_scope_ms_per_step",
                         "gdn_kernel_roofline_pct", "afmoe_local_pair_share",
                         "full_flash_fwd_s16k_roofline_pct"}
     # every metric without a list is read here as in every cell
@@ -238,21 +241,26 @@ def test_the_work_functions_are_their_arithmetic(cell):
 
 
 def test_the_patterns_read_the_scan_and_the_two_kernels():
-    scan = [re.compile(_args(m)["args"]["pattern"])
-            for m in ("ssd_ms_per_step", "ssd_roofline_pct")]
-    assert scan[0].pattern == scan[1].pattern
-    assert _args("ssd_ms_per_step")["args"]["stat"] == "ms_per_execution"
-    for rx in scan:
-        assert rx.search("%mamba2_ssd_fwd.3 = (bf16[1,8192,64,64]")
-        assert rx.search("mamba2_ssd_bwd")
-        # a chunk's [Q, Q] term of all heads, and the state of all heads
-        assert rx.search("%convolution_convert_fusion = bf16[32,64,256,256]"
-                         " fusion(f32[1,32,256,1,64,64] %bitcast.4929")
-        assert rx.search("%fusion.1421 = f32[32,1,1,64,64,128] fusion("
-                         "f32[32,256,64,64] %bitcast.5053")
-        assert rx.search("%fusion.9 = f32[64,64,128] fusion(")
-        assert not rx.search("%flash_attention_fwd.1 = (bf16[32,8192,128]")
-        assert not rx.search("%fusion.7 = bf16[8192,8512] fusion(")
+    scope = _args("ssd_scope_ms_per_step")
+    assert (scope["reader"], scope["args"]["stat"]) == (
+        "trace_scope_time", "ms_per_step")
+    rx = re.compile(scope["args"]["scope"])
+    for path in ("remat_0/mamba/ssd", "remat_8/mamba/ssd/mamba2_ssd_fwd"):
+        assert rx.search(path)
+    for path in ("remat_0/mamba/in_proj", "remat_0/mamba/norm",
+                 "remat_9/attn", "ssd_like"):
+        assert not rx.search(path)
+    rx = re.compile(_args("ssd_roofline_pct")["args"]["pattern"])
+    assert rx.search("%mamba2_ssd_fwd.3 = (bf16[1,8192,64,64]")
+    assert rx.search("mamba2_ssd_bwd")
+    # a chunk's [Q, Q] term of all heads, and the state of all heads
+    assert rx.search("%convolution_convert_fusion = bf16[32,64,256,256]"
+                     " fusion(f32[1,32,256,1,64,64] %bitcast.4929")
+    assert rx.search("%fusion.1421 = f32[32,1,1,64,64,128] fusion("
+                     "f32[32,256,64,64] %bitcast.5053")
+    assert rx.search("%fusion.9 = f32[64,64,128] fusion(")
+    assert not rx.search("%flash_attention_fwd.1 = (bf16[32,8192,128]")
+    assert not rx.search("%fusion.7 = bf16[8192,8512] fusion(")
     fwd = re.compile(_args("granite_flash_fwd_roofline_pct")["args"][
         "pattern"])
     bwd = re.compile(_args("granite_flash_bwd_roofline_pct")["args"][

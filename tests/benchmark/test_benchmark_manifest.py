@@ -212,3 +212,54 @@ def test_no_device_or_topology_call_at_import_time():
     proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
                           text=True, timeout=300)
     assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+#: a manifest list indexed from its end: what a later PR appends would move
+FROM_THE_END = re.compile(
+    r"""\[\s*["'](configs|workloads|per_layer)["']\s*\]\s*\[\s*-""")
+
+
+@pytest.mark.parametrize("subscript,index,found", [
+    ('["configs"]', '[-1]["name"]', True),
+    ("['workloads']", "[-1]", True),
+    ('["per_layer"]', "[-3:]", True),
+    ('["per_layer"]', "[-len(NEW_METRICS):]", True),
+    ('[ "per_layer" ]', "[ -2 ]", True),
+    ('["configs"]', "[0]", False),
+    ('["per_layer"]', "[:-1]", False),
+    ('["end_to_end"]', "[-1]", False),
+])
+def test_the_guard_knows_an_index_from_the_end(subscript, index, found):
+    # written apart, so that this file's own lines hold no such index
+    assert bool(FROM_THE_END.search("SPEC" + subscript + index)) == found
+
+
+def test_no_test_pins_a_position_at_the_end_of_the_manifest():
+    """A configuration, a cell or a metric is appended to ``BENCHMARK.json``
+    by a later PR, which may not edit these files: a test finds its entries
+    by name, never as the manifest's last."""
+    folder = os.path.dirname(os.path.abspath(__file__))
+    for f in sorted(os.listdir(folder)):
+        if f.endswith(".py"):
+            with open(os.path.join(folder, f)) as fh:
+                hits = [n for n, line in enumerate(fh, 1)
+                        if FROM_THE_END.search(line)]
+            assert not hits, (f, hits)
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_a_rehearsal_opens_its_slice_with_the_window_and_a_chip_run_later(
+        name):
+    """On a CPU that a test suite loads, a rehearsal's window can close
+    before a slice started at a share of its estimated length would open;
+    a chip run's slice keeps its traffic's own start."""
+    entry = next(w for w in SPEC["workloads"] if w["name"] == name)
+    with open(os.path.join(REPO, "benchmark", "traffic",
+                           entry["traffic"] + ".json")) as f:
+        own = json.load(f)
+    assert manifest.cell(SPEC, name).traffic["trace_after_share"] \
+        == own["trace_after_share"]
+    tiny = manifest.cell(SPEC, name, rehearse=True).traffic
+    assert tiny["trace_after_share"] == 0
+    assert tiny["trace_seconds"] == own.get("rehearse", {}).get(
+        "trace_seconds", own["trace_seconds"])

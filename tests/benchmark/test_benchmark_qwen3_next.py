@@ -20,7 +20,7 @@ from benchmark.layer_metrics import Reading, trace_kernel_roofline  # noqa: E402
 SPEC = manifest.load(REPO)
 CELL = "qwen3next_ep16_fit_s8192"
 NEW_METRICS = {"moe_local_pair_share", "moe_load_max_over_mean",
-               "moe_dropped_pairs_per_step", "pallas_ms_per_step",
+               "moe_dropped_pairs_per_step", "gdn_scope_ms_per_step",
                "flash_fwd_roofline_pct", "ragged_dot_roofline_pct"}
 
 
@@ -59,7 +59,10 @@ def test_the_traced_rehearsal_reports_the_expert_layers_counters():
     assert 1.0 <= got["moe_load_max_over_mean"]["value"] < 4.0
     assert NEW_METRICS & set(got) == {
         "moe_local_pair_share", "moe_load_max_over_mean",
-        "moe_dropped_pairs_per_step"}
+        "moe_dropped_pairs_per_step", "gdn_scope_ms_per_step"}
+    # a module's time reads on the CPU too: the rehearsal's program has a
+    # table of its ops, and the mixers' ops lie under ``gdn``
+    assert got["gdn_scope_ms_per_step"]["value"] > 0
     assert "first_step_s" in got and "epoch_gap_ms" in got
 
 

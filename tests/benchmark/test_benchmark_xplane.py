@@ -128,3 +128,42 @@ def test_a_trace_without_device_events_reduces_to_nothing():
     assert empty.devices == [] and xplane.idle_pct(empty) is None
     assert xplane.busy_seconds(empty) == [] and xplane.idle_gaps(empty) == []
     assert xplane.find(os.path.join(REPO, "benchmark", "testdata")) is None
+
+
+def _slice_around_work(after):
+    """A slice of a minute started ``after`` seconds before some compiled
+    work of some microseconds, and closed as the work ends: the directory
+    it left."""
+    import jax
+    import jax.numpy as jnp
+    from benchmark.harness import window
+    step = jax.jit(lambda a: jnp.tanh(a @ a))
+    x = step(jnp.full((8, 8), 0.01)).block_until_ready()
+    slice_ = window.TraceSlice(after=after, seconds=60.0).start()
+    step(x).block_until_ready()
+    return slice_.finish()
+
+
+def test_a_slice_with_no_after_holds_the_work_that_follows_its_start():
+    """A rehearsal's slice is open when ``start()`` returns, so a window
+    however short lies inside it."""
+    import shutil
+    folder = _slice_around_work(0.0)
+    try:
+        path = xplane.find(folder)
+        assert path is not None
+        assert sum(xplane.busy_seconds(xplane.load(path))) > 0
+    finally:
+        shutil.rmtree(folder, ignore_errors=True)
+
+
+def test_a_slice_that_would_start_after_the_window_leaves_no_trace():
+    """A chip run's slice waits ``after`` seconds on its own thread; a
+    window that closes first stops the wait, writes nothing and leaves no
+    thread behind."""
+    import shutil
+    folder = _slice_around_work(60.0)
+    try:
+        assert xplane.find(folder) is None
+    finally:
+        shutil.rmtree(folder, ignore_errors=True)
